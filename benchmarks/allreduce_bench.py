@@ -63,7 +63,7 @@ def wallclock_rows(p: int = 8):
 import time, numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.collectives import circulant_allreduce
-from repro.core.jaxcompat import shard_map
+from jax import shard_map
 p = len(jax.devices())
 mesh = Mesh(np.array(jax.devices()), ("data",))
 def native_psum(a):

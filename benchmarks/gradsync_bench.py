@@ -96,7 +96,7 @@ _DEVICE_CODE = r"""
 import json, time, numpy as np, jax, jax.numpy as jnp
 from functools import partial
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.core.jaxcompat import shard_map
+from jax import shard_map
 from repro.core.comm import circulant_qallreduce_body
 from repro.optim.compression import compressed_psum_ring
 

@@ -160,39 +160,42 @@ def replay_kernel(spec, scalars: Sequence[np.ndarray],
 
 def _traced_pallas_params(name: str, R: int, nslots: int, bs: int, nb: int,
                           dtype) -> Tuple[Optional[dict], Tuple]:
-    """(pallas_call eqn params, traced out dtypes) for kernel ``name``.
+    """(pallas_call eqn params, traced out dtypes) for kernel ``name``
+    called as the plans call it: compiled (``interpret=False``) on
+    buffers already in the tiled slot layout.
 
-    Tracing only -- jax.make_jaxpr never executes the kernel, so this is
-    cheap and device-free.
+    Tracing only -- jax.make_jaxpr over shape structs never executes
+    the kernel or allocates its operands, so this is cheap and
+    device-free at any block size.
     """
+    import functools
+
     import jax
     import jax.numpy as jnp
 
     from repro.kernels import block_pack as bp
 
-    buf = jnp.zeros((R, nslots, bs), dtype)
-    msg = jnp.zeros((R, bs), dtype)
-    idx = jnp.zeros((R,), jnp.int32)
-    calls = {
-        "block_pack": (lambda: bp.block_pack(buf, idx, interpret=True)),
-        "block_unpack": (lambda: bp.block_unpack(buf, msg, idx,
-                                                 interpret=True)),
-        "block_shuffle": (lambda: bp.block_shuffle(buf, msg, idx, idx,
-                                                   interpret=True)),
-        "block_shuffle_staged": (lambda: bp.block_shuffle_staged(
-            buf, msg, msg, idx, idx, interpret=True)),
-        "block_acc_shuffle": (lambda: bp.block_acc_shuffle(
-            buf, msg, idx, idx, op="sum", interpret=True)),
-        "block_acc_shuffle_staged": (lambda: bp.block_acc_shuffle_staged(
-            buf, msg, msg, idx, idx, op="sum", interpret=True)),
-        "block_qacc_shuffle": (lambda: bp.block_qacc_shuffle(
-            jnp.zeros((R, nslots, bs), jnp.float32),
-            jnp.zeros((R, nslots, bs), jnp.float32),
-            jnp.zeros((R, bs), jnp.int8),
-            jnp.zeros((R, nb), jnp.float32),
-            idx, idx, interpret=True)),
-    }
-    jaxpr = jax.make_jaxpr(calls[name])()
+    S = jax.ShapeDtypeStruct
+    idx = S((R,), jnp.int32)
+    if name == "block_qacc_shuffle":
+        slot = bp.slot_shape(bs, jnp.float32, qblock=bs // nb)
+        buf = S((R, nslots) + slot, jnp.float32)
+        args = (buf, buf, S((R,) + slot, jnp.int8),
+                S((R, slot[0]), jnp.float32), idx, idx)
+    else:
+        slot = bp.slot_shape(bs, dtype)
+        buf = S((R, nslots) + slot, dtype)
+        msg = S((R,) + slot, dtype)
+        args = {
+            "block_pack": (buf, idx),
+            "block_unpack": (buf, msg, idx),
+            "block_shuffle": (buf, msg, idx, idx),
+            "block_shuffle_staged": (buf, msg, msg, idx, idx),
+            "block_acc_shuffle": (buf, msg, idx, idx),
+            "block_acc_shuffle_staged": (buf, msg, msg, idx, idx),
+        }[name]
+    fn = functools.partial(getattr(bp, name), interpret=False)
+    jaxpr = jax.make_jaxpr(fn)(*args)
     outs = tuple(v.aval.dtype for v in jaxpr.jaxpr.outvars)
     for eqn in jaxpr.eqns:
         if "pallas" in eqn.primitive.name:
@@ -217,7 +220,8 @@ def audit_kernel_trace(name: str, *, R: int = 3, nslots: int = 4,
     dtypes = ("float32",) if name == "block_qacc_shuffle" else _DTYPES
     for dt in dtypes:
         spec = registry_spec if registry_spec is not None else \
-            bp.kernel_audit_spec(name, R=R, nslots=nslots, bs=bs, nb=nb)
+            bp.kernel_audit_spec(name, R=R, nslots=nslots, bs=bs, nb=nb,
+                                 dtype=_np.dtype(dt))
         loc = f"{name}[{dt}]"
         params, traced_out = _traced_pallas_params(
             name, R, nslots, bs, nb, _np.dtype(dt))
@@ -283,14 +287,21 @@ def schedule_scalars(name: str, p: int, n: int,
 
 
 def audit_kernel(name: str, p: int, n: int, root: int = 0,
-                 bs: int = 8) -> Report:
+                 bs: Optional[int] = None) -> Report:
     """Structural replay of one kernel over every round of a real
-    p-rank n-block schedule, plus the trace/dtype checks."""
+    p-rank n-block schedule, plus the trace/dtype checks.
+
+    The default block of ``bs`` elements spans several row tiles of
+    every audited dtype, so the replay covers the tile grid axis too.
+    """
     from repro.kernels import block_pack as bp
+    from repro.kernels.quant_ops import QBLOCK
 
     findings: List[Finding] = []
     nslots, rows = schedule_scalars(name, p, n, root)
-    nb = max(1, bs // 4)
+    if bs is None:
+        bs = 3 * bp.MAX_BLOCK_BYTES // 4
+    nb = max(1, bs // QBLOCK)
     spec = bp.kernel_audit_spec(name, R=p, nslots=nslots, bs=bs, nb=nb)
     checked = 0
     for t, scalars in enumerate(rows):

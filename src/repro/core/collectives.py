@@ -55,7 +55,6 @@ from .hier import (  # noqa: F401  (re-exports)
     hier_broadcast,
     hier_reduce,
 )
-from .jaxcompat import shard_map as _shard_map
 
 __all__ = [
     "circulant_broadcast",
@@ -293,7 +292,7 @@ def ring_allgather(mesh: Mesh, axis_name: str, x: jax.Array):
             buf = jax.lax.dynamic_update_slice(buf, cur[None], (src,) + (0,) * xs.ndim)
         return buf.reshape((p * xs.shape[0],) + xs.shape[1:])
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh, in_specs=P(axis_name), out_specs=P(), check_vma=False
     )
     return shard(x)

@@ -40,7 +40,7 @@ later call with the same spec.
 
 The module also hosts the :class:`HostDataPlan` certification path: the
 single-process executions of the full data plane (kernel rows standing
-in for the p ranks, ``jnp.roll`` as the network exchange) that
+in for the p ranks, a row rotation as the network exchange) that
 :mod:`repro.core.simulator` asserts bit-exact against its
 message-passing reference -- routed through the same plan cache, so
 certification sweeps reuse slot tables and step handles too.
@@ -48,6 +48,7 @@ certification sweeps reuse slot tables and step handles too.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Tuple
 
@@ -64,7 +65,6 @@ from .costmodel import (
     optimal_num_blocks_reduce,
 )
 from .engine import ScheduleBundle, cached_plan, get_bundle
-from .jaxcompat import shard_map as _shard_map
 from .roundstep import (
     BACKENDS,
     PhaseStatic,
@@ -181,28 +181,19 @@ def _rot_perm(p: int, s: int):
     return [(r, (r + s) % p) for r in range(p)]
 
 
-def _split_blocks(flat: jnp.ndarray, n: int):
-    """Split a flat vector into n padded blocks + 1 garbage slot: [n+1, B]."""
-    size = flat.shape[0]
-    bs = -(-size // n)  # ceil
-    pad = n * bs - size
-    flat = jnp.pad(flat, (0, pad))
-    blocks = flat.reshape(n, bs)
-    garbage = jnp.zeros((1, bs), flat.dtype)
-    return jnp.concatenate([blocks, garbage], axis=0), bs, pad
-
-
-def _split_blocks_q(flat: jnp.ndarray, n: int, qblock: int):
-    """:func:`_split_blocks` with the block size rounded up to a multiple
-    of the quantization block, so schedule blocks and quantization blocks
-    never straddle each other (one scale vector per schedule block)."""
-    size = flat.shape[0]
-    bs = -(-(-(-size // n)) // qblock) * qblock
-    pad = n * bs - size
-    flat = jnp.pad(flat, (0, pad))
-    blocks = flat.reshape(n, bs)
-    garbage = jnp.zeros((1, bs), flat.dtype)
-    return jnp.concatenate([blocks, garbage], axis=0), bs, pad
+def _split_blocks(flat: jnp.ndarray, n: int, step,
+                  qblock: Optional[int] = None):
+    """Split a flat vector into n blocks + 1 garbage slot, ``[n+1,
+    *slot]`` in the round step's slot layout (``ceil(len/n)`` elements
+    per block, zero padded at the tail).  With ``qblock`` the blocks
+    hold whole quantization blocks, so schedule blocks and quantization
+    blocks never straddle each other (one scale vector per schedule
+    block).  Returns ``(buffer, slot)``."""
+    slot = step.slot_shape(-(-flat.shape[0] // n), flat.dtype, qblock)
+    flat = jnp.pad(flat, (0, n * math.prod(slot) - flat.shape[0]))
+    blocks = flat.reshape((n,) + slot)
+    garbage = jnp.zeros((1,) + slot, flat.dtype)
+    return jnp.concatenate([blocks, garbage], axis=0), slot
 
 
 def _leaf_elems(shape: Tuple[int, ...]) -> int:
@@ -267,8 +258,8 @@ def _bcast_phase(flats, n, recv_slots, send_slots, perms, axis_name, r, step,
     R = recv_t.shape[0]
     bufs, msgs, sizes = [], [], []
     for flat in flats:
-        buf, _, _ = _split_blocks(flat, n)
-        buf = buf[None]                               # [1, n+1, bs]
+        buf, _ = _split_blocks(flat, n, step)
+        buf = buf[None]                               # [1, n+1, *slot]
         bufs.append(buf)
         sizes.append(flat.shape[0])
         msgs.append(step.pack(buf, send_t[0, r][None]))
@@ -307,13 +298,14 @@ def _reduce_phase(flats, n, fwd_slots, acc_slots, perms, axis_name, r,
     garbage = jnp.full((1,), n, jnp.int32)
     bufs, msgs, sizes = [], [], []
     for flat, ident in zip(flats, idents):
-        buf, bs, _ = _split_blocks(flat, n)           # [n+1, bs]
+        buf, slot = _split_blocks(flat, n, step)      # [n+1, *slot]
         buf = jnp.concatenate(
-            [buf, jnp.full((1, bs), ident, buf.dtype)], axis=0
-        )[None]                                       # [1, n+2, bs]
+            [buf, jnp.full((1,) + slot, ident, buf.dtype)], axis=0
+        )[None]                                       # [1, n+2, *slot]
         # Initial capture+drain of round 0's forwarded partial.
         buf, msg = step.acc_shuffle(
-            buf, jnp.zeros((1, bs), buf.dtype), garbage, F[0, r][None], op=op)
+            buf, jnp.zeros((1,) + slot, buf.dtype), garbage, F[0, r][None],
+            op=op)
         bufs.append(buf)
         msgs.append(msg)
         sizes.append(flat.shape[0])
@@ -353,9 +345,10 @@ def _allgather_phase(flats, n, recv_slots, skips, perms, axis_name, r,
     bufs, sizes = [], []
     for flat in flats:
         # buffers[j] holds root j's blocks; only the own row is filled.
-        own, _, _ = _split_blocks(flat, n)            # [n+1, bs]
+        own, _ = _split_blocks(flat, n, step)         # [n+1, *slot]
         buf = jnp.zeros((p,) + own.shape, flat.dtype)
-        buf = jax.lax.dynamic_update_slice(buf, own[None], (r, 0, 0))
+        buf = jax.lax.dynamic_update_slice(buf, own[None],
+                                           (r,) + (0,) * own.ndim)
         bufs.append(buf)
         sizes.append(flat.shape[0])
     msgs = [step.pack(buf, send_slots_at(0)) for buf in bufs]
@@ -373,7 +366,7 @@ def _allgather_phase(flats, n, recv_slots, skips, perms, axis_name, r,
                         bufs[i], got[i], S[t][base], send_slots_at(t + 1))
             else:
                 bufs[i] = step.unpack(bufs[i], got[i], S[t][base])
-    return [buf[:, :n, :].reshape(p, -1)[:, :size].reshape(-1)
+    return [buf[:, :n].reshape(p, -1)[:, :size].reshape(-1)
             for buf, size in zip(bufs, sizes)]
 
 
@@ -382,8 +375,8 @@ def _qreduce_phase(flats, n, fwd_slots, acc_slots, perms, axis_name, r, step,
     """Quantized-wire reversed (sum) rounds along ``axis_name``: the wire
     carries int8 blocks + per-qblock f32 scales; every requantization's
     error is accumulated into a per-slot error buffer on the rank that
-    generated it.  Returns per-leaf ``(buf, err, bs, size)`` with buf/err
-    the [1, n+2, bs] f32 buffers (root row of buf holds the lossy sum;
+    generated it.  Returns per-leaf ``(buf, err, nb, size)`` with buf/err
+    the [1, n+2, *slot] f32 buffers (root row of buf holds the lossy sum;
     err holds each rank's locally generated error in SUM units)."""
     F = jnp.asarray(fwd_slots)  # [R, p] static slot tables (root row
     A = jnp.asarray(acc_slots)  # pinned to the identity slot n+1)
@@ -391,23 +384,23 @@ def _qreduce_phase(flats, n, fwd_slots, acc_slots, perms, axis_name, r, step,
     garbage = jnp.full((1,), n, jnp.int32)
     bufs, errs, qmsgs, smsgs, metas = [], [], [], [], []
     for flat in flats:
-        buf, bs, _ = _split_blocks_q(flat, n, qblock)  # [n+1, bs]
-        nb = bs // qblock
+        buf, slot = _split_blocks(flat, n, step, qblock)  # [n+1, *slot]
+        nb = math.prod(slot) // qblock
         # slot n+1 is the sum identity (zero), matching _reduce_phase.
         buf = jnp.concatenate(
-            [buf, jnp.zeros((1, bs), buf.dtype)], axis=0
-        )[None]                                        # [1, n+2, bs]
+            [buf, jnp.zeros((1,) + slot, buf.dtype)], axis=0
+        )[None]                                        # [1, n+2, *slot]
         err = jnp.zeros_like(buf)
         # Initial capture+drain of round 0's forwarded partial (zero
         # message: dequant(0, 0) == 0 folds into the garbage slot).
         buf, err, qm, sm = step.qacc_shuffle(
-            buf, err, jnp.zeros((1, bs), jnp.int8),
+            buf, err, jnp.zeros((1,) + slot, jnp.int8),
             jnp.zeros((1, nb), jnp.float32), garbage, F[0, r][None])
         bufs.append(buf)
         errs.append(err)
         qmsgs.append(qm)
         smsgs.append(sm)
-        metas.append((bs, flat.shape[0]))
+        metas.append((nb, flat.shape[0]))
     for t in range(R):
         got_q = [jax.lax.ppermute(m, axis_name, perms[t]) for m in qmsgs]
         got_s = [jax.lax.ppermute(m, axis_name, perms[t]) for m in smsgs]
@@ -442,18 +435,18 @@ def _quantized_allreduce_core(flats, n, fwd_slots, acc_slots, recv_slots,
 
     reduced = _qreduce_phase(flats, n, fwd_slots, acc_slots, red_perms,
                              axis_name, r, step, qblock)
-    q_flats, s_flats, err_flats, sizes, bss = [], [], [], [], []
-    for buf, err, bs, size in reduced:
-        nb = bs // qblock
-        data = buf[0, :n]                              # [n, bs]
+    q_flats, s_flats, err_flats, sizes, nbs = [], [], [], [], []
+    for buf, err, nb, size in reduced:
+        data = buf[0, :n]                              # [n, *slot]
         q, sc = quant_blocks(data.reshape(n * nb, qblock))
-        eps = quant_error(data.reshape(n * nb, qblock), q, sc).reshape(n, bs)
+        eps = quant_error(data.reshape(n * nb, qblock), q,
+                          sc).reshape(data.shape)
         is_root = r == root
         # Non-root rows were drained by the reduce, but capped re-sends
         # can leave stale partials in slot n-1 -- zero them exactly as
         # _lower_broadcast zeroes non-root payloads.
         q_flats.append(jnp.where(is_root, q.reshape(-1),
-                                 jnp.zeros((n * bs,), jnp.int8)))
+                                 jnp.zeros((q.size,), jnp.int8)))
         s_flats.append(jnp.where(is_root, sc.reshape(-1),
                                  jnp.zeros((n * nb,), jnp.float32)))
         # The final quantization error belongs to the root (the rank
@@ -461,14 +454,13 @@ def _quantized_allreduce_core(flats, n, fwd_slots, acc_slots, recv_slots,
         e = err[0, :n] + jnp.where(is_root, eps, jnp.zeros_like(eps))
         err_flats.append(e.reshape(-1))
         sizes.append(size)
-        bss.append(bs)
+        nbs.append(nb)
     outs = _bcast_phase(q_flats + s_flats, n, recv_slots, send_slots,
                         bc_perms, axis_name, r, step)
     L = len(q_flats)
     sums, errs = [], []
     for i in range(L):
-        bs, size = bss[i], sizes[i]
-        nb = bs // qblock
+        nb, size = nbs[i], sizes[i]
         red = dequant_blocks(
             outs[i].reshape(n * nb, qblock),
             outs[L + i].reshape(n * nb, 1),
@@ -562,7 +554,7 @@ def _lower_broadcast(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
                             axis_name, r, step, overlap=overlap)
         return tuple(f.reshape(shape) for f, shape in zip(outs, shapes))
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name),) * L,
@@ -595,7 +587,7 @@ def _lower_allgather(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
             for f, shape in zip(outs, shapes)
         )
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name),) * L,
@@ -616,46 +608,50 @@ def _lower_allgatherv(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
     perms = [_rot_perm(p, bundle.skip[int(k)]) for k in ks]
     skips = [int(bundle.skip[int(k)]) for k in ks]
     caps = [shape[1] for shape, _ in spec.leaves]
-    # Static per-(leaf, root) block sizes: the wire volume tracks
+    # Static per-(leaf, root) slot shapes: the wire volume tracks
     # sum(sizes), not p*max(sizes) (paper Figure 2's degenerate case).
-    bs_all = [[max(1, -(-s // n)) for s in sizes] for sizes in sizes_canon]
+    slots_all = [[step.slot_shape(max(1, -(-s // n)), dtype) for s in sizes]
+                 for (_, dtype), sizes in zip(spec.leaves, sizes_canon)]
     L = spec.num_leaves
 
     def body(*shards):
         r = jax.lax.axis_index(axis_name)
         S = jnp.asarray(recv_slots)  # [R, p] static slot table
         allbufs: List[List[jnp.ndarray]] = []
-        for xs, bs_j, cap in zip(shards, bs_all, caps):
+        for xs, slots, cap in zip(shards, slots_all, caps):
             flat = xs.reshape(-1)  # own contribution padded to cap
             bufs = []
             for j in range(p):
-                pj = jnp.pad(flat[: min(cap, n * bs_j[j])],
-                             (0, max(0, n * bs_j[j] - cap)))
+                e = math.prod(slots[j])
+                pj = jnp.pad(flat[: min(cap, n * e)],
+                             (0, max(0, n * e - cap)))
                 own = jnp.concatenate(
-                    [pj[: n * bs_j[j]].reshape(n, bs_j[j]),
-                     jnp.zeros((1, bs_j[j]), xs.dtype)], axis=0)
+                    [pj[: n * e].reshape((n,) + slots[j]),
+                     jnp.zeros((1,) + slots[j], xs.dtype)], axis=0)
                 bufs.append(jnp.where(r == j, own, jnp.zeros_like(own)))
             allbufs.append(bufs)
         for t in range(R):
             sk = skips[t]
             gots, all_slots = [], []
-            for bufs, bs_j in zip(allbufs, bs_all):
+            for bufs in allbufs:
                 parts, slots_r = [], []
                 for j in range(p):
                     ss = S[t][(r - j + sk) % p]
                     slots_r.append(S[t][(r - j) % p])
-                    parts.append(step.pack(bufs[j][None], ss[None])[0])
-                msg = jnp.concatenate(parts)  # [sum bs_j]
+                    parts.append(
+                        step.pack(bufs[j][None], ss[None])[0].reshape(-1))
+                msg = jnp.concatenate(parts)  # [sum of slot sizes]
                 gots.append(jax.lax.ppermute(msg, axis_name, perms[t]))
                 all_slots.append(slots_r)
-            for bufs, bs_j, got, slots_r in zip(allbufs, bs_all, gots,
-                                                all_slots):
+            for bufs, slots, got, slots_r in zip(allbufs, slots_all, gots,
+                                                 all_slots):
                 o = 0
                 for j in range(p):
-                    piece = got[o: o + bs_j[j]][None]
+                    e = math.prod(slots[j])
+                    piece = got[o: o + e].reshape(slots[j])[None]
                     bufs[j] = step.unpack(bufs[j][None], piece,
                                           slots_r[j][None])[0]
-                    o += bs_j[j]
+                    o += e
         outs = []
         for bufs, sizes, cap in zip(allbufs, sizes_canon, caps):
             rows = []
@@ -665,7 +661,7 @@ def _lower_allgatherv(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
             outs.append(jnp.stack(rows))
         return tuple(outs)
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis_name),) * L,
         out_specs=(P(),) * L, check_vma=False,
     )
@@ -697,7 +693,7 @@ def _lower_reduce(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
             for f, shape in zip(outs, shapes)
         )
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name),) * L,
@@ -717,6 +713,8 @@ def _lower_reduce_scatter(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
     R = len(ks)
     perms = [_rot_perm(p, (p - bundle.skip[int(k)]) % p) for k in ks]
     shard_l = [shape[1] // p for shape, _ in spec.leaves]
+    slot_l = [step.slot_shape(max(1, -(-shard // n)), _acc_dtype(dt))
+              for shard, (_, dt) in zip(shard_l, spec.leaves)]
     L = spec.num_leaves
 
     def body(*shards):
@@ -726,24 +724,24 @@ def _lower_reduce_scatter(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
         base = (r - jnp.arange(p)) % p
         garbage = jnp.full((p,), n, jnp.int32)
         bufs, msgs, meta = [], [], []
-        for xs, shard in zip(shards, shard_l):
+        for xs, shard, slot in zip(shards, shard_l, slot_l):
             rows = xs[0].reshape(p, shard)            # contribution per root
-            bs = -(-shard // n)
-            rows = jnp.pad(rows, ((0, 0), (0, n * bs - shard)))
+            rows = jnp.pad(rows, ((0, 0), (0, n * math.prod(slot) - shard)))
             # Partials accumulate in _acc_dtype: native for ints (so the
             # sums are bit-exact) and >= float32 floats, widened to
             # float32 for bf16/f16 stability.
             buf = jnp.concatenate(
-                [rows.reshape(p, n, bs), jnp.zeros((p, 1, bs), xs.dtype)],
+                [rows.reshape((p, n) + slot),
+                 jnp.zeros((p, 1) + slot, xs.dtype)],
                 axis=1,
             ).astype(_acc_dtype(xs.dtype))
             # Initial capture+drain of round 0's forwarded partials.
             buf, msg = step.acc_shuffle(
-                buf, jnp.zeros((p, bs), buf.dtype), garbage, F[0][base],
+                buf, jnp.zeros((p,) + slot, buf.dtype), garbage, F[0][base],
                 op="sum")
             bufs.append(buf)
             msgs.append(msg)
-            meta.append((shard, bs, xs.dtype))
+            meta.append((shard, slot, xs.dtype))
         for t in range(R):
             got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
             nxt = F[t + 1][base] if t + 1 < R else garbage
@@ -756,12 +754,13 @@ def _lower_reduce_scatter(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
                     bufs[i], msgs[i] = step.acc_shuffle(
                         bufs[i], got[i], A[t][base], nxt, op="sum")
         outs = []
-        for buf, (shard, bs, dt) in zip(bufs, meta):
-            own = jax.lax.dynamic_slice(buf, (r, 0, 0), (1, n, bs))
+        for buf, (shard, slot, dt) in zip(bufs, meta):
+            own = jax.lax.dynamic_slice(buf, (r,) + (0,) * (buf.ndim - 1),
+                                        (1, n) + slot)
             outs.append(own.reshape(-1)[:shard].astype(dt)[None])
         return tuple(outs)
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name),) * L,
@@ -796,7 +795,7 @@ def _lower_quantized_allreduce(mesh: Mesh, axis_name: str,
         return (tuple(f.reshape(s) for f, s in zip(sums, shapes))
                 + tuple(f.reshape(s) for f, s in zip(errs, shapes)))
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name),) * L,
@@ -1172,6 +1171,13 @@ class CirculantComm:
                 axis_name=self.axis_name, qblock=qblock, overlap=overlap,
                 _execute=ex)
 
+        # The round step rejects payloads it cannot lay out (64-bit
+        # leaves or an untileable qblock on the compiled Pallas path)
+        # here, at plan time, rather than on the first call.
+        step = get_round_step(self.backend)
+        for _, dt in spec.leaves:
+            step.slot_shape(1, _acc_dtype(dt) if kind == "reduce_scatter"
+                            else dt, qblock)
         bundle = get_bundle(p, root)
         mesh, axis = self.mesh, self.axis_name
         if kind == "broadcast":
@@ -1288,8 +1294,8 @@ def get_comm(mesh: Mesh, axis_name: str, *, backend: str = "jnp",
 #
 # Single-process executions of the full collectives with the R rows of
 # the batched kernels standing in for the p ranks and the network
-# exchange realized as a row rotation (ppermute's rotation r -> (r+s)%p
-# is exactly jnp.roll along the rank axis).  The simulator runs these
+# exchange realized as a rotation of the rank axis (ppermute's
+# r -> (r+s)%p, :func:`_rotate`).  The simulator runs these
 # next to its message-passing reference and asserts bit-exact agreement
 # -- the certification path for the Pallas backend on CPU CI.  Plans
 # are cached like their device siblings: slot tables and the step
@@ -1303,14 +1309,28 @@ def _as_blocks(values: np.ndarray, lead: int) -> np.ndarray:
         else arr.reshape(arr.shape[: lead + 1] + (1,))
 
 
-def _x64():
-    """Certification runs in the values' own precision: without this,
-    ``jnp.asarray`` silently downcasts the reference's int64/float64
-    payloads and "bit-exact" would be vacuous (or int32-overflow wrong).
-    """
-    from jax.experimental import enable_x64
+def _rotate(msg, shift: int):
+    """The exchange of a host plan: rank r's message moves to rank
+    ``(r + shift) % p`` (``ppermute``'s rotation on the leading rank
+    axis).  A static row gather rather than ``jnp.roll``: the TPU
+    compiler aborts on the roll's concatenation of sublane-unaligned
+    row slices of a [p, bs] array (jax 0.9.0 / libtpu, v5e)."""
+    p = msg.shape[0]
+    return msg[(np.arange(p) - shift) % p]
 
-    return enable_x64()
+
+def _to_slots(vals: np.ndarray, slot: Tuple[int, ...]) -> np.ndarray:
+    """[..., bs] host blocks -> [..., *slot], the round step's slot
+    layout (zero padded at the tail of each block)."""
+    pad = math.prod(slot) - vals.shape[-1]
+    vals = np.pad(vals, [(0, 0)] * (vals.ndim - 1) + [(0, pad)])
+    return vals.reshape(vals.shape[:-1] + tuple(slot))
+
+
+def _from_slots(buf, lead: int, bs: int) -> np.ndarray:
+    """Inverse of :func:`_to_slots` after ``lead`` leading axes."""
+    a = np.asarray(buf)
+    return a.reshape(a.shape[:lead] + (-1,))[..., :bs]
 
 
 @jax.jit
@@ -1366,14 +1386,16 @@ class HostDataPlan:
         p, n = self.p, self.n
         recv_slots, send_slots = self.slots
         vals = _as_blocks(values, 0)                 # [n, bs]
-        buf = np.zeros((p, n + 1, vals.shape[-1]), vals.dtype)
-        buf[self.root, :n] = vals
+        bs = vals.shape[-1]
+        slot = self.step.slot_shape(bs, vals.dtype)
+        buf = np.zeros((p, n + 1) + slot, vals.dtype)
+        buf[self.root, :n] = _to_slots(vals, slot)
         R = len(self.ks)
-        with _x64():
+        with jax.enable_x64(True):
             buf = jnp.asarray(buf)
             msg = self.step.pack(buf, jnp.asarray(send_slots[0]))
             for t in range(R):
-                got = jnp.roll(msg, self.skips[t], axis=0)
+                got = _rotate(msg, self.skips[t])
                 if t + 1 < R:
                     if self.overlap:
                         pre = self.step.pack(
@@ -1388,7 +1410,7 @@ class HostDataPlan:
                 else:
                     buf = self.step.unpack(buf, got,
                                            jnp.asarray(recv_slots[t]))
-            return np.asarray(buf)[:, :n]
+            return _from_slots(np.asarray(buf)[:, :n], 2, bs)
 
     def _run_allgather(self, values: np.ndarray) -> np.ndarray:
         """``values``: [p, n(, bs)] per-root payloads -> final
@@ -1397,22 +1419,24 @@ class HostDataPlan:
         (recv_slots,) = self.slots
         vals = _as_blocks(values, 1)                 # [p, n, bs]
         bs = vals.shape[-1]
-        buf = np.zeros((p, p, n + 1, bs), vals.dtype)
+        slot = self.step.slot_shape(bs, vals.dtype)
+        tiled = _to_slots(vals, slot)
+        buf = np.zeros((p, p, n + 1) + slot, vals.dtype)
         for j in range(p):
-            buf[j, j, :n] = vals[j]
+            buf[j, j, :n] = tiled[j]
         base = (np.arange(p)[:, None] - np.arange(p)[None, :]) % p
         R = len(self.ks)
 
         def slots(t, shift):
             return jnp.asarray(recv_slots[t][(base + shift) % p].reshape(-1))
 
-        with _x64():
-            buf = jnp.asarray(buf.reshape(p * p, n + 1, bs))
+        with jax.enable_x64(True):
+            buf = jnp.asarray(buf.reshape((p * p, n + 1) + slot))
             msg = self.step.pack(buf, slots(0, self.skips[0]))
             for t in range(R):
                 sk = self.skips[t]
-                got = jnp.roll(msg.reshape(p, p, bs), sk,
-                               axis=0).reshape(p * p, bs)
+                got = _rotate(msg.reshape((p, p) + slot),
+                              sk).reshape((p * p,) + slot)
                 if t + 1 < R:
                     if self.overlap:
                         nxt = slots(t + 1, self.skips[t + 1])
@@ -1425,7 +1449,9 @@ class HostDataPlan:
                             slots(t + 1, self.skips[t + 1]))
                 else:
                     buf = self.step.unpack(buf, got, slots(t, 0))
-            return np.asarray(buf).reshape(p, p, n + 1, bs)[:, :, :n]
+            return _from_slots(
+                np.asarray(buf).reshape((p, p, n + 1) + slot)[:, :, :n],
+                3, bs)
 
     def _run_reduce(self, values: np.ndarray) -> np.ndarray:
         """``values``: [p, n(, bs)] per-rank contributions -> final
@@ -1436,21 +1462,24 @@ class HostDataPlan:
         fwd_slots, acc_slots = self.slots
         vals = _as_blocks(values, 1)                 # [p, n, bs]
         bs = vals.shape[-1]
+        slot = self.step.slot_shape(bs, vals.dtype)
         ident = op_identity(self.op, vals.dtype)
         npbuf = np.concatenate(
-            [vals, np.zeros((p, 1, bs), vals.dtype),         # garbage slot n
-             np.full((p, 1, bs), ident, vals.dtype)], axis=1)  # identity n+1
+            [_to_slots(vals, slot),
+             np.zeros((p, 1) + slot, vals.dtype),         # garbage slot n
+             np.full((p, 1) + slot, ident, vals.dtype)],  # identity n+1
+            axis=1)
         R = len(self.ks)
-        with _x64():
+        with jax.enable_x64(True):
             buf = jnp.asarray(npbuf)
             garbage = jnp.full((p,), n, jnp.int32)
             # Initial capture+drain of round 0's forwarded partials (the
             # acc part folds a zero message into the garbage slot).
             buf, msg = self.step.acc_shuffle(
-                buf, jnp.zeros((p, bs), buf.dtype), garbage,
+                buf, jnp.zeros((p,) + slot, buf.dtype), garbage,
                 jnp.asarray(fwd_slots[0]), op=self.op)
             for t in range(R):
-                got = jnp.roll(msg, -self.skips[t], axis=0)
+                got = _rotate(msg, -self.skips[t])
                 nxt = (jnp.asarray(fwd_slots[t + 1]) if t + 1 < R
                        else garbage)
                 if self.overlap:
@@ -1461,7 +1490,7 @@ class HostDataPlan:
                 else:
                     buf, msg = self.step.acc_shuffle(
                         buf, got, jnp.asarray(acc_slots[t]), nxt, op=self.op)
-            return np.asarray(buf)[:, :n]
+            return _from_slots(np.asarray(buf)[:, :n], 2, bs)
 
     def _run_quantized(self, values: np.ndarray):
         """``values``: [p, n(, bs)] per-rank f32 contributions (bs a
@@ -1484,20 +1513,22 @@ class HostDataPlan:
         if bs % qb:
             raise ValueError(f"block size {bs} not a multiple of "
                              f"qblock {qb}")
-        nb = bs // qb
+        slot = self.step.slot_shape(bs, np.float32, qb)
+        nb = math.prod(slot) // qb          # quantization blocks per slot
         npbuf = np.concatenate(
-            [vals, np.zeros((p, 2, bs), np.float32)], axis=1)  # n: garbage,
-        buf = jnp.asarray(npbuf)                               # n+1: identity
+            [_to_slots(vals, slot),
+             np.zeros((p, 2) + slot, np.float32)], axis=1)  # n: garbage,
+        buf = jnp.asarray(npbuf)                            # n+1: identity
         err = jnp.zeros_like(buf)
         garbage = jnp.full((p,), n, jnp.int32)
         buf, err, qm, sm = self.step.qacc_shuffle(
-            buf, err, jnp.zeros((p, bs), jnp.int8),
+            buf, err, jnp.zeros((p,) + slot, jnp.int8),
             jnp.zeros((p, nb), jnp.float32), garbage,
             jnp.asarray(fwd_slots[0]))
         R = len(red_skips)
         for t in range(R):
-            gq = jnp.roll(qm, -red_skips[t], axis=0)
-            gs = jnp.roll(sm, -red_skips[t], axis=0)
+            gq = _rotate(qm, -red_skips[t])
+            gs = _rotate(sm, -red_skips[t])
             nxt = (jnp.asarray(fwd_slots[t + 1]) if t + 1 < R else garbage)
             buf, err, qm, sm = self.step.qacc_shuffle(
                 buf, err, gq, gs, jnp.asarray(acc_slots[t]), nxt)
@@ -1506,20 +1537,21 @@ class HostDataPlan:
         # so the error capture has the same fused multiply-add rounding
         # as the in-round captures (eager jnp materializes the f32
         # product and rounds twice).
-        droot = buf[self.root, :n]                             # [n, bs]
+        droot = buf[self.root, :n]                          # [n, *slot]
         q, sc, eps = _jit_requant(droot.reshape(n * nb, qb))
-        eps = eps.reshape(n, bs)
-        err = err.at[self.root, :n].add(eps)
-        qbuf = jnp.zeros((p, n + 1, bs), jnp.int8)
-        qbuf = qbuf.at[self.root, :n].set(q.reshape(n, bs))
-        sbuf = jnp.zeros((p, n + 1, nb), jnp.float32)
-        sbuf = sbuf.at[self.root, :n].set(sc.reshape(n, nb))
+        err = err.at[self.root, :n].add(eps.reshape(droot.shape))
+        qbuf = jnp.zeros((p, n + 1) + slot, jnp.int8)
+        qbuf = qbuf.at[self.root, :n].set(q.reshape(droot.shape))
+        sslot = self.step.slot_shape(nb, np.float32)
+        sbuf = jnp.zeros((p, n + 1) + sslot, jnp.float32)
+        sbuf = sbuf.at[self.root, :n].set(
+            _to_slots(np.asarray(sc).reshape(n, nb), sslot))
         Rb = len(bc_skips)
         msgq = self.step.pack(qbuf, jnp.asarray(send_slots[0]))
         msgs_ = self.step.pack(sbuf, jnp.asarray(send_slots[0]))
         for t in range(Rb):
-            gq = jnp.roll(msgq, bc_skips[t], axis=0)
-            gs = jnp.roll(msgs_, bc_skips[t], axis=0)
+            gq = _rotate(msgq, bc_skips[t])
+            gs = _rotate(msgs_, bc_skips[t])
             if t + 1 < Rb:
                 qbuf, msgq = self.step.shuffle(
                     qbuf, gq, jnp.asarray(recv_slots[t]),
@@ -1532,11 +1564,12 @@ class HostDataPlan:
                                         jnp.asarray(recv_slots[t]))
                 sbuf = self.step.unpack(sbuf, gs,
                                         jnp.asarray(recv_slots[t]))
+        scales = sbuf[:, :n].reshape(p, n, -1)[..., :nb]
         out = dequant_blocks(
             qbuf[:, :n].reshape(p * n * nb, qb),
-            sbuf[:, :n].reshape(p * n * nb, 1),
-        ).reshape(p, n, bs)
-        return np.asarray(out), np.asarray(err)[:, :n]
+            scales.reshape(p * n * nb, 1),
+        ).reshape(p, n, nb * qb)[..., :bs]
+        return np.asarray(out), _from_slots(np.asarray(err)[:, :n], 2, bs)
 
 
 def host_plan(kind: str, p: int, n: int, *, root: int = 0, op: str = "sum",

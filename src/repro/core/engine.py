@@ -166,9 +166,12 @@ class ScheduleBundle:
         Round i uses schedule column k = i % q with the phase offset
         folded in: the effective block index is ``sched[r][k] + offset``
         (off_i = q*((i-k)//q) - x; the two adjustment loops at the top of
-        Algorithm 1, precomputed per round).
+        Algorithm 1, precomputed per round).  Empty for p = 1: a one-rank
+        axis has no rounds.
         """
         q, x = self.q, self.virtual_rounds(n)
+        if q == 0:
+            return []
         out = []
         for i in range(x, n + q - 1 + x):
             k = i % q

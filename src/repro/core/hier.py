@@ -60,7 +60,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .costmodel import DEFAULT_MODEL, CommModel, optimal_hier_blocks
 from .engine import cached_plan, get_bundle
-from .jaxcompat import shard_map as _shard_map
 from .roundstep import (
     BACKENDS,
     PhaseStatic,
@@ -265,7 +264,7 @@ def _lower_hier(mesh: Mesh, inter_axis: str, intra_axis: str, kind: str,
             for f, shape in zip(flats, shapes))
 
     replicated_out = kind == "allgather"
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P((inter_axis, intra_axis)),) * L,

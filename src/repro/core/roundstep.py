@@ -11,9 +11,11 @@ shares one per-round inner step on its block buffers:
     exchange -> ``accumulate`` the incoming partial (sum/max).
 
 :class:`RoundStep` is that step as a small backend interface.  Buffers
-are ``[R, nslots, bs]`` arrays (R rows: one per rank in the batched
+are ``[R, nslots, *slot]`` arrays (R rows: one per rank in the batched
 simulator data plane, one per root in the all-gather family, a single
-row inside a per-rank ``shard_map`` body); slot vectors are ``[R]``
+row inside a per-rank ``shard_map`` body), with the slot layout each
+backend asks for through :meth:`RoundStep.slot_shape` (flat ``(bs,)``
+for jnp, a ``(rows, lanes)`` tile stack for Pallas); slot vectors are ``[R]``
 int32 columns of the engine's per-round tables
 (:meth:`ScheduleBundle.per_round_tables` /
 :meth:`ScheduleBundle.reversed_per_round_tables`).
@@ -252,8 +254,16 @@ class RoundStep:
 
     backend: str
 
+    def slot_shape(self, bs: int, dtype,
+                   qblock: Optional[int] = None) -> Tuple[int, ...]:
+        """Array shape of one buffer slot holding ``bs`` elements of
+        ``dtype`` (zero padded as the backend needs; quantized-wire
+        slots hold whole ``qblock``-element quantization blocks).
+        Raises ``ValueError`` for a slot this backend cannot run."""
+        raise NotImplementedError
+
     def pack(self, buf, idx):
-        """[R, S, B], [R] -> [R, B]: out[r] = buf[r, idx[r]]."""
+        """[R, S, *slot], [R] -> [R, *slot]: out[r] = buf[r, idx[r]]."""
         raise NotImplementedError
 
     def unpack(self, buf, msg, idx):
@@ -307,6 +317,11 @@ class JnpRoundStep(RoundStep):
 
     backend = "jnp"
 
+    def slot_shape(self, bs, dtype, qblock=None):
+        if qblock is not None:
+            bs = -(-bs // qblock) * qblock
+        return (int(bs),)
+
     def pack(self, buf, idx):
         return _jnp_call("block_pack_ref", buf, idx)
 
@@ -357,7 +372,8 @@ class PallasRoundStep(RoundStep):
     HBM blocks to DMA.  ``interpret=None`` auto-detects the platform
     (compiled on TPU, interpret-mode on CPU CI).  Calls route through
     the jit'd :mod:`repro.kernels.ops` wrappers, so eager host-side use
-    hits the compile cache."""
+    hits the compile cache.  Slots are ``(rows, lanes)`` tile stacks
+    (:func:`repro.kernels.block_pack.slot_shape`)."""
 
     backend = "pallas"
 
@@ -365,6 +381,20 @@ class PallasRoundStep(RoundStep):
         from repro.kernels.ops import resolve_interpret
 
         self.interpret = resolve_interpret(interpret)
+
+    def slot_shape(self, bs, dtype, qblock=None):
+        from repro.kernels.block_pack import LANES, slot_shape, tileable
+
+        if not self.interpret:
+            if not tileable(dtype):
+                raise ValueError(
+                    f"{np.dtype(dtype).name} slots cannot be tiled for the "
+                    "compiled Pallas round step (use 8/16/32-bit payloads)")
+            if qblock is not None and qblock % LANES:
+                raise ValueError(
+                    f"qblock={qblock} is not a multiple of {LANES} lanes; "
+                    "the compiled quantized round step cannot tile it")
+        return slot_shape(bs, dtype, qblock)
 
     def pack(self, buf, idx):
         from repro.kernels.ops import schedule_pack

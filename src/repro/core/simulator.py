@@ -20,6 +20,10 @@ CPU CI without any devices.  Certification is routed through the cached
 host data plans of :mod:`repro.core.comm` (:func:`~repro.core.comm.
 host_plan`), so sweeping a (p, n, root, op, backend) grid resolves slot
 tables and step handles once per combination.
+
+:func:`replay_quantized_allreduce` is the matching reference for the
+lossy kind: a plain-NumPy replay of the quantized allreduce data plane
+that the round-step backends must reproduce.
 """
 
 from __future__ import annotations
@@ -43,6 +47,7 @@ __all__ = [
     "simulate_hier_allreduce",
     "SimResult",
     "HierSimResult",
+    "replay_quantized_allreduce",
 ]
 
 # Reduction operators: name -> (binary combine on numpy values).  Both are
@@ -747,3 +752,115 @@ def simulate_hier_allreduce(
                 )
     res.buffers = [reduced] if keep_buffers else None
     return res
+
+
+# ------------------------------------- quantized allreduce NumPy replay
+#
+# Reference arithmetic.  Quantize (amax, scale, round, clip) is plain
+# float32, round-half-even -- both np.round and jnp.round.  The data
+# plane's accumulate (``cur + q*s``) and error capture (``x - q*s``)
+# compile to fused multiply-adds (one rounding, no intermediate f32
+# product); NumPy reproduces an f32 FMA exactly through float64: the
+# product q*s is EXACT in f64 (33-bit significand at most), so
+# f32(f64(cur) + f64(q)*f64(s)) applies the same single rounding.
+
+
+def _np_fma(a, q, s, sign=1.0):
+    """f32 fused multiply-add a + sign*q*s, emulated exactly in f64."""
+    out = (np.asarray(a, np.float64) +
+           np.float64(sign) * np.asarray(q, np.float64) *
+           np.asarray(s, np.float64)).astype(np.float32)
+    return out
+
+
+def _np_quant_blocks(x2d):
+    x2d = np.asarray(x2d, np.float32)
+    finite = np.isfinite(x2d)
+    xf = np.where(finite, x2d, np.float32(0.0)).astype(np.float32)
+    amax = np.max(np.abs(xf), axis=1, keepdims=True).astype(np.float32)
+    inv127 = np.float32(1.0) / np.float32(127.0)
+    scale = np.maximum(amax * inv127, np.float32(1e-12))
+    q = np.clip(np.round(xf / scale), -127, 127).astype(np.int8)
+    allf = finite.all(axis=1, keepdims=True)
+    return q, np.where(allf, scale, np.float32(np.nan)).astype(np.float32)
+
+
+def _np_dequant_blocks(q, scale):
+    return (q.astype(np.float32) * scale).astype(np.float32)
+
+
+def _np_quant_error(x2d, q, scale):
+    err = _np_fma(x2d, q, np.broadcast_to(scale, x2d.shape), sign=-1.0)
+    return np.where(np.isfinite(err), err, np.float32(0.0)).astype(np.float32)
+
+
+def replay_quantized_allreduce(plan, vals):
+    """Pure-NumPy replay of a quantized-allreduce host data plan
+    (:func:`repro.core.comm.host_plan`) using the plan's own slot
+    tables: reduce-phase qacc rounds (dequantize -> accumulate ->
+    requantize forward slot -> capture error -> drain), root
+    requantization, then the int8+scales broadcast phase.
+
+    ``vals``: [p, n, bs] f32 contributions.  Returns ``(out, err)`` as
+    ``plan.run(vals)`` does; every quantize, dequantize and accumulate
+    is plain NumPy, independent of the jnp and Pallas round steps."""
+    p, n, qb = plan.p, plan.n, plan.qblock
+    fwd_slots, acc_slots, recv_slots, send_slots = plan.slots
+    red_skips, bc_skips = plan.skips
+    vals = np.asarray(vals, np.float32)               # [p, n, bs]
+    bs = vals.shape[-1]
+    nb = bs // qb
+    buf = np.concatenate([vals, np.zeros((p, 2, bs), np.float32)], axis=1)
+    err = np.zeros_like(buf)
+
+    def qacc(buf, err, qmsg, smsg, acc_idx, fwd_idx):
+        qout = np.zeros((p, bs), np.int8)
+        sout = np.zeros((p, nb), np.float32)
+        for r in range(p):
+            buf[r, acc_idx[r]] = _np_fma(
+                buf[r, acc_idx[r]].reshape(nb, qb),
+                qmsg[r].reshape(nb, qb),
+                np.broadcast_to(smsg[r].reshape(nb, 1), (nb, qb)),
+            ).reshape(bs)
+            captured = buf[r, fwd_idx[r]].reshape(nb, qb)
+            q, s = _np_quant_blocks(captured)
+            err[r, fwd_idx[r]] += _np_quant_error(captured, q, s).reshape(bs)
+            buf[r, fwd_idx[r]] = 0.0
+            qout[r], sout[r] = q.reshape(bs), s.reshape(nb)
+        return qout, sout
+
+    garbage = np.full((p,), n, np.int64)
+    qm, sm = qacc(buf, err, np.zeros((p, bs), np.int8),
+                  np.zeros((p, nb), np.float32), garbage, fwd_slots[0])
+    R = len(red_skips)
+    for t in range(R):
+        gq = np.roll(qm, -red_skips[t], axis=0)
+        gs = np.roll(sm, -red_skips[t], axis=0)
+        nxt = fwd_slots[t + 1] if t + 1 < R else garbage
+        qm, sm = qacc(buf, err, gq, gs, acc_slots[t], nxt)
+
+    droot = buf[plan.root, :n].reshape(n * nb, qb)
+    q, sc = _np_quant_blocks(droot)
+    err[plan.root, :n] += _np_quant_error(droot, q, sc).reshape(n, bs)
+    qbuf = np.zeros((p, n + 1, bs), np.int8)
+    qbuf[plan.root, :n] = q.reshape(n, bs)
+    sbuf = np.zeros((p, n + 1, nb), np.float32)
+    sbuf[plan.root, :n] = sc.reshape(n, nb)
+
+    def pack(b, idx):
+        return np.stack([b[r, idx[r]] for r in range(p)])
+
+    msgq, msgs = pack(qbuf, send_slots[0]), pack(sbuf, send_slots[0])
+    Rb = len(bc_skips)
+    for t in range(Rb):
+        gq = np.roll(msgq, bc_skips[t], axis=0)
+        gs = np.roll(msgs, bc_skips[t], axis=0)
+        for r in range(p):
+            qbuf[r, recv_slots[t][r]] = gq[r]
+            sbuf[r, recv_slots[t][r]] = gs[r]
+        if t + 1 < Rb:
+            msgq = pack(qbuf, send_slots[t + 1])
+            msgs = pack(sbuf, send_slots[t + 1])
+    out = _np_dequant_blocks(qbuf[:, :n].reshape(p * n * nb, qb),
+                            sbuf[:, :n].reshape(p * n * nb, 1))
+    return out.reshape(p, n, bs), err[:, :n]

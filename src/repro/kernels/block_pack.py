@@ -18,6 +18,21 @@ the pack/unpack becomes pure DMA scheduling with zero real compute,
 exactly the paper's "packing ... bounded by the total size of all
 buffers" requirement.
 
+**Slot layout.**  The TPU compiler only accepts blocks whose last two
+dimensions are multiples of the dtype's native tile (8x128 for 32-bit,
+16x128 for 16-bit, 32x128 for 8-bit values) or span the whole array.
+A buffer slot is therefore laid out as a ``[rows, lanes]`` tile stack:
+buffers are ``[R, nslots, rows, lanes]`` and messages ``[R, rows,
+lanes]`` (:func:`slot_shape` gives the layout for a block of ``bs``
+elements; plans hold their buffers in it for every round).  The grid is
+``(R, tiles)`` -- or ``(R, tiles, 2)`` for the two-step accumulate/drain
+kernels -- where each grid point moves one ``[tile_rows, lanes]`` tile
+of at most :data:`MAX_BLOCK_BYTES`, so every kernel's double-buffered
+operands stay well inside the default scoped VMEM.  Flat ``[R, nslots,
+bs]`` buffers are accepted too: they are zero-padded to the tile and
+viewed in the slot layout for the call (a relayout per call, for tests
+and one-off use; the plans never take that path).
+
 Two *fused* kernels cover the steady state with one ``pallas_call`` per
 round instead of two:
 
@@ -30,23 +45,26 @@ round instead of two:
     partial and drain its slot to the identity
     (capture-drain-accumulate, see docs/collectives.md).
 
-All kernels run under ``interpret=True`` on CPU CI bit-exactly against
-the jnp reference backend (:mod:`repro.core.roundstep`); on TPU the same
-code compiles with the index maps lowered to DMA descriptors.  The
-fused kernels pass the buffer twice (one read-only operand, one aliased
-to the output) so no in-kernel value ever depends on reading back a
-block written earlier in the same grid -- the interpret and compiled
-modes cannot diverge.
+The kernels compile for the TPU (``interpret=False`` there) and run
+under ``interpret=True`` elsewhere, bit-exactly against the jnp
+reference backend (:mod:`repro.core.roundstep`).  The fused kernels pass
+the buffer twice (one read-only operand, one aliased to the output) so
+no in-kernel value ever depends on reading back a block written earlier
+in the same grid: the pipelined DMAs of the compiled kernel and the
+sequential interpreter see the same values (proved per schedule by
+:mod:`repro.analysis.kernelaudit`).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -55,6 +73,21 @@ from jax.experimental.pallas import tpu as pltpu
 # know the kernel module).
 from .quant_ops import dequant_blocks, quant_blocks, quant_error
 from .reduce_ops import op_combine, op_identity
+
+#: Lane width of a slot tile (the TPU vector register width).
+LANES = 128
+#: Upper bound on one operand block; with double buffering and the
+#: fused kernels' five or so operands this keeps a kernel within a few
+#: MiB of v5e's 16 MiB default scoped VMEM.
+MAX_BLOCK_BYTES = 512 * 1024
+#: Quantization blocks per tile row group: the int8 wire tile is 32 rows.
+QROWS = 32
+
+_SQ = pl.Squeezed()
+# Lane-tile index of every block: an explicit int32 so the index maps
+# stay 32-bit when traced under ``jax.enable_x64`` (the host plans'
+# certification mode), which Mosaic requires.
+_LANE0 = np.int32(0)
 
 
 def default_interpret() -> bool:
@@ -66,55 +99,136 @@ def _resolve(interpret):
     return default_interpret() if interpret is None else interpret
 
 
+# ----------------------------------------------------------- slot layout
+
+
+def sublanes(dtype) -> int:
+    """Rows of one native TPU tile: 8 for 32-bit, 16 for 16-bit, 32 for
+    8-bit values (64-bit values have no TPU tile; 8 keeps them working
+    in interpret mode)."""
+    return 8 * max(1, 4 // np.dtype(dtype).itemsize)
+
+
+def tileable(dtype) -> bool:
+    """True when the compiled kernels can hold ``dtype`` (not 64-bit)."""
+    return np.dtype(dtype).itemsize <= 4
+
+
+def slot_shape(bs: int, dtype, qblock: Optional[int] = None) -> Tuple[int, int]:
+    """``(rows, lanes)`` of a buffer slot holding ``bs`` elements.
+
+    Plain slots are ``LANES`` wide with rows padded to the dtype's tile
+    (``sublanes(dtype) * LANES`` elements).  Quantized-wire slots
+    (``qblock`` given) hold one quantization block per row, padded to
+    ``QROWS`` rows so the int8 payload tiles too.
+    """
+    if qblock is not None:
+        return -(-max(1, -(-bs // qblock)) // QROWS) * QROWS, int(qblock)
+    sub = sublanes(dtype)
+    return -(-max(1, -(-bs // LANES)) // sub) * sub, LANES
+
+
+def row_tile(rows: int, lanes: int, dtype, unit: Optional[int] = None) -> int:
+    """Rows per grid tile: the largest divisor of ``rows`` that is a
+    multiple of ``unit`` (default the dtype's sublane tile) and keeps
+    one block within :data:`MAX_BLOCK_BYTES`.  A row count that is not a
+    multiple of ``unit`` is one whole-slot tile (legal as a full-array
+    block dimension)."""
+    unit = sublanes(dtype) if unit is None else unit
+    if rows % unit:
+        return rows
+    cap = max(unit, MAX_BLOCK_BYTES // (lanes * np.dtype(dtype).itemsize))
+    best = unit
+    for d in range(unit, min(rows, cap) + 1, unit):
+        if rows % d == 0:
+            best = d
+    return best
+
+
+def _to_tiles(x, shape):
+    """[..., bs] -> [..., *shape], zero padding the tail of each slot."""
+    bs, elems = x.shape[-1], math.prod(shape)
+    if elems != bs:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, elems - bs)])
+    return x.reshape(x.shape[:-1] + tuple(shape))
+
+
+def _from_tiles(x, bs):
+    """Inverse of :func:`_to_tiles`: [..., rows, lanes] -> [..., bs]."""
+    return x.reshape(x.shape[:-2] + (-1,))[..., :bs]
+
+
+def _on_flat(kernel, buffers, msgs, *idx, **kw):
+    """Run a tiled kernel on flat [R, nslots, bs] buffers and [R, bs]
+    messages: pad each slot to the tile, call, and flatten back."""
+    bs = buffers.shape[-1]
+    shape = slot_shape(bs, buffers.dtype)
+    out = kernel(_to_tiles(buffers, shape),
+                 *(_to_tiles(m, shape) for m in msgs), *idx, **kw)
+    if isinstance(out, (tuple, list)):
+        return tuple(_from_tiles(o, bs) for o in out)
+    return _from_tiles(out, bs)
+
+
+def _grid_tiles(buffers, unit: Optional[int] = None) -> Tuple[int, int]:
+    """(tile_rows, tiles) for tiled [R, nslots, rows, lanes] buffers."""
+    rows, lanes = buffers.shape[-2:]
+    tr = row_tile(rows, lanes, buffers.dtype, unit)
+    return tr, rows // tr
+
+
 # ----------------------------------------------------------- index maps
 #
 # Every BlockSpec index map is a named module-level function so the
 # static race detector (repro.analysis.kernelaudit) can evaluate the
 # SAME map objects the pallas_call was built with over the whole grid.
-# 1-D kernels get (r, *prefetch_refs); the two-step accumulate/drain
-# kernels get (r, s, *prefetch_refs) with s the sequential sub-round.
+# 1-step kernels get (r, j, *prefetch_refs); the two-step
+# accumulate/drain kernels get (r, j, s, *prefetch_refs) with j the row
+# tile and s the sequential sub-round.  Slot blocks address
+# (row, slot, tile, 0) of [R, nslots, rows, lanes]; message blocks
+# address (row, tile, 0) of [R, rows, lanes].
 
 
-def _row_map1(r, idx_ref):
-    """[R, bs] row block of the 1-prefetch 1-D kernels (pack out)."""
-    return (r, 0)
+def _row_map(r, j, *rest):
+    """Message tile of any kernel (pack out, unpack in, fused in/out)."""
+    return (r, j, _LANE0)
 
 
-def _slot_map1(r, idx_ref):
-    """Prefetched-slot block of the 1-prefetch 1-D kernels."""
-    return (r, idx_ref[r], 0)
+def _slot_map1(r, j, idx_ref):
+    """Prefetched-slot tile of the 1-prefetch kernels (pack/unpack)."""
+    return (r, idx_ref[r], j, _LANE0)
 
 
-def _row_map2(r, ri, si):
-    """[R, bs] row block of the 2-prefetch 1-D shuffle kernel."""
-    return (r, 0)
+def _send_map(r, j, ri, si):
+    """Read-only send-slot tile of the shuffle kernel (pre-update)."""
+    return (r, si[r], j, _LANE0)
 
 
-def _send_map(r, ri, si):
-    """Read-only send-slot block of the shuffle kernel (pre-update)."""
-    return (r, si[r], 0)
+def _recv_map(r, j, ri, si):
+    """Recv-slot tile of the shuffle kernels (aliased, overwritten)."""
+    return (r, ri[r], j, _LANE0)
 
 
-def _recv_map(r, ri, si):
-    """Recv-slot block of the shuffle kernel (aliased, overwritten)."""
-    return (r, ri[r], 0)
-
-
-def _row_map_rs(r, s, ai, fi):
-    """[R, bs] row block of the two-step accumulate/drain kernels."""
-    return (r, 0)
-
-
-def _fwd_map(r, s, ai, fi):
-    """Fwd-slot block of the accumulate/drain kernels (captured and, in
+def _fwd_map(r, j, s, ai, fi):
+    """Fwd-slot tile of the accumulate/drain kernels (captured and, in
     the qacc error path, read-modify-written)."""
-    return (r, fi[r], 0)
+    return (r, fi[r], j, _LANE0)
 
 
-def _step_map(r, s, ai, fi):
-    """Aliased buffer block of the accumulate/drain kernels: the acc
+def _step_map(r, j, s, ai, fi):
+    """Aliased buffer tile of the accumulate/drain kernels: the acc
     slot at s == 0, the fwd slot at s == 1 (the drain)."""
-    return (r, jnp.where(s == 0, ai[r], fi[r]), 0)
+    return (r, jnp.where(s == 0, ai[r], fi[r]), j, _LANE0)
+
+
+def _slot_spec(tr, lanes, index_map):
+    """One [tr, lanes] tile of a [R, nslots, rows, lanes] buffer."""
+    return pl.BlockSpec((_SQ, _SQ, tr, lanes), index_map)
+
+
+def _row_spec(tr, lanes, index_map=_row_map):
+    """One [tr, lanes] tile of a [R, rows, lanes] message."""
+    return pl.BlockSpec((_SQ, tr, lanes), index_map)
 
 
 # Pallas input_output_aliases, operand-indexed INCLUDING the scalar
@@ -134,29 +248,30 @@ ACC_STAGED_ALIASES = {4: 0}      # buffers operand -> new_buffers
 def _pack_kernel(idx_ref, buf_ref, out_ref):
     # the interesting work happened in the index_map DMA; just copy VMEM->VMEM
     del idx_ref
-    out_ref[...] = buf_ref[0]
+    out_ref[...] = buf_ref[...]
 
 
 def block_pack(buffers: jnp.ndarray, idx: jnp.ndarray, *, interpret=None):
-    """buffers: [R, nslots, bs]; idx: [R] int32 slot per row -> [R, bs].
+    """buffers: [R, nslots, rows, lanes] (or flat [R, nslots, bs]);
+    idx: [R] int32 slot per row -> [R, rows, lanes] (or [R, bs]).
 
     Row r of the output is buffers[r, idx[r]]; the slot choice is the
     send schedule column for the round.
     """
-    R, nslots, bs = buffers.shape
-
+    if buffers.ndim == 3:
+        return _on_flat(block_pack, buffers, (), idx, interpret=interpret)
+    R, nslots, rows, lanes = buffers.shape
+    tr, T = _grid_tiles(buffers)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(R,),
-        in_specs=[
-            pl.BlockSpec((1, 1, bs), _slot_map1),
-        ],
-        out_specs=pl.BlockSpec((1, bs), _row_map1),
+        grid=(R, T),
+        in_specs=[_slot_spec(tr, lanes, _slot_map1)],
+        out_specs=_row_spec(tr, lanes),
     )
     return pl.pallas_call(
         _pack_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, bs), buffers.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, rows, lanes), buffers.dtype),
         interpret=_resolve(interpret),
     )(idx.astype(jnp.int32), buffers)
 
@@ -166,7 +281,7 @@ def block_pack(buffers: jnp.ndarray, idx: jnp.ndarray, *, interpret=None):
 
 def _unpack_kernel(idx_ref, msg_ref, buf_ref, out_ref):
     del idx_ref, buf_ref  # aliased with the output; untouched slots keep contents
-    out_ref[0] = msg_ref[...]
+    out_ref[...] = msg_ref[...]
 
 
 def block_unpack(buffers: jnp.ndarray, msg: jnp.ndarray, idx: jnp.ndarray,
@@ -176,21 +291,24 @@ def block_unpack(buffers: jnp.ndarray, msg: jnp.ndarray, idx: jnp.ndarray,
     Implemented with an input-output alias so untouched slots keep their
     contents (the receive schedule only writes one slot per round).
     """
-    R, nslots, bs = buffers.shape
-
+    if buffers.ndim == 3:
+        return _on_flat(block_unpack, buffers, (msg,), idx,
+                        interpret=interpret)
+    R, nslots, rows, lanes = buffers.shape
+    tr, T = _grid_tiles(buffers)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(R,),
+        grid=(R, T),
         in_specs=[
-            pl.BlockSpec((1, bs), _row_map1),
-            pl.BlockSpec((1, 1, bs), _slot_map1),
+            _row_spec(tr, lanes),
+            _slot_spec(tr, lanes, _slot_map1),
         ],
-        out_specs=pl.BlockSpec((1, 1, bs), _slot_map1),
+        out_specs=_slot_spec(tr, lanes, _slot_map1),
     )
     return pl.pallas_call(
         _unpack_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, nslots, bs), buffers.dtype),
+        out_shape=jax.ShapeDtypeStruct(buffers.shape, buffers.dtype),
         input_output_aliases=UNPACK_ALIASES,
         interpret=_resolve(interpret),
     )(idx.astype(jnp.int32), msg, buffers)
@@ -204,13 +322,13 @@ def _shuffle_kernel(recv_ref, send_ref, msg_ref, ro_ref, alias_ref,
     r = pl.program_id(0)
     del alias_ref  # aliased with outbuf; untouched slots keep contents
     # unpack: the received message lands in this row's recv slot
-    outbuf_ref[...] = msg_ref[...][None]
+    outbuf_ref[...] = msg_ref[...]
     # pack from the UPDATED buffer: when the next send slot is the slot
     # just written (the broadcast pipeline "forward what you received"),
     # the outgoing block is the message itself; otherwise it is the
     # DMA-selected old block.  No read-back of a freshly written block.
     same = recv_ref[r] == send_ref[r]
-    outmsg_ref[...] = jnp.where(same, msg_ref[...], ro_ref[0, 0])
+    outmsg_ref[...] = jnp.where(same, msg_ref[...], ro_ref[...])
 
 
 def block_shuffle(buffers: jnp.ndarray, msg: jnp.ndarray,
@@ -218,34 +336,39 @@ def block_shuffle(buffers: jnp.ndarray, msg: jnp.ndarray,
                   *, interpret=None):
     """Fused unpack(t) + pack(t+1) for the broadcast family.
 
-    buffers: [R, nslots, bs]; msg: [R, bs] received this round;
-    recv_idx/send_idx: [R] int32 slots.  Returns ``(new_buffers,
-    out_msg)`` where ``new_buffers[r, recv_idx[r]] = msg[r]`` and
-    ``out_msg[r] = new_buffers[r, send_idx[r]]`` (i.e. the pack sees the
-    unpack's write -- the round-t+1 send of a round-t delivery).
+    buffers: [R, nslots, rows, lanes] (or flat [R, nslots, bs]); msg:
+    one slot per row, received this round; recv_idx/send_idx: [R] int32
+    slots.  Returns ``(new_buffers, out_msg)`` where
+    ``new_buffers[r, recv_idx[r]] = msg[r]`` and ``out_msg[r] =
+    new_buffers[r, send_idx[r]]`` (i.e. the pack sees the unpack's write
+    -- the round-t+1 send of a round-t delivery).
     """
-    R, nslots, bs = buffers.shape
+    if buffers.ndim == 3:
+        return _on_flat(block_shuffle, buffers, (msg,), recv_idx, send_idx,
+                        interpret=interpret)
+    R, nslots, rows, lanes = buffers.shape
+    tr, T = _grid_tiles(buffers)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(R,),
+        grid=(R, T),
         in_specs=[
-            pl.BlockSpec((1, bs), _row_map2),
-            # read-only buffer view: the send block (pre-update content)
-            pl.BlockSpec((1, 1, bs), _send_map),
-            # aliased buffer: the recv block (overwritten by the kernel)
-            pl.BlockSpec((1, 1, bs), _recv_map),
+            _row_spec(tr, lanes),
+            # read-only buffer view: the send tile (pre-update content)
+            _slot_spec(tr, lanes, _send_map),
+            # aliased buffer: the recv tile (overwritten by the kernel)
+            _slot_spec(tr, lanes, _recv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bs), _recv_map),
-            pl.BlockSpec((1, bs), _row_map2),
+            _slot_spec(tr, lanes, _recv_map),
+            _row_spec(tr, lanes),
         ],
     )
     return pl.pallas_call(
         _shuffle_kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((R, nslots, bs), buffers.dtype),
-            jax.ShapeDtypeStruct((R, bs), buffers.dtype),
+            jax.ShapeDtypeStruct(buffers.shape, buffers.dtype),
+            jax.ShapeDtypeStruct((R, rows, lanes), buffers.dtype),
         ],
         input_output_aliases=SHUFFLE_ALIASES,
         interpret=_resolve(interpret),
@@ -261,7 +384,7 @@ def _shuffle_staged_kernel(recv_ref, send_ref, msg_ref, pre_ref, alias_ref,
     r = pl.program_id(0)
     del alias_ref  # aliased with outbuf; untouched slots keep contents
     # unpack: the received message lands in this row's recv slot
-    outbuf_ref[...] = msg_ref[...][None]
+    outbuf_ref[...] = msg_ref[...]
     # the round-t+1 send block was packed from the PRE-update buffer
     # (``pre``) before the exchange completed; the unpack only changed
     # the recv slot, so the staged block is stale exactly when the next
@@ -275,37 +398,41 @@ def block_shuffle_staged(buffers: jnp.ndarray, msg: jnp.ndarray,
                          send_idx: jnp.ndarray, *, interpret=None):
     """Overlap-staged variant of :func:`block_shuffle`.
 
-    ``pre`` [R, bs] is round t+1's send block packed from the buffer
-    *before* round t's delivery landed, so it can be computed while the
-    round-t exchange is still in flight.  The kernel writes ``msg`` into
-    the recv slots and selects the outgoing message as ``msg`` where
-    ``recv_idx == send_idx`` (the pipeline case -- the only slot the
-    unpack changed) and ``pre`` everywhere else.  Bit-exact vs
+    ``pre`` (one slot per row) is round t+1's send block packed from the
+    buffer *before* round t's delivery landed, so it can be computed
+    while the round-t exchange is still in flight.  The kernel writes
+    ``msg`` into the recv slots and selects the outgoing message as
+    ``msg`` where ``recv_idx == send_idx`` (the pipeline case -- the only
+    slot the unpack changed) and ``pre`` everywhere else.  Bit-exact vs
     ``block_shuffle(buffers, msg, recv_idx, send_idx)`` whenever the
     schedule writes each slot at most once (the write-once invariant the
     static auditor proves).  Returns ``(new_buffers, out_msg)``.
     """
-    R, nslots, bs = buffers.shape
+    if buffers.ndim == 3:
+        return _on_flat(block_shuffle_staged, buffers, (msg, pre), recv_idx,
+                        send_idx, interpret=interpret)
+    R, nslots, rows, lanes = buffers.shape
+    tr, T = _grid_tiles(buffers)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(R,),
+        grid=(R, T),
         in_specs=[
-            pl.BlockSpec((1, bs), _row_map2),
-            pl.BlockSpec((1, bs), _row_map2),
-            # aliased buffer: the recv block (overwritten by the kernel)
-            pl.BlockSpec((1, 1, bs), _recv_map),
+            _row_spec(tr, lanes),
+            _row_spec(tr, lanes),
+            # aliased buffer: the recv tile (overwritten by the kernel)
+            _slot_spec(tr, lanes, _recv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bs), _recv_map),
-            pl.BlockSpec((1, bs), _row_map2),
+            _slot_spec(tr, lanes, _recv_map),
+            _row_spec(tr, lanes),
         ],
     )
     return pl.pallas_call(
         _shuffle_staged_kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((R, nslots, bs), buffers.dtype),
-            jax.ShapeDtypeStruct((R, bs), buffers.dtype),
+            jax.ShapeDtypeStruct(buffers.shape, buffers.dtype),
+            jax.ShapeDtypeStruct((R, rows, lanes), buffers.dtype),
         ],
         input_output_aliases=SHUFFLE_STAGED_ALIASES,
         interpret=_resolve(interpret),
@@ -319,7 +446,7 @@ def block_shuffle_staged(buffers: jnp.ndarray, msg: jnp.ndarray,
 def _acc_shuffle_kernel(acc_ref, fwd_ref, msg_ref, ro_ref, alias_ref,
                         outbuf_ref, outmsg_ref, scratch_ref, *, op, identity):
     r = pl.program_id(0)
-    s = pl.program_id(1)
+    s = pl.program_id(2)
     # s == 0: accumulate the incoming partial into the acc slot.
     # s == 1: drain the (next round's) fwd slot to the identity.
     # The captured outgoing partial is staged through VMEM scratch at
@@ -327,15 +454,15 @@ def _acc_shuffle_kernel(acc_ref, fwd_ref, msg_ref, ro_ref, alias_ref,
     # fwd slot IS the acc slot, the old fwd block otherwise) -- never by
     # reading back a block written earlier in the grid, so interpret and
     # compiled modes agree bit-for-bit.
-    combined = op_combine(op)(alias_ref[0, 0], msg_ref[...])
+    combined = op_combine(op)(alias_ref[...], msg_ref[...])
 
     @pl.when(s == 0)
     def _():
         same = acc_ref[r] == fwd_ref[r]
-        scratch_ref[...] = jnp.where(same, combined, ro_ref[0, 0])
+        scratch_ref[...] = jnp.where(same, combined, ro_ref[...])
 
-    ident = jnp.full_like(msg_ref[...], identity)
-    outbuf_ref[...] = jnp.where(s == 0, combined, ident)[None]
+    ident = jnp.full_like(combined, identity)
+    outbuf_ref[...] = jnp.where(s == 0, combined, ident)
     outmsg_ref[...] = scratch_ref[...]
 
 
@@ -344,8 +471,9 @@ def block_acc_shuffle(buffers: jnp.ndarray, msg: jnp.ndarray,
                       *, op: str = "sum", interpret=None):
     """Fused accumulate(t) + capture/drain(t+1) for the reduce family.
 
-    buffers: [R, nslots, bs]; msg: [R, bs] incoming partials;
-    acc_idx/fwd_idx: [R] int32 slots.  Per row r, in order:
+    buffers: [R, nslots, rows, lanes] (or flat [R, nslots, bs]); msg:
+    one incoming partial per row; acc_idx/fwd_idx: [R] int32 slots.  Per
+    row r, in order:
 
       1. ``buffers[r, acc_idx[r]] op= msg[r]``   (accumulate, round t)
       2. ``out_msg[r] = buffers[r, fwd_idx[r]]`` (capture, round t+1 --
@@ -355,31 +483,35 @@ def block_acc_shuffle(buffers: jnp.ndarray, msg: jnp.ndarray,
     ``op`` is ``"sum"`` (identity 0) or ``"max"`` (identity -inf /
     integer min).  Returns ``(new_buffers, out_msg)``.
     """
-    R, nslots, bs = buffers.shape
+    if buffers.ndim == 3:
+        return _on_flat(block_acc_shuffle, buffers, (msg,), acc_idx,
+                        fwd_idx, op=op, interpret=interpret)
+    R, nslots, rows, lanes = buffers.shape
+    tr, T = _grid_tiles(buffers)
     identity = op_identity(op, buffers.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(R, 2),
+        grid=(R, T, 2),
         in_specs=[
-            pl.BlockSpec((1, bs), _row_map_rs),
-            # read-only buffer view: the fwd block (pre-update content)
-            pl.BlockSpec((1, 1, bs), _fwd_map),
-            # aliased buffer: acc block at s=0, fwd block at s=1
-            pl.BlockSpec((1, 1, bs), _step_map),
+            _row_spec(tr, lanes),
+            # read-only buffer view: the fwd tile (pre-update content)
+            _slot_spec(tr, lanes, _fwd_map),
+            # aliased buffer: acc tile at s=0, fwd tile at s=1
+            _slot_spec(tr, lanes, _step_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bs), _step_map),
-            pl.BlockSpec((1, bs), _row_map_rs),
+            _slot_spec(tr, lanes, _step_map),
+            _row_spec(tr, lanes),
         ],
-        scratch_shapes=[pltpu.VMEM((1, bs), buffers.dtype)],
+        scratch_shapes=[pltpu.VMEM((tr, lanes), buffers.dtype)],
     )
     kern = functools.partial(_acc_shuffle_kernel, op=op, identity=identity)
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((R, nslots, bs), buffers.dtype),
-            jax.ShapeDtypeStruct((R, bs), buffers.dtype),
+            jax.ShapeDtypeStruct(buffers.shape, buffers.dtype),
+            jax.ShapeDtypeStruct((R, rows, lanes), buffers.dtype),
         ],
         input_output_aliases=ACC_ALIASES,
         interpret=_resolve(interpret),
@@ -394,7 +526,7 @@ def _acc_shuffle_staged_kernel(acc_ref, fwd_ref, msg_ref, pre_ref, alias_ref,
                                outbuf_ref, outmsg_ref, scratch_ref,
                                *, op, identity):
     r = pl.program_id(0)
-    s = pl.program_id(1)
+    s = pl.program_id(2)
     # Same two-step grid as _acc_shuffle_kernel (s=0 accumulate, s=1
     # drain), but the captured outgoing partial for the non-coincident
     # case comes from ``pre`` -- the fwd block packed from the
@@ -402,15 +534,15 @@ def _acc_shuffle_staged_kernel(acc_ref, fwd_ref, msg_ref, pre_ref, alias_ref,
     # second read-only buffer view.  The accumulate only changed the acc
     # slot, so ``pre`` is stale exactly when fwd == acc; patch that case
     # with the freshly combined value.
-    combined = op_combine(op)(alias_ref[0, 0], msg_ref[...])
+    combined = op_combine(op)(alias_ref[...], msg_ref[...])
 
     @pl.when(s == 0)
     def _():
         same = acc_ref[r] == fwd_ref[r]
         scratch_ref[...] = jnp.where(same, combined, pre_ref[...])
 
-    ident = jnp.full_like(msg_ref[...], identity)
-    outbuf_ref[...] = jnp.where(s == 0, combined, ident)[None]
+    ident = jnp.full_like(combined, identity)
+    outbuf_ref[...] = jnp.where(s == 0, combined, ident)
     outmsg_ref[...] = scratch_ref[...]
 
 
@@ -420,9 +552,9 @@ def block_acc_shuffle_staged(buffers: jnp.ndarray, msg: jnp.ndarray,
                              interpret=None):
     """Overlap-staged variant of :func:`block_acc_shuffle`.
 
-    ``pre`` [R, bs] is round t+1's fwd block packed from the buffer
-    *before* round t's partial was accumulated, so it can be computed
-    while the round-t exchange is still in flight.  Per row r:
+    ``pre`` (one slot per row) is round t+1's fwd block packed from the
+    buffer *before* round t's partial was accumulated, so it can be
+    computed while the round-t exchange is still in flight.  Per row r:
 
       1. ``buffers[r, acc_idx[r]] op= msg[r]``   (accumulate, round t)
       2. ``out_msg[r]`` = the combined value where ``fwd_idx == acc_idx``
@@ -433,22 +565,26 @@ def block_acc_shuffle_staged(buffers: jnp.ndarray, msg: jnp.ndarray,
     the sequential capture also reads pre-accumulate content everywhere
     except the coincident slot.  Returns ``(new_buffers, out_msg)``.
     """
-    R, nslots, bs = buffers.shape
+    if buffers.ndim == 3:
+        return _on_flat(block_acc_shuffle_staged, buffers, (msg, pre),
+                        acc_idx, fwd_idx, op=op, interpret=interpret)
+    R, nslots, rows, lanes = buffers.shape
+    tr, T = _grid_tiles(buffers)
     identity = op_identity(op, buffers.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(R, 2),
+        grid=(R, T, 2),
         in_specs=[
-            pl.BlockSpec((1, bs), _row_map_rs),
-            pl.BlockSpec((1, bs), _row_map_rs),
-            # aliased buffer: acc block at s=0, fwd block at s=1
-            pl.BlockSpec((1, 1, bs), _step_map),
+            _row_spec(tr, lanes),
+            _row_spec(tr, lanes),
+            # aliased buffer: acc tile at s=0, fwd tile at s=1
+            _slot_spec(tr, lanes, _step_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bs), _step_map),
-            pl.BlockSpec((1, bs), _row_map_rs),
+            _slot_spec(tr, lanes, _step_map),
+            _row_spec(tr, lanes),
         ],
-        scratch_shapes=[pltpu.VMEM((1, bs), buffers.dtype)],
+        scratch_shapes=[pltpu.VMEM((tr, lanes), buffers.dtype)],
     )
     kern = functools.partial(
         _acc_shuffle_staged_kernel, op=op, identity=identity)
@@ -456,8 +592,8 @@ def block_acc_shuffle_staged(buffers: jnp.ndarray, msg: jnp.ndarray,
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((R, nslots, bs), buffers.dtype),
-            jax.ShapeDtypeStruct((R, bs), buffers.dtype),
+            jax.ShapeDtypeStruct(buffers.shape, buffers.dtype),
+            jax.ShapeDtypeStruct((R, rows, lanes), buffers.dtype),
         ],
         input_output_aliases=ACC_STAGED_ALIASES,
         interpret=_resolve(interpret),
@@ -470,36 +606,33 @@ def block_acc_shuffle_staged(buffers: jnp.ndarray, msg: jnp.ndarray,
 
 def _qacc_shuffle_kernel(acc_ref, fwd_ref, qmsg_ref, smsg_ref, ro_ref,
                          alias_ref, erro_ref, outbuf_ref, outerr_ref,
-                         outq_ref, outs_ref, q_scr, s_scr, e_scr, *, nb, qb):
+                         outq_ref, outs_ref, q_scr, s_scr, e_scr, *,
+                         barrier):
     r = pl.program_id(0)
-    s = pl.program_id(1)
+    s = pl.program_id(2)
     # Same two-step grid as _acc_shuffle_kernel (s=0 accumulate, s=1
-    # drain), with the wire format quantized: the incoming message is
-    # int8 blocks + per-QBLOCK f32 scales, dequantized on the fly; the
+    # drain), with the wire format quantized: one tile row is one
+    # quantization block, the incoming message is its int8 lanes plus a
+    # [tile_rows, 1] column of f32 scales, dequantized on the fly; the
     # captured outgoing partial is requantized for the next hop and its
     # requantization error accumulated into the matching err slot (the
     # per-hop term the error-feedback sum needs -- dropping it is a
     # first-order bias, see optim/compression.py).
-    deq = dequant_blocks(
-        qmsg_ref[...].reshape(nb, qb), smsg_ref[...].reshape(nb, 1)
-    )
-    combined = alias_ref[0, 0].reshape(nb, qb) + deq
+    deq = dequant_blocks(qmsg_ref[...], smsg_ref[...], barrier=barrier)
+    combined = alias_ref[...] + deq
 
     @pl.when(s == 0)
     def _():
         same = acc_ref[r] == fwd_ref[r]
-        captured = jnp.where(same, combined, ro_ref[0, 0].reshape(nb, qb))
+        captured = jnp.where(same, combined, ro_ref[...])
         q, sc = quant_blocks(captured)
-        q_scr[...] = q.reshape(1, nb * qb)
-        s_scr[...] = sc.reshape(1, nb)
-        e_scr[...] = (
-            erro_ref[0, 0].reshape(nb, qb) + quant_error(captured, q, sc)
-        ).reshape(1, nb * qb)
+        q_scr[...] = q
+        s_scr[...] = sc
+        e_scr[...] = erro_ref[...] + quant_error(captured, q, sc,
+                                                 barrier=barrier)
 
-    outbuf_ref[...] = jnp.where(
-        s == 0, combined, jnp.zeros_like(combined)
-    ).reshape(1, 1, nb * qb)
-    outerr_ref[...] = e_scr[...][None]
+    outbuf_ref[...] = jnp.where(s == 0, combined, jnp.zeros_like(combined))
+    outerr_ref[...] = e_scr[...]
     outq_ref[...] = q_scr[...]
     outs_ref[...] = s_scr[...]
 
@@ -511,9 +644,11 @@ def block_qacc_shuffle(buffers: jnp.ndarray, err: jnp.ndarray,
     """Fused dequantize+accumulate(t) + requantize/capture/drain(t+1).
 
     The quantized-wire variant of :func:`block_acc_shuffle` (sum only).
-    buffers/err: [R, nslots, bs] f32 partial sums and their accumulated
-    requantization errors; qmsg: [R, bs] int8 incoming payload; smsg:
-    [R, nb] f32 per-QBLOCK scales (bs == nb * qb).  Per row r, in order:
+    buffers/err: [R, nslots, nb, qb] f32 partial sums and their
+    accumulated requantization errors, one quantization block per row
+    (or flat [R, nslots, nb * qb]); qmsg: the int8 incoming payload in
+    the same slot layout; smsg: [R, nb] f32 per-block scales.  Per row
+    r, in order:
 
       1. ``buffers[r, acc_idx[r]] += dequant(qmsg[r], smsg[r])``
       2. capture ``buffers[r, fwd_idx[r]]`` (sees step 1 when the slots
@@ -522,55 +657,73 @@ def block_qacc_shuffle(buffers: jnp.ndarray, err: jnp.ndarray,
       4. drain ``buffers[r, fwd_idx[r]]`` to zero
 
     Returns ``(new_buffers, new_err, out_q, out_s)``.  Quantization math
-    is :mod:`repro.kernels.quant_ops` (bit-identical to the jnp oracle).
-    On TPU the in-kernel (1, bs) -> (nb, qb) relayouts want qb to be a
-    multiple of 128 lanes; the default QBLOCK=256 satisfies this.
+    is :mod:`repro.kernels.quant_ops`.  Compiled for the TPU, qb must be
+    a multiple of 128 lanes (the default QBLOCK=256 is) and nb a
+    multiple of :data:`QROWS` (:func:`slot_shape` pads it).
     """
-    R, nslots, bs = buffers.shape
+    R, nslots = buffers.shape[:2]
     nb = smsg.shape[1]
-    assert bs % nb == 0, (bs, nb)
-    qb = bs // nb
+    if buffers.ndim == 3:
+        bs = buffers.shape[-1]
+        assert bs % nb == 0, (bs, nb)
+        shape = slot_shape(bs, jnp.float32, qblock=bs // nb)
+        pad = shape[0] - nb
+        nbuf, nerr, q, s = block_qacc_shuffle(
+            _to_tiles(buffers, shape), _to_tiles(err, shape),
+            _to_tiles(qmsg, shape), jnp.pad(smsg, ((0, 0), (0, pad))),
+            acc_idx, fwd_idx, interpret=interpret)
+        return (_from_tiles(nbuf, bs), _from_tiles(nerr, bs),
+                _from_tiles(q, bs), s[:, :nb])
+    qb = buffers.shape[-1]
+    assert buffers.shape[2] == nb, (buffers.shape, nb)
+    interpret = _resolve(interpret)
+    tq, T = _grid_tiles(buffers, unit=QROWS)
+    sspec = _row_spec(tq, 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(R, 2),
+        grid=(R, T, 2),
         in_specs=[
-            pl.BlockSpec((1, bs), _row_map_rs),
-            pl.BlockSpec((1, nb), _row_map_rs),
-            # read-only buffer view: the fwd block (pre-update content)
-            pl.BlockSpec((1, 1, bs), _fwd_map),
-            # aliased buffer: acc block at s=0, fwd block at s=1
-            pl.BlockSpec((1, 1, bs), _step_map),
-            # aliased err buffer: always the fwd block
-            pl.BlockSpec((1, 1, bs), _fwd_map),
+            _row_spec(tq, qb),
+            sspec,
+            # read-only buffer view: the fwd tile (pre-update content)
+            _slot_spec(tq, qb, _fwd_map),
+            # aliased buffer: acc tile at s=0, fwd tile at s=1
+            _slot_spec(tq, qb, _step_map),
+            # aliased err buffer: always the fwd tile
+            _slot_spec(tq, qb, _fwd_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bs), _step_map),
-            pl.BlockSpec((1, 1, bs), _fwd_map),
-            pl.BlockSpec((1, bs), _row_map_rs),
-            pl.BlockSpec((1, nb), _row_map_rs),
+            _slot_spec(tq, qb, _step_map),
+            _slot_spec(tq, qb, _fwd_map),
+            _row_spec(tq, qb),
+            sspec,
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, bs), jnp.int8),
-            pltpu.VMEM((1, nb), jnp.float32),
-            pltpu.VMEM((1, bs), jnp.float32),
+            pltpu.VMEM((tq, qb), jnp.int8),
+            pltpu.VMEM((tq, 1), jnp.float32),
+            pltpu.VMEM((tq, qb), jnp.float32),
         ],
     )
-    kern = functools.partial(_qacc_shuffle_kernel, nb=nb, qb=qb)
-    return pl.pallas_call(
+    # The optimization barrier pins round-after-multiply semantics for
+    # the interpreter's XLA; Mosaic has no such primitive and never
+    # contracts the dequantize into the accumulate.
+    kern = functools.partial(_qacc_shuffle_kernel, barrier=interpret)
+    nbuf, nerr, q, s = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((R, nslots, bs), jnp.float32),
-            jax.ShapeDtypeStruct((R, nslots, bs), jnp.float32),
-            jax.ShapeDtypeStruct((R, bs), jnp.int8),
-            jax.ShapeDtypeStruct((R, nb), jnp.float32),
+            jax.ShapeDtypeStruct(buffers.shape, jnp.float32),
+            jax.ShapeDtypeStruct(buffers.shape, jnp.float32),
+            jax.ShapeDtypeStruct((R, nb, qb), jnp.int8),
+            jax.ShapeDtypeStruct((R, nb, 1), jnp.float32),
         ],
         # operands counted including the 2 prefetch scalars:
         # 5 = 2nd buffer operand -> new_buffers, 6 = err -> new_err
         input_output_aliases=QACC_ALIASES,
-        interpret=_resolve(interpret),
+        interpret=interpret,
     )(acc_idx.astype(jnp.int32), fwd_idx.astype(jnp.int32),
-      qmsg, smsg, buffers, buffers, err)
+      qmsg, smsg.reshape(R, nb, 1), buffers, buffers, err)
+    return nbuf, nerr, q, s.reshape(R, nb)
 
 
 # ------------------------------------------------------- audit registry
@@ -634,132 +787,146 @@ KERNEL_NAMES = ("block_pack", "block_unpack", "block_shuffle",
 def _live_acc_step(g) -> bool:
     """Accumulate/drain kernels consume their inputs only in the s == 0
     sub-round; every s == 1 fetch is staged-through or discarded."""
-    return g[1] == 0
+    return g[2] == 0
 
 
 def kernel_audit_spec(name: str, *, R: int, nslots: int, bs: int,
-                      nb: int = 1) -> KernelAudit:
-    """The :class:`KernelAudit` for kernel ``name`` at concrete sizes.
+                      nb: int = 1, dtype=jnp.float32) -> KernelAudit:
+    """The :class:`KernelAudit` for kernel ``name`` at concrete sizes:
+    ``R`` rows of ``nslots`` slots of ``bs`` elements of ``dtype``
+    (``nb`` quantization blocks per slot for ``block_qacc_shuffle``,
+    whose buffers are float32).
 
     Single-sourced with the real calls: the returned records reference
     the very index-map functions and alias dicts the ``pallas_call``\\ s
-    in this module pass, so auditing them audits the shipped kernels.
+    in this module pass, and the grid comes from the same
+    :func:`slot_shape` / :func:`row_tile` layout, so auditing them
+    audits the shipped kernels.
     """
     f32, i8 = jnp.float32, jnp.int8
+    if name == "block_qacc_shuffle":
+        rows, lanes = slot_shape(bs, f32, qblock=bs // nb)
+        tr = row_tile(rows, lanes, f32, QROWS)
+    else:
+        rows, lanes = slot_shape(bs, dtype)
+        tr = row_tile(rows, lanes, dtype)
+    T = rows // tr
+    slot, row = (1, 1, tr, lanes), (1, tr, lanes)
     if name == "block_pack":
         return KernelAudit(
-            name=name, grid=(R,), num_scalar_prefetch=1,
+            name=name, grid=(R, T), num_scalar_prefetch=1,
             scalar_names=("idx",),
-            inputs=(OperandAudit("buffers", "buf", _slot_map1, (1, 1, bs)),),
-            outputs=(OperandAudit("out", "msg", _row_map1, (1, bs)),),
+            inputs=(OperandAudit("buffers", "buf", _slot_map1, slot),),
+            outputs=(OperandAudit("out", "msg", _row_map, row),),
             aliases=(), drain_dims=(),
             out_dtypes=lambda dt: (dt,))
     if name == "block_unpack":
         return KernelAudit(
-            name=name, grid=(R,), num_scalar_prefetch=1,
+            name=name, grid=(R, T), num_scalar_prefetch=1,
             scalar_names=("idx",),
             inputs=(
-                OperandAudit("msg", "msg", _row_map1, (1, bs)),
+                OperandAudit("msg", "msg", _row_map, row),
                 # aliased with the output; its fetched block is never
                 # consumed (the kernel dels the ref)
-                OperandAudit("buffers", "buf", _slot_map1, (1, 1, bs),
+                OperandAudit("buffers", "buf", _slot_map1, slot,
                              live=lambda g: False),
             ),
-            outputs=(OperandAudit("out", "buf", _slot_map1, (1, 1, bs)),),
+            outputs=(OperandAudit("out", "buf", _slot_map1, slot),),
             aliases=tuple(sorted(UNPACK_ALIASES.items())), drain_dims=(),
             out_dtypes=lambda dt: (dt,))
     if name == "block_shuffle":
         return KernelAudit(
-            name=name, grid=(R,), num_scalar_prefetch=2,
+            name=name, grid=(R, T), num_scalar_prefetch=2,
             scalar_names=("recv_idx", "send_idx"),
             inputs=(
-                OperandAudit("msg", "msg", _row_map2, (1, bs)),
-                OperandAudit("ro", "buf", _send_map, (1, 1, bs)),
-                OperandAudit("alias", "buf", _recv_map, (1, 1, bs),
+                OperandAudit("msg", "msg", _row_map, row),
+                OperandAudit("ro", "buf", _send_map, slot),
+                OperandAudit("alias", "buf", _recv_map, slot,
                              live=lambda g: False),
             ),
             outputs=(
-                OperandAudit("outbuf", "buf", _recv_map, (1, 1, bs)),
-                OperandAudit("outmsg", "outmsg", _row_map2, (1, bs)),
+                OperandAudit("outbuf", "buf", _recv_map, slot),
+                OperandAudit("outmsg", "outmsg", _row_map, row),
             ),
             aliases=tuple(sorted(SHUFFLE_ALIASES.items())), drain_dims=(),
             out_dtypes=lambda dt: (dt, dt))
     if name == "block_shuffle_staged":
         return KernelAudit(
-            name=name, grid=(R,), num_scalar_prefetch=2,
+            name=name, grid=(R, T), num_scalar_prefetch=2,
             scalar_names=("recv_idx", "send_idx"),
             inputs=(
-                OperandAudit("msg", "msg", _row_map2, (1, bs)),
-                OperandAudit("pre", "pre", _row_map2, (1, bs)),
-                OperandAudit("alias", "buf", _recv_map, (1, 1, bs),
+                OperandAudit("msg", "msg", _row_map, row),
+                OperandAudit("pre", "pre", _row_map, row),
+                OperandAudit("alias", "buf", _recv_map, slot,
                              live=lambda g: False),
             ),
             outputs=(
-                OperandAudit("outbuf", "buf", _recv_map, (1, 1, bs)),
-                OperandAudit("outmsg", "outmsg", _row_map2, (1, bs)),
+                OperandAudit("outbuf", "buf", _recv_map, slot),
+                OperandAudit("outmsg", "outmsg", _row_map, row),
             ),
             aliases=tuple(sorted(SHUFFLE_STAGED_ALIASES.items())),
             drain_dims=(),
             out_dtypes=lambda dt: (dt, dt))
     if name == "block_acc_shuffle":
         return KernelAudit(
-            name=name, grid=(R, 2), num_scalar_prefetch=2,
+            name=name, grid=(R, T, 2), num_scalar_prefetch=2,
             scalar_names=("acc_idx", "fwd_idx"),
             inputs=(
-                OperandAudit("msg", "msg", _row_map_rs, (1, bs),
+                OperandAudit("msg", "msg", _row_map, row,
                              live=_live_acc_step),
-                OperandAudit("ro", "buf", _fwd_map, (1, 1, bs),
+                OperandAudit("ro", "buf", _fwd_map, slot,
                              live=_live_acc_step),
-                OperandAudit("alias", "buf", _step_map, (1, 1, bs),
+                OperandAudit("alias", "buf", _step_map, slot,
                              live=_live_acc_step),
             ),
             outputs=(
-                OperandAudit("outbuf", "buf", _step_map, (1, 1, bs)),
-                OperandAudit("outmsg", "outmsg", _row_map_rs, (1, bs)),
+                OperandAudit("outbuf", "buf", _step_map, slot),
+                OperandAudit("outmsg", "outmsg", _row_map, row),
             ),
-            aliases=tuple(sorted(ACC_ALIASES.items())), drain_dims=(1,),
+            aliases=tuple(sorted(ACC_ALIASES.items())), drain_dims=(2,),
             out_dtypes=lambda dt: (dt, dt))
     if name == "block_acc_shuffle_staged":
         return KernelAudit(
-            name=name, grid=(R, 2), num_scalar_prefetch=2,
+            name=name, grid=(R, T, 2), num_scalar_prefetch=2,
             scalar_names=("acc_idx", "fwd_idx"),
             inputs=(
-                OperandAudit("msg", "msg", _row_map_rs, (1, bs),
+                OperandAudit("msg", "msg", _row_map, row,
                              live=_live_acc_step),
-                OperandAudit("pre", "pre", _row_map_rs, (1, bs),
+                OperandAudit("pre", "pre", _row_map, row,
                              live=_live_acc_step),
-                OperandAudit("alias", "buf", _step_map, (1, 1, bs),
+                OperandAudit("alias", "buf", _step_map, slot,
                              live=_live_acc_step),
             ),
             outputs=(
-                OperandAudit("outbuf", "buf", _step_map, (1, 1, bs)),
-                OperandAudit("outmsg", "outmsg", _row_map_rs, (1, bs)),
+                OperandAudit("outbuf", "buf", _step_map, slot),
+                OperandAudit("outmsg", "outmsg", _row_map, row),
             ),
             aliases=tuple(sorted(ACC_STAGED_ALIASES.items())),
-            drain_dims=(1,),
+            drain_dims=(2,),
             out_dtypes=lambda dt: (dt, dt))
     if name == "block_qacc_shuffle":
+        scale = (1, tr, 1)
         return KernelAudit(
-            name=name, grid=(R, 2), num_scalar_prefetch=2,
+            name=name, grid=(R, T, 2), num_scalar_prefetch=2,
             scalar_names=("acc_idx", "fwd_idx"),
             inputs=(
-                OperandAudit("qmsg", "qmsg", _row_map_rs, (1, bs),
+                OperandAudit("qmsg", "qmsg", _row_map, row,
                              live=_live_acc_step),
-                OperandAudit("smsg", "smsg", _row_map_rs, (1, nb),
+                OperandAudit("smsg", "smsg", _row_map, scale,
                              live=_live_acc_step),
-                OperandAudit("ro", "buf", _fwd_map, (1, 1, bs),
+                OperandAudit("ro", "buf", _fwd_map, slot,
                              live=_live_acc_step),
-                OperandAudit("alias", "buf", _step_map, (1, 1, bs),
+                OperandAudit("alias", "buf", _step_map, slot,
                              live=_live_acc_step),
-                OperandAudit("erro", "err", _fwd_map, (1, 1, bs),
+                OperandAudit("erro", "err", _fwd_map, slot,
                              live=_live_acc_step),
             ),
             outputs=(
-                OperandAudit("outbuf", "buf", _step_map, (1, 1, bs)),
-                OperandAudit("outerr", "err", _fwd_map, (1, 1, bs)),
-                OperandAudit("outq", "outq", _row_map_rs, (1, bs)),
-                OperandAudit("outs", "outs", _row_map_rs, (1, nb)),
+                OperandAudit("outbuf", "buf", _step_map, slot),
+                OperandAudit("outerr", "err", _fwd_map, slot),
+                OperandAudit("outq", "outq", _row_map, row),
+                OperandAudit("outs", "outs", _row_map, scale),
             ),
-            aliases=tuple(sorted(QACC_ALIASES.items())), drain_dims=(1,),
+            aliases=tuple(sorted(QACC_ALIASES.items())), drain_dims=(2,),
             out_dtypes=lambda dt: (f32, f32, i8, f32))
     raise ValueError(f"unknown kernel {name!r} (use one of {KERNEL_NAMES})")

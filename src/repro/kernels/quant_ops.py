@@ -65,7 +65,8 @@ def quant_blocks(x2d: jnp.ndarray):
     return q, scale.astype(jnp.float32)
 
 
-def dequant_blocks(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
+def dequant_blocks(q: jnp.ndarray, scale: jnp.ndarray,
+                   barrier: bool = True) -> jnp.ndarray:
     """Dequantize [nb, qb] int8 against [nb, 1] scales -> [nb, qb] f32.
 
     Flagged (NaN-scale) blocks dequantize to all-NaN deterministically.
@@ -75,19 +76,23 @@ def dequant_blocks(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     (FMA), and whether it does depends on the surrounding graph -- the
     jnp oracle and the interpreted Pallas kernel would then disagree in
     the last bit.  The barrier pins round-after-multiply semantics in
-    every backend.
+    every XLA backend.  Compiled Pallas TPU kernels pass
+    ``barrier=False``: Mosaic has no barrier primitive, and it rounds
+    the product before the add anyway.
     """
-    return jax.lax.optimization_barrier(q.astype(jnp.float32) * scale)
+    dq = q.astype(jnp.float32) * scale
+    return jax.lax.optimization_barrier(dq) if barrier else dq
 
 
-def quant_error(x2d: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray):
+def quant_error(x2d: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray,
+                barrier: bool = True):
     """Elementwise quantization error x - dq, with non-finite lanes zeroed.
 
     Zeroing keeps error-feedback state finite even when a gradient leaf
     goes NaN/inf for a step -- the flag travels via the NaN scale, not
-    via the feedback buffer.
+    via the feedback buffer.  ``barrier`` as in :func:`dequant_blocks`.
     """
-    err = x2d.astype(jnp.float32) - dequant_blocks(q, scale)
+    err = x2d.astype(jnp.float32) - dequant_blocks(q, scale, barrier)
     return jnp.where(jnp.isfinite(err), err, 0.0)
 
 

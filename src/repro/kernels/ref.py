@@ -25,9 +25,15 @@ def attention_ref(q, k, v, *, causal=True, window=None):
     return jnp.einsum("bqk,bkd->bqd", w, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _take_slot(buffers, idx):
+    """buffers[r, idx[r]] for every row r of [R, nslots, *slot]."""
+    col = idx.reshape((-1,) + (1,) * (buffers.ndim - 1))
+    return jnp.take_along_axis(buffers, col, axis=1)[:, 0]
+
+
 def block_pack_ref(buffers, idx):
-    """buffers: [R, nslots, bs]; idx: [R] int32 -> packed [R, bs]."""
-    return jnp.take_along_axis(buffers, idx[:, None, None], axis=1)[:, 0]
+    """buffers: [R, nslots, *slot]; idx: [R] int32 -> packed [R, *slot]."""
+    return _take_slot(buffers, idx)
 
 
 def block_unpack_ref(buffers, msg, idx):
@@ -41,7 +47,7 @@ def block_shuffle_ref(buffers, msg, recv_idx, send_idx):
     be the round-t+1 send).  Returns (new_buffers, out_msg)."""
     rows = jnp.arange(buffers.shape[0])
     buffers = buffers.at[rows, recv_idx].set(msg, mode="promise_in_bounds")
-    out = jnp.take_along_axis(buffers, send_idx[:, None, None], axis=1)[:, 0]
+    out = _take_slot(buffers, send_idx)
     return buffers, out
 
 
@@ -71,7 +77,7 @@ def block_acc_shuffle_staged_ref(buffers, msg, pre, acc_idx, fwd_idx,
 
     combine = op_combine(op)
     rows = jnp.arange(buffers.shape[0])
-    cur = jnp.take_along_axis(buffers, acc_idx[:, None, None], axis=1)[:, 0]
+    cur = _take_slot(buffers, acc_idx)
     combined = combine(cur, msg)
     buffers = buffers.at[rows, acc_idx].set(
         combined, mode="promise_in_bounds"
@@ -93,11 +99,11 @@ def block_acc_shuffle_ref(buffers, msg, acc_idx, fwd_idx, op="sum"):
 
     combine = op_combine(op)
     rows = jnp.arange(buffers.shape[0])
-    cur = jnp.take_along_axis(buffers, acc_idx[:, None, None], axis=1)[:, 0]
+    cur = _take_slot(buffers, acc_idx)
     buffers = buffers.at[rows, acc_idx].set(
         combine(cur, msg), mode="promise_in_bounds"
     )
-    out = jnp.take_along_axis(buffers, fwd_idx[:, None, None], axis=1)[:, 0]
+    out = _take_slot(buffers, fwd_idx)
     ident = op_identity(op, buffers.dtype)
     buffers = buffers.at[rows, fwd_idx].set(
         jnp.full_like(out, ident), mode="promise_in_bounds"
@@ -108,40 +114,41 @@ def block_acc_shuffle_ref(buffers, msg, acc_idx, fwd_idx, op="sum"):
 def block_qacc_shuffle_ref(buffers, err, qmsg, smsg, acc_idx, fwd_idx):
     """Quantized accumulate+capture/drain oracle (sum only).
 
-    The incoming message is int8 blocks ``qmsg`` [R, bs] with per-QBLOCK
-    scales ``smsg`` [R, nb] (bs == nb * qb): dequantize, accumulate into
-    the acc slots of the f32 ``buffers`` [R, nslots, bs], capture the fwd
+    The incoming message is int8 blocks ``qmsg`` [R, *slot] with
+    per-QBLOCK scales ``smsg`` [R, nb] (nb * qb elements per slot):
+    dequantize, accumulate into
+    the acc slots of the f32 ``buffers`` [R, nslots, *slot], capture the fwd
     slots from the updated buffer, quantize the captured partial for the
     wire, record the requantization error into the matching slot of
-    ``err`` [R, nslots, bs], then drain the fwd slots to zero.
+    ``err`` [R, nslots, *slot], then drain the fwd slots to zero.
 
-    Returns (new_buffers, new_err, out_q [R, bs] int8, out_s [R, nb] f32).
+    Returns (new_buffers, new_err, out_q [R, *slot] int8, out_s [R, nb] f32).
     """
     from .quant_ops import dequant_blocks, quant_blocks, quant_error
 
-    R, _, bs = buffers.shape
+    R, slot = buffers.shape[0], buffers.shape[2:]
     nb = smsg.shape[1]
-    qb = bs // nb
+    qb = math.prod(slot) // nb
     rows = jnp.arange(R)
 
     deq = dequant_blocks(
         qmsg.reshape(R * nb, qb), smsg.reshape(R * nb, 1)
-    ).reshape(R, bs)
-    cur = jnp.take_along_axis(buffers, acc_idx[:, None, None], axis=1)[:, 0]
+    ).reshape((R,) + slot)
+    cur = _take_slot(buffers, acc_idx)
     buffers = buffers.at[rows, acc_idx].set(
         cur + deq, mode="promise_in_bounds"
     )
 
-    captured = jnp.take_along_axis(buffers, fwd_idx[:, None, None], axis=1)[:, 0]
+    captured = _take_slot(buffers, fwd_idx)
     q, s = quant_blocks(captured.reshape(R * nb, qb))
-    eps = quant_error(captured.reshape(R * nb, qb), q, s).reshape(R, bs)
-    cur_e = jnp.take_along_axis(err, fwd_idx[:, None, None], axis=1)[:, 0]
+    eps = quant_error(captured.reshape(R * nb, qb), q, s).reshape((R,) + slot)
+    cur_e = _take_slot(err, fwd_idx)
     err = err.at[rows, fwd_idx].set(cur_e + eps, mode="promise_in_bounds")
 
     buffers = buffers.at[rows, fwd_idx].set(
         jnp.zeros_like(captured), mode="promise_in_bounds"
     )
-    return buffers, err, q.reshape(R, bs), s.reshape(R, nb)
+    return buffers, err, q.reshape((R,) + slot), s.reshape(R, nb)
 
 
 def ssd_ref(x, B_, C_, dt, A_log, D):

@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.configs import all_arch_names, get_config
 from repro.launch.hlo_analysis import collective_stats, weighted_cost
-from repro.launch.mesh import make_production_mesh, set_global_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models.common import SHAPES, ModelConfig, ShapeConfig
 from repro.models import moe as moe_mod
 from repro.models.transformer import init_cache
@@ -125,7 +125,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, microbatches=None,
     mesh = make_production_mesh(multi_pod=multi_pod)
     dp_axes, model_axis = mesh_axes(mesh)
     dp = int(np.prod([mesh.shape[a] for a in dp_axes]))
-    set_global_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
     from repro.train import sharding as shard_rules
     ep_mode = os.environ.get("DRYRUN_EP_MODE", "2d")
     shard_rules.set_ep_mode(ep_mode)

@@ -15,35 +15,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)  # pre-AxisType jax (< 0.5)
-
-
-# The mesh context entered by the pre-0.6 fallback below; exited before a
-# replacement is entered so repeated calls (e.g. dry-run sweeps) don't
-# stack leaked contexts.
-_ACTIVE_MESH_CTX = []
-
-
-def set_global_mesh(mesh):
-    """jax.sharding.set_mesh across jax versions.
-
-    ``set_mesh`` only exists from jax 0.6; on older versions entering the
-    mesh context manager (kept open until the next call or process exit)
-    provides the ambient mesh.
-    """
-    setter = getattr(jax.sharding, "set_mesh", None)
-    if setter is not None:
-        setter(mesh)
-    else:
-        while _ACTIVE_MESH_CTX:
-            _ACTIVE_MESH_CTX.pop().__exit__(None, None, None)
-        mesh.__enter__()
-        _ACTIVE_MESH_CTX.append(mesh)
-    return mesh
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_host_mesh(p: int, axis: str = "data"):

@@ -20,8 +20,6 @@ import argparse
 import time
 
 import jax
-
-from repro.launch.mesh import set_global_mesh
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -54,7 +52,7 @@ def main():
 
     mesh = build_mesh(args.mesh)
     dp_axes, model_axis = mesh_axes(mesh)
-    set_global_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
     hints.set_hint("hidden", P(dp_axes, None, None))
     cfg = get_config(args.arch, smoke=args.smoke)
     print(f"mesh {dict(mesh.shape)}  model {cfg.name}")

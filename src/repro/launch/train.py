@@ -25,8 +25,6 @@ import math
 import time
 
 import jax
-
-from repro.launch.mesh import set_global_mesh
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -73,7 +71,7 @@ def main():
     mesh = build_mesh(args.mesh)
     dp_axes, model_axis = mesh_axes(mesh)
     dp = int(np.prod([mesh.shape[a] for a in dp_axes]))
-    set_global_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
     if args.grad_sync == "auto":
         # GSPMD layout hints.  The compressed path runs the model inside
         # shard_map (every mesh axis manual), where sharding constraints

@@ -197,7 +197,7 @@ def _make_compressed_step(cfg, tcfg, mesh, dp, compute_grads, finish):
     spec = grad_bucket_spec(cfg, tcfg)
     nb = spec.num_buckets
 
-    from repro.core.jaxcompat import shard_map
+    from jax import shard_map
 
     def body(params, opt, errs, batch):
         # Gradients stay local to the shard: the lossy sync below is the

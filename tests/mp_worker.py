@@ -98,7 +98,7 @@ def check_compressed_allreduce(p, elems=2048, backend="jnp"):
     adversarial high-dynamic-range gradients, ragged leaf sizes,
     bf16 leaves, and nonfinite propagation."""
     from jax.sharding import PartitionSpec as P
-    from repro.core.jaxcompat import shard_map
+    from jax import shard_map
     from repro.optim.compression import (
         BLOCK,
         compressed_allreduce_tree,

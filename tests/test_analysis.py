@@ -295,7 +295,7 @@ def test_overlapping_output_blocks_rejected():  # class 13: ww-overlap
 
     bp, spec, idx = _pack_spec()
     evil_out = dataclasses.replace(
-        spec.outputs[0], index_map=lambda r, i: (0, 0))  # every r -> row 0
+        spec.outputs[0], index_map=lambda r, j, i: (0, 0, 0))  # every r -> row 0
     bad = dataclasses.replace(spec, outputs=(evil_out,))
     findings = replay_kernel(bad, (idx,))
     assert any(f.check == "ww-overlap" for f in findings), findings
@@ -312,7 +312,7 @@ def test_alias_read_back_rejected():  # class 14: raw-alias
     # written block: the exact interpret/compiled divergence hazard.
     live_alias = dataclasses.replace(
         spec.inputs[1], live=None,
-        index_map=lambda r, i: (max(r - 1, 0), i[max(r - 1, 0)], 0))
+        index_map=lambda r, j, i: (max(r - 1, 0), i[max(r - 1, 0)], j, 0))
     bad = dataclasses.replace(spec, inputs=(spec.inputs[0], live_alias))
     findings = replay_kernel(bad, (idx,))
     assert any(f.check == "raw-alias" for f in findings), findings
@@ -326,7 +326,7 @@ def test_alias_map_mismatch_rejected():  # class 15: alias-map
 
     spec = kernel_audit_spec("block_unpack", R=4, nslots=5, bs=8)
     skewed = dataclasses.replace(
-        spec.inputs[1], index_map=lambda r, i: (r, (i[r] + 1) % 5, 0))
+        spec.inputs[1], index_map=lambda r, j, i: (r, (i[r] + 1) % 5, j, 0))
     bad = dataclasses.replace(spec, inputs=(spec.inputs[0], skewed))
     findings = replay_kernel(bad, (np.arange(4, dtype=np.int32),))
     assert any(f.check == "alias-map" for f in findings), findings

@@ -1,0 +1,137 @@
+"""Ahead-of-time compiles for a described TPU v5e (no chip needed).
+
+The TPU compiler is installed with jax; it compiles for a topology that
+is described rather than attached.  These tests guard what interpret
+mode cannot see: every round-step kernel must lower through Mosaic at
+deployment block sizes (tile-aligned blocks, scoped-VMEM budget), and
+the jnp-backend broadcast plan must lower to the schedule's round count
+of ``collective-permute``s on a 4-chip mesh.
+
+The topology is described inside a module fixture, never at import: one
+process at a time may load the TPU library, and the suite runs on
+several workers.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import block_pack as bp
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+R = 4                          # one row per rank of a p = 4 plan
+NSLOTS = 10                    # n = 8 blocks + garbage + identity slot
+SIZES = (4 << 10, 1 << 20, 16 << 20)   # bytes per block
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    # Compiles here cannot be read back from the persistent cache
+    # without a chip; keep them out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _arg(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_args(name, nbytes, dtype, sh):
+    """ShapeDtypeStructs for kernel ``name`` at ``nbytes`` per block, in
+    the tiled slot layout the plans hold."""
+    idx = _arg((R,), jnp.int32, sh)
+    if name == "block_qacc_shuffle":
+        shape = bp.slot_shape(nbytes // 4, jnp.float32, qblock=256)
+        buf = _arg((R, NSLOTS) + shape, jnp.float32, sh)
+        return (buf, buf, _arg((R,) + shape, jnp.int8, sh),
+                _arg((R, shape[0]), jnp.float32, sh), idx, idx)
+    shape = bp.slot_shape(nbytes // np.dtype(dtype).itemsize, dtype)
+    buf = _arg((R, NSLOTS) + shape, dtype, sh)
+    msg = _arg((R,) + shape, dtype, sh)
+    return {
+        "block_pack": (buf, idx),
+        "block_unpack": (buf, msg, idx),
+        "block_shuffle": (buf, msg, idx, idx),
+        "block_shuffle_staged": (buf, msg, msg, idx, idx),
+        "block_acc_shuffle": (buf, msg, idx, idx),
+        "block_acc_shuffle_staged": (buf, msg, msg, idx, idx),
+    }[name]
+
+
+@pytest.mark.parametrize("nbytes", SIZES)
+@pytest.mark.parametrize("name", bp.KERNEL_NAMES)
+def test_kernel_compiles_for_v5e(one_chip, name, nbytes):
+    fn = getattr(bp, name)
+    args = _kernel_args(name, nbytes, jnp.float32, one_chip)
+    compiled = jax.jit(lambda *a: fn(*a, interpret=False)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,dtype", [
+    ("block_shuffle", jnp.bfloat16), ("block_shuffle", jnp.int32),
+    ("block_shuffle", jnp.int8), ("block_acc_shuffle", jnp.bfloat16),
+    ("block_acc_shuffle", jnp.int32)])
+def test_fused_kernels_compile_for_payload_dtypes(one_chip, name, dtype):
+    fn = getattr(bp, name)
+    args = _kernel_args(name, 1 << 20, dtype, one_chip)
+    compiled = jax.jit(lambda *a: fn(*a, interpret=False)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "name", [k for k in bp.KERNEL_NAMES if k != "block_qacc_shuffle"])
+def test_kernels_compile_under_x64(one_chip, name):
+    """The exact kinds' host data plans certify in x64 mode (the
+    quantized one runs in f32); the kernels' index maps must stay 32-bit
+    there (Mosaic refuses 64-bit block indices)."""
+    fn = getattr(bp, name)
+    with jax.enable_x64(True):
+        args = _kernel_args(name, 1 << 20, jnp.float32, one_chip)
+        compiled = jax.jit(lambda *a: fn(*a, interpret=False)).lower(
+            *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shift", [-3, -2, -1, 1, 2, 3])
+def test_host_plan_exchange_compiles_for_v5e(one_chip, shift):
+    """The host plans' rank rotation on a [p, bs] message of one 25 MiB
+    bucket split in 8 (``jnp.roll`` by 2 rows aborted this compiler)."""
+    from repro.core.comm import _rotate
+
+    msg = _arg((4, 819200), jnp.float32, one_chip)
+    with jax.enable_x64(True):
+        jax.jit(lambda m: _rotate(m, shift)).lower(msg).compile()
+
+
+def test_broadcast_plan_lowers_to_schedule_permutes(topo):
+    from repro.core.comm import CirculantComm
+
+    p, n = 4, 8
+    mesh = Mesh(np.array(topo.devices[:p]), ("data",))
+    comm = CirculantComm(mesh, "data")
+    x = _arg((p, 1 << 20), jnp.float32, NamedSharding(mesh, P("data")))
+    plan = comm.plan("broadcast", x, n_blocks=n)
+    assert plan.rounds == n - 1 + 2
+    text = jax.jit(plan).lower(x).compile().as_text()
+    starts = text.count("collective-permute-start(")
+    assert (starts or text.count("collective-permute(")) == plan.rounds
