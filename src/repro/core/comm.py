@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from . import tracing
 from .costmodel import (
     DEFAULT_MODEL,
     CommModel,
@@ -77,6 +78,7 @@ from .roundstep import (
     scatter_phase_static,
     scatter_slot_plan,
 )
+from .tracing import scope, span
 
 __all__ = [
     "KINDS",
@@ -253,33 +255,37 @@ def _bcast_phase(flats, n, recv_slots, send_slots, perms, axis_name, r, step,
     step patches the single stale case ``recv[t] == send[t+1]`` with
     the received message.  Bit-exact vs the sequential loop (only the
     recv slot changes per round)."""
-    recv_t = jnp.asarray(recv_slots)  # [R, p] static slot tables
-    send_t = jnp.asarray(send_slots)
-    R = recv_t.shape[0]
-    bufs, msgs, sizes = [], [], []
-    for flat in flats:
-        buf, _ = _split_blocks(flat, n, step)
-        buf = buf[None]                               # [1, n+1, *slot]
-        bufs.append(buf)
-        sizes.append(flat.shape[0])
-        msgs.append(step.pack(buf, send_t[0, r][None]))
-    for t in range(R):
-        got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
-        for i in range(len(bufs)):
-            if t + 1 < R:
-                if overlap:
-                    pre = step.pack(bufs[i], send_t[t + 1, r][None])
-                    bufs[i], msgs[i] = step.shuffle_staged(
-                        bufs[i], got[i], pre, recv_t[t, r][None],
-                        send_t[t + 1, r][None])
+    with scope(tracing.BCAST):
+        recv_t = jnp.asarray(recv_slots)  # [R, p] static slot tables
+        send_t = jnp.asarray(send_slots)
+        R = recv_t.shape[0]
+        bufs, msgs, sizes = [], [], []
+        for flat in flats:
+            with scope(tracing.SPLIT):
+                buf, _ = _split_blocks(flat, n, step)
+                buf = buf[None]                       # [1, n+1, *slot]
+            bufs.append(buf)
+            sizes.append(flat.shape[0])
+            msgs.append(step.pack(buf, send_t[0, r][None]))
+        for t in range(R):
+            got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
+            for i in range(len(bufs)):
+                if t + 1 < R:
+                    if overlap:
+                        pre = step.pack(bufs[i], send_t[t + 1, r][None])
+                        bufs[i], msgs[i] = step.shuffle_staged(
+                            bufs[i], got[i], pre, recv_t[t, r][None],
+                            send_t[t + 1, r][None])
+                    else:
+                        bufs[i], msgs[i] = step.shuffle(
+                            bufs[i], got[i], recv_t[t, r][None],
+                            send_t[t + 1, r][None])
                 else:
-                    bufs[i], msgs[i] = step.shuffle(
-                        bufs[i], got[i], recv_t[t, r][None],
-                        send_t[t + 1, r][None])
-            else:
-                bufs[i] = step.unpack(bufs[i], got[i], recv_t[t, r][None])
-    return [buf[0, :n].reshape(-1)[:size]
-            for buf, size in zip(bufs, sizes)]
+                    bufs[i] = step.unpack(bufs[i], got[i],
+                                          recv_t[t, r][None])
+        with scope(tracing.JOIN):
+            return [buf[0, :n].reshape(-1)[:size]
+                    for buf, size in zip(bufs, sizes)]
 
 
 def _reduce_phase(flats, n, fwd_slots, acc_slots, perms, axis_name, r,
@@ -292,39 +298,42 @@ def _reduce_phase(flats, n, fwd_slots, acc_slots, perms, axis_name, r,
     from the PRE-accumulate buffer (overlapping the round-t exchange)
     and the staged step patches the coincident ``fwd == acc`` case with
     the freshly combined value -- bit-exact vs the sequential loop."""
-    F = jnp.asarray(fwd_slots)  # [R, p] static slot tables (root row
-    A = jnp.asarray(acc_slots)  # pinned to the identity slot n+1)
-    R = F.shape[0]
-    garbage = jnp.full((1,), n, jnp.int32)
-    bufs, msgs, sizes = [], [], []
-    for flat, ident in zip(flats, idents):
-        buf, slot = _split_blocks(flat, n, step)      # [n+1, *slot]
-        buf = jnp.concatenate(
-            [buf, jnp.full((1,) + slot, ident, buf.dtype)], axis=0
-        )[None]                                       # [1, n+2, *slot]
-        # Initial capture+drain of round 0's forwarded partial.
-        buf, msg = step.acc_shuffle(
-            buf, jnp.zeros((1,) + slot, buf.dtype), garbage, F[0, r][None],
-            op=op)
-        bufs.append(buf)
-        msgs.append(msg)
-        sizes.append(flat.shape[0])
-    for t in range(R):
-        got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
-        nxt = F[t + 1, r][None] if t + 1 < R else garbage
-        for i in range(len(bufs)):
-            # accumulate round t's incoming partial, then capture+drain
-            # round t+1's forward (each partial flows along exactly one
-            # tree edge).
-            if overlap:
-                pre = step.pack(bufs[i], nxt)
-                bufs[i], msgs[i] = step.acc_shuffle_staged(
-                    bufs[i], got[i], pre, A[t, r][None], nxt, op=op)
-            else:
-                bufs[i], msgs[i] = step.acc_shuffle(
-                    bufs[i], got[i], A[t, r][None], nxt, op=op)
-    return [buf[0, :n].reshape(-1)[:size]
-            for buf, size in zip(bufs, sizes)]
+    with scope(tracing.REDUCE):
+        F = jnp.asarray(fwd_slots)  # [R, p] static slot tables (root row
+        A = jnp.asarray(acc_slots)  # pinned to the identity slot n+1)
+        R = F.shape[0]
+        garbage = jnp.full((1,), n, jnp.int32)
+        bufs, msgs, sizes = [], [], []
+        for flat, ident in zip(flats, idents):
+            with scope(tracing.SPLIT):
+                buf, slot = _split_blocks(flat, n, step)  # [n+1, *slot]
+                buf = jnp.concatenate(
+                    [buf, jnp.full((1,) + slot, ident, buf.dtype)], axis=0
+                )[None]                                   # [1, n+2, *slot]
+            # Initial capture+drain of round 0's forwarded partial.
+            buf, msg = step.acc_shuffle(
+                buf, jnp.zeros((1,) + slot, buf.dtype), garbage,
+                F[0, r][None], op=op)
+            bufs.append(buf)
+            msgs.append(msg)
+            sizes.append(flat.shape[0])
+        for t in range(R):
+            got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
+            nxt = F[t + 1, r][None] if t + 1 < R else garbage
+            for i in range(len(bufs)):
+                # accumulate round t's incoming partial, then
+                # capture+drain round t+1's forward (each partial flows
+                # along exactly one tree edge).
+                if overlap:
+                    pre = step.pack(bufs[i], nxt)
+                    bufs[i], msgs[i] = step.acc_shuffle_staged(
+                        bufs[i], got[i], pre, A[t, r][None], nxt, op=op)
+                else:
+                    bufs[i], msgs[i] = step.acc_shuffle(
+                        bufs[i], got[i], A[t, r][None], nxt, op=op)
+        with scope(tracing.JOIN):
+            return [buf[0, :n].reshape(-1)[:size]
+                    for buf, size in zip(bufs, sizes)]
 
 
 def _allgather_phase(flats, n, recv_slots, skips, perms, axis_name, r,
@@ -335,39 +344,43 @@ def _allgather_phase(flats, n, recv_slots, skips, perms, axis_name, r,
     recv AND send: by Condition 2 the send slot of root row j is the
     recv slot of the shifted virtual rank, so both are gathers of the
     same table."""
-    S = jnp.asarray(recv_slots)  # [R, p] static slot table
-    R = S.shape[0]
-    base = (r - jnp.arange(p)) % p  # virtual rank of root row j at rank r
+    with scope(tracing.ALLGATHER):
+        S = jnp.asarray(recv_slots)  # [R, p] static slot table
+        R = S.shape[0]
+        base = (r - jnp.arange(p)) % p  # virtual rank of root row j at r
 
-    def send_slots_at(t):
-        return S[t][(base + skips[t]) % p]
+        def send_slots_at(t):
+            return S[t][(base + skips[t]) % p]
 
-    bufs, sizes = [], []
-    for flat in flats:
-        # buffers[j] holds root j's blocks; only the own row is filled.
-        own, _ = _split_blocks(flat, n, step)         # [n+1, *slot]
-        buf = jnp.zeros((p,) + own.shape, flat.dtype)
-        buf = jax.lax.dynamic_update_slice(buf, own[None],
-                                           (r,) + (0,) * own.ndim)
-        bufs.append(buf)
-        sizes.append(flat.shape[0])
-    msgs = [step.pack(buf, send_slots_at(0)) for buf in bufs]
-    for t in range(R):
-        got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
-        for i in range(len(bufs)):
-            if t + 1 < R:
-                if overlap:
-                    pre = step.pack(bufs[i], send_slots_at(t + 1))
-                    bufs[i], msgs[i] = step.shuffle_staged(
-                        bufs[i], got[i], pre, S[t][base],
-                        send_slots_at(t + 1))
+        bufs, sizes = [], []
+        for flat in flats:
+            # buffers[j] holds root j's blocks; only the own row is filled.
+            with scope(tracing.SPLIT):
+                own, _ = _split_blocks(flat, n, step)     # [n+1, *slot]
+                buf = jnp.zeros((p,) + own.shape, flat.dtype)
+                buf = jax.lax.dynamic_update_slice(buf, own[None],
+                                                   (r,) + (0,) * own.ndim)
+            bufs.append(buf)
+            sizes.append(flat.shape[0])
+        msgs = [step.pack(buf, send_slots_at(0)) for buf in bufs]
+        for t in range(R):
+            got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
+            for i in range(len(bufs)):
+                if t + 1 < R:
+                    if overlap:
+                        pre = step.pack(bufs[i], send_slots_at(t + 1))
+                        bufs[i], msgs[i] = step.shuffle_staged(
+                            bufs[i], got[i], pre, S[t][base],
+                            send_slots_at(t + 1))
+                    else:
+                        bufs[i], msgs[i] = step.shuffle(
+                            bufs[i], got[i], S[t][base],
+                            send_slots_at(t + 1))
                 else:
-                    bufs[i], msgs[i] = step.shuffle(
-                        bufs[i], got[i], S[t][base], send_slots_at(t + 1))
-            else:
-                bufs[i] = step.unpack(bufs[i], got[i], S[t][base])
-    return [buf[:, :n].reshape(p, -1)[:, :size].reshape(-1)
-            for buf, size in zip(bufs, sizes)]
+                    bufs[i] = step.unpack(bufs[i], got[i], S[t][base])
+        with scope(tracing.JOIN):
+            return [buf[:, :n].reshape(p, -1)[:, :size].reshape(-1)
+                    for buf, size in zip(bufs, sizes)]
 
 
 def _qreduce_phase(flats, n, fwd_slots, acc_slots, perms, axis_name, r, step,
@@ -378,37 +391,40 @@ def _qreduce_phase(flats, n, fwd_slots, acc_slots, perms, axis_name, r, step,
     generated it.  Returns per-leaf ``(buf, err, nb, size)`` with buf/err
     the [1, n+2, *slot] f32 buffers (root row of buf holds the lossy sum;
     err holds each rank's locally generated error in SUM units)."""
-    F = jnp.asarray(fwd_slots)  # [R, p] static slot tables (root row
-    A = jnp.asarray(acc_slots)  # pinned to the identity slot n+1)
-    R = F.shape[0]
-    garbage = jnp.full((1,), n, jnp.int32)
-    bufs, errs, qmsgs, smsgs, metas = [], [], [], [], []
-    for flat in flats:
-        buf, slot = _split_blocks(flat, n, step, qblock)  # [n+1, *slot]
-        nb = math.prod(slot) // qblock
-        # slot n+1 is the sum identity (zero), matching _reduce_phase.
-        buf = jnp.concatenate(
-            [buf, jnp.zeros((1,) + slot, buf.dtype)], axis=0
-        )[None]                                        # [1, n+2, *slot]
-        err = jnp.zeros_like(buf)
-        # Initial capture+drain of round 0's forwarded partial (zero
-        # message: dequant(0, 0) == 0 folds into the garbage slot).
-        buf, err, qm, sm = step.qacc_shuffle(
-            buf, err, jnp.zeros((1,) + slot, jnp.int8),
-            jnp.zeros((1, nb), jnp.float32), garbage, F[0, r][None])
-        bufs.append(buf)
-        errs.append(err)
-        qmsgs.append(qm)
-        smsgs.append(sm)
-        metas.append((nb, flat.shape[0]))
-    for t in range(R):
-        got_q = [jax.lax.ppermute(m, axis_name, perms[t]) for m in qmsgs]
-        got_s = [jax.lax.ppermute(m, axis_name, perms[t]) for m in smsgs]
-        nxt = F[t + 1, r][None] if t + 1 < R else garbage
-        for i in range(len(bufs)):
-            bufs[i], errs[i], qmsgs[i], smsgs[i] = step.qacc_shuffle(
-                bufs[i], errs[i], got_q[i], got_s[i], A[t, r][None], nxt)
-    return [(buf, err) + meta for buf, err, meta in zip(bufs, errs, metas)]
+    with scope(tracing.QREDUCE):
+        F = jnp.asarray(fwd_slots)  # [R, p] static slot tables (root row
+        A = jnp.asarray(acc_slots)  # pinned to the identity slot n+1)
+        R = F.shape[0]
+        garbage = jnp.full((1,), n, jnp.int32)
+        bufs, errs, qmsgs, smsgs, metas = [], [], [], [], []
+        for flat in flats:
+            with scope(tracing.SPLIT):
+                buf, slot = _split_blocks(flat, n, step, qblock)
+                nb = math.prod(slot) // qblock
+                # slot n+1 is the sum identity (zero), as in _reduce_phase.
+                buf = jnp.concatenate(
+                    [buf, jnp.zeros((1,) + slot, buf.dtype)], axis=0
+                )[None]                                   # [1, n+2, *slot]
+                err = jnp.zeros_like(buf)
+            # Initial capture+drain of round 0's forwarded partial (zero
+            # message: dequant(0, 0) == 0 folds into the garbage slot).
+            buf, err, qm, sm = step.qacc_shuffle(
+                buf, err, jnp.zeros((1,) + slot, jnp.int8),
+                jnp.zeros((1, nb), jnp.float32), garbage, F[0, r][None])
+            bufs.append(buf)
+            errs.append(err)
+            qmsgs.append(qm)
+            smsgs.append(sm)
+            metas.append((nb, flat.shape[0]))
+        for t in range(R):
+            got_q = [jax.lax.ppermute(m, axis_name, perms[t]) for m in qmsgs]
+            got_s = [jax.lax.ppermute(m, axis_name, perms[t]) for m in smsgs]
+            nxt = F[t + 1, r][None] if t + 1 < R else garbage
+            for i in range(len(bufs)):
+                bufs[i], errs[i], qmsgs[i], smsgs[i] = step.qacc_shuffle(
+                    bufs[i], errs[i], got_q[i], got_s[i], A[t, r][None], nxt)
+        return [(buf, err) + meta
+                for buf, err, meta in zip(bufs, errs, metas)]
 
 
 def _quantized_allreduce_core(flats, n, fwd_slots, acc_slots, recv_slots,
@@ -437,41 +453,43 @@ def _quantized_allreduce_core(flats, n, fwd_slots, acc_slots, recv_slots,
                              axis_name, r, step, qblock)
     q_flats, s_flats, err_flats, sizes, nbs = [], [], [], [], []
     for buf, err, nb, size in reduced:
-        data = buf[0, :n]                              # [n, *slot]
-        q, sc = quant_blocks(data.reshape(n * nb, qblock))
-        eps = quant_error(data.reshape(n * nb, qblock), q,
-                          sc).reshape(data.shape)
-        is_root = r == root
-        # Non-root rows were drained by the reduce, but capped re-sends
-        # can leave stale partials in slot n-1 -- zero them exactly as
-        # _lower_broadcast zeroes non-root payloads.
-        q_flats.append(jnp.where(is_root, q.reshape(-1),
-                                 jnp.zeros((q.size,), jnp.int8)))
-        s_flats.append(jnp.where(is_root, sc.reshape(-1),
-                                 jnp.zeros((n * nb,), jnp.float32)))
-        # The final quantization error belongs to the root (the rank
-        # that generated it); everyone else contributes zero.
-        e = err[0, :n] + jnp.where(is_root, eps, jnp.zeros_like(eps))
-        err_flats.append(e.reshape(-1))
+        with scope(tracing.REQUANT):
+            data = buf[0, :n]                          # [n, *slot]
+            q, sc = quant_blocks(data.reshape(n * nb, qblock))
+            eps = quant_error(data.reshape(n * nb, qblock), q,
+                              sc).reshape(data.shape)
+            is_root = r == root
+            # Non-root rows were drained by the reduce, but capped
+            # re-sends can leave stale partials in slot n-1 -- zero them
+            # exactly as _lower_broadcast zeroes non-root payloads.
+            q_flats.append(jnp.where(is_root, q.reshape(-1),
+                                     jnp.zeros((q.size,), jnp.int8)))
+            s_flats.append(jnp.where(is_root, sc.reshape(-1),
+                                     jnp.zeros((n * nb,), jnp.float32)))
+            # The final quantization error belongs to the root (the rank
+            # that generated it); everyone else contributes zero.
+            e = err[0, :n] + jnp.where(is_root, eps, jnp.zeros_like(eps))
+            err_flats.append(e.reshape(-1))
         sizes.append(size)
         nbs.append(nb)
     outs = _bcast_phase(q_flats + s_flats, n, recv_slots, send_slots,
                         bc_perms, axis_name, r, step)
     L = len(q_flats)
     sums, errs = [], []
-    for i in range(L):
-        nb, size = nbs[i], sizes[i]
-        red = dequant_blocks(
-            outs[i].reshape(n * nb, qblock),
-            outs[L + i].reshape(n * nb, 1),
-        ).reshape(-1)[:size]
-        # Pad-lane error is identically zero (all ranks pad with exact
-        # zeros), but fold the tail anyway so truncation provably never
-        # drops error mass.
-        e_full = err_flats[i]
-        e = e_full[:size].at[size - 1].add(jnp.sum(e_full[size:]))
-        sums.append(red)
-        errs.append(e)
+    with scope(tracing.JOIN):
+        for i in range(L):
+            nb, size = nbs[i], sizes[i]
+            red = dequant_blocks(
+                outs[i].reshape(n * nb, qblock),
+                outs[L + i].reshape(n * nb, 1),
+            ).reshape(-1)[:size]
+            # Pad-lane error is identically zero (all ranks pad with
+            # exact zeros), but fold the tail anyway so truncation
+            # provably never drops error mass.
+            e_full = err_flats[i]
+            e = e_full[:size].at[size - 1].add(jnp.sum(e_full[size:]))
+            sums.append(red)
+            errs.append(e)
     return sums, errs
 
 
@@ -546,13 +564,16 @@ def _lower_broadcast(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
     def body(*shards):
         r = jax.lax.axis_index(axis_name)
         flats, shapes = [], []
-        for xs in shards:
-            flat = xs.reshape(-1)
-            flats.append(jnp.where(r == root, flat, jnp.zeros_like(flat)))
-            shapes.append(xs.shape)
+        with scope(tracing.SPLIT):
+            for xs in shards:
+                flat = xs.reshape(-1)
+                flats.append(jnp.where(r == root, flat,
+                                       jnp.zeros_like(flat)))
+                shapes.append(xs.shape)
         outs = _bcast_phase(flats, n, recv_slots, send_slots, perms,
                             axis_name, r, step, overlap=overlap)
-        return tuple(f.reshape(shape) for f, shape in zip(outs, shapes))
+        with scope(tracing.JOIN):
+            return tuple(f.reshape(shape) for f, shape in zip(outs, shapes))
 
     shard_fn = jax.shard_map(
         body,
@@ -578,14 +599,16 @@ def _lower_allgather(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
 
     def body(*shards):
         r = jax.lax.axis_index(axis_name)
-        flats = [xs.reshape(-1) for xs in shards]
+        with scope(tracing.SPLIT):
+            flats = [xs.reshape(-1) for xs in shards]
         shapes = [xs.shape for xs in shards]
         outs = _allgather_phase(flats, n, recv_slots, skips, perms,
                                 axis_name, r, p, step, overlap=overlap)
-        return tuple(
-            f.reshape((p * shape[0],) + tuple(shape[1:]))
-            for f, shape in zip(outs, shapes)
-        )
+        with scope(tracing.JOIN):
+            return tuple(
+                f.reshape((p * shape[0],) + tuple(shape[1:]))
+                for f, shape in zip(outs, shapes)
+            )
 
     shard_fn = jax.shard_map(
         body,
@@ -615,51 +638,55 @@ def _lower_allgatherv(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
     L = spec.num_leaves
 
     def body(*shards):
-        r = jax.lax.axis_index(axis_name)
-        S = jnp.asarray(recv_slots)  # [R, p] static slot table
-        allbufs: List[List[jnp.ndarray]] = []
-        for xs, slots, cap in zip(shards, slots_all, caps):
-            flat = xs.reshape(-1)  # own contribution padded to cap
-            bufs = []
-            for j in range(p):
-                e = math.prod(slots[j])
-                pj = jnp.pad(flat[: min(cap, n * e)],
-                             (0, max(0, n * e - cap)))
-                own = jnp.concatenate(
-                    [pj[: n * e].reshape((n,) + slots[j]),
-                     jnp.zeros((1,) + slots[j], xs.dtype)], axis=0)
-                bufs.append(jnp.where(r == j, own, jnp.zeros_like(own)))
-            allbufs.append(bufs)
-        for t in range(R):
-            sk = skips[t]
-            gots, all_slots = [], []
-            for bufs in allbufs:
-                parts, slots_r = [], []
-                for j in range(p):
-                    ss = S[t][(r - j + sk) % p]
-                    slots_r.append(S[t][(r - j) % p])
-                    parts.append(
-                        step.pack(bufs[j][None], ss[None])[0].reshape(-1))
-                msg = jnp.concatenate(parts)  # [sum of slot sizes]
-                gots.append(jax.lax.ppermute(msg, axis_name, perms[t]))
-                all_slots.append(slots_r)
-            for bufs, slots, got, slots_r in zip(allbufs, slots_all, gots,
-                                                 all_slots):
-                o = 0
-                for j in range(p):
-                    e = math.prod(slots[j])
-                    piece = got[o: o + e].reshape(slots[j])[None]
-                    bufs[j] = step.unpack(bufs[j][None], piece,
-                                          slots_r[j][None])[0]
-                    o += e
-        outs = []
-        for bufs, sizes, cap in zip(allbufs, sizes_canon, caps):
-            rows = []
-            for j in range(p):
-                rj = bufs[j][:n].reshape(-1)[: sizes[j]]
-                rows.append(jnp.pad(rj, (0, cap - sizes[j])))
-            outs.append(jnp.stack(rows))
-        return tuple(outs)
+        with scope(tracing.ALLGATHERV):
+            r = jax.lax.axis_index(axis_name)
+            S = jnp.asarray(recv_slots)  # [R, p] static slot table
+            allbufs: List[List[jnp.ndarray]] = []
+            for xs, slots, cap in zip(shards, slots_all, caps):
+                with scope(tracing.SPLIT):
+                    flat = xs.reshape(-1)  # own contribution padded to cap
+                    bufs = []
+                    for j in range(p):
+                        e = math.prod(slots[j])
+                        pj = jnp.pad(flat[: min(cap, n * e)],
+                                     (0, max(0, n * e - cap)))
+                        own = jnp.concatenate(
+                            [pj[: n * e].reshape((n,) + slots[j]),
+                             jnp.zeros((1,) + slots[j], xs.dtype)], axis=0)
+                        bufs.append(jnp.where(r == j, own,
+                                              jnp.zeros_like(own)))
+                allbufs.append(bufs)
+            for t in range(R):
+                sk = skips[t]
+                gots, all_slots = [], []
+                for bufs in allbufs:
+                    parts, slots_r = [], []
+                    for j in range(p):
+                        ss = S[t][(r - j + sk) % p]
+                        slots_r.append(S[t][(r - j) % p])
+                        parts.append(
+                            step.pack(bufs[j][None], ss[None])[0].reshape(-1))
+                    msg = jnp.concatenate(parts)  # [sum of slot sizes]
+                    gots.append(jax.lax.ppermute(msg, axis_name, perms[t]))
+                    all_slots.append(slots_r)
+                for bufs, slots, got, slots_r in zip(allbufs, slots_all, gots,
+                                                     all_slots):
+                    o = 0
+                    for j in range(p):
+                        e = math.prod(slots[j])
+                        piece = got[o: o + e].reshape(slots[j])[None]
+                        bufs[j] = step.unpack(bufs[j][None], piece,
+                                              slots_r[j][None])[0]
+                        o += e
+            outs = []
+            with scope(tracing.JOIN):
+                for bufs, sizes, cap in zip(allbufs, sizes_canon, caps):
+                    rows = []
+                    for j in range(p):
+                        rj = bufs[j][:n].reshape(-1)[: sizes[j]]
+                        rows.append(jnp.pad(rj, (0, cap - sizes[j])))
+                    outs.append(jnp.stack(rows))
+            return tuple(outs)
 
     shard_fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis_name),) * L,
@@ -683,15 +710,17 @@ def _lower_reduce(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
 
     def body(*shards):
         r = jax.lax.axis_index(axis_name)
-        flats = [xs.reshape(-1) for xs in shards]
+        with scope(tracing.SPLIT):
+            flats = [xs.reshape(-1) for xs in shards]
         shapes = [xs.shape for xs in shards]
         outs = _reduce_phase(flats, n, fwd_slots, acc_slots, perms,
                              axis_name, r, idents, op, step,
                              overlap=overlap)
-        return tuple(
-            jnp.where(r == root, f, jnp.zeros_like(f)).reshape(shape)
-            for f, shape in zip(outs, shapes)
-        )
+        with scope(tracing.JOIN):
+            return tuple(
+                jnp.where(r == root, f, jnp.zeros_like(f)).reshape(shape)
+                for f, shape in zip(outs, shapes)
+            )
 
     shard_fn = jax.shard_map(
         body,
@@ -718,47 +747,52 @@ def _lower_reduce_scatter(mesh: Mesh, axis_name: str, bundle: ScheduleBundle,
     L = spec.num_leaves
 
     def body(*shards):
-        r = jax.lax.axis_index(axis_name)
-        F = jnp.asarray(fwd_slots)  # [R, p] static slot tables
-        A = jnp.asarray(acc_slots)
-        base = (r - jnp.arange(p)) % p
-        garbage = jnp.full((p,), n, jnp.int32)
-        bufs, msgs, meta = [], [], []
-        for xs, shard, slot in zip(shards, shard_l, slot_l):
-            rows = xs[0].reshape(p, shard)            # contribution per root
-            rows = jnp.pad(rows, ((0, 0), (0, n * math.prod(slot) - shard)))
-            # Partials accumulate in _acc_dtype: native for ints (so the
-            # sums are bit-exact) and >= float32 floats, widened to
-            # float32 for bf16/f16 stability.
-            buf = jnp.concatenate(
-                [rows.reshape((p, n) + slot),
-                 jnp.zeros((p, 1) + slot, xs.dtype)],
-                axis=1,
-            ).astype(_acc_dtype(xs.dtype))
-            # Initial capture+drain of round 0's forwarded partials.
-            buf, msg = step.acc_shuffle(
-                buf, jnp.zeros((p,) + slot, buf.dtype), garbage, F[0][base],
-                op="sum")
-            bufs.append(buf)
-            msgs.append(msg)
-            meta.append((shard, slot, xs.dtype))
-        for t in range(R):
-            got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
-            nxt = F[t + 1][base] if t + 1 < R else garbage
-            for i in range(L):
-                if overlap:
-                    pre = step.pack(bufs[i], nxt)
-                    bufs[i], msgs[i] = step.acc_shuffle_staged(
-                        bufs[i], got[i], pre, A[t][base], nxt, op="sum")
-                else:
-                    bufs[i], msgs[i] = step.acc_shuffle(
-                        bufs[i], got[i], A[t][base], nxt, op="sum")
-        outs = []
-        for buf, (shard, slot, dt) in zip(bufs, meta):
-            own = jax.lax.dynamic_slice(buf, (r,) + (0,) * (buf.ndim - 1),
-                                        (1, n) + slot)
-            outs.append(own.reshape(-1)[:shard].astype(dt)[None])
-        return tuple(outs)
+        with scope(tracing.SCATTER):
+            r = jax.lax.axis_index(axis_name)
+            F = jnp.asarray(fwd_slots)  # [R, p] static slot tables
+            A = jnp.asarray(acc_slots)
+            base = (r - jnp.arange(p)) % p
+            garbage = jnp.full((p,), n, jnp.int32)
+            bufs, msgs, meta = [], [], []
+            for xs, shard, slot in zip(shards, shard_l, slot_l):
+                with scope(tracing.SPLIT):
+                    rows = xs[0].reshape(p, shard)  # contribution per root
+                    rows = jnp.pad(
+                        rows, ((0, 0), (0, n * math.prod(slot) - shard)))
+                    # Partials accumulate in _acc_dtype: native for ints
+                    # (so the sums are bit-exact) and >= float32 floats,
+                    # widened to float32 for bf16/f16 stability.
+                    buf = jnp.concatenate(
+                        [rows.reshape((p, n) + slot),
+                         jnp.zeros((p, 1) + slot, xs.dtype)],
+                        axis=1,
+                    ).astype(_acc_dtype(xs.dtype))
+                # Initial capture+drain of round 0's forwarded partials.
+                buf, msg = step.acc_shuffle(
+                    buf, jnp.zeros((p,) + slot, buf.dtype), garbage,
+                    F[0][base], op="sum")
+                bufs.append(buf)
+                msgs.append(msg)
+                meta.append((shard, slot, xs.dtype))
+            for t in range(R):
+                got = [jax.lax.ppermute(m, axis_name, perms[t])
+                       for m in msgs]
+                nxt = F[t + 1][base] if t + 1 < R else garbage
+                for i in range(L):
+                    if overlap:
+                        pre = step.pack(bufs[i], nxt)
+                        bufs[i], msgs[i] = step.acc_shuffle_staged(
+                            bufs[i], got[i], pre, A[t][base], nxt, op="sum")
+                    else:
+                        bufs[i], msgs[i] = step.acc_shuffle(
+                            bufs[i], got[i], A[t][base], nxt, op="sum")
+            outs = []
+            with scope(tracing.JOIN):
+                for buf, (shard, slot, dt) in zip(bufs, meta):
+                    own = jax.lax.dynamic_slice(
+                        buf, (r,) + (0,) * (buf.ndim - 1), (1, n) + slot)
+                    outs.append(own.reshape(-1)[:shard].astype(dt)[None])
+            return tuple(outs)
 
     shard_fn = jax.shard_map(
         body,
@@ -787,13 +821,15 @@ def _lower_quantized_allreduce(mesh: Mesh, axis_name: str,
 
     def body(*shards):
         r = jax.lax.axis_index(axis_name)
-        flats = [xs.reshape(-1) for xs in shards]
+        with scope(tracing.SPLIT):
+            flats = [xs.reshape(-1) for xs in shards]
         shapes = [xs.shape for xs in shards]
         sums, errs = _quantized_allreduce_core(
             flats, n, fwd_slots, acc_slots, recv_slots, send_slots,
             red_perms, bc_perms, axis_name, r, root, step, qblock)
-        return (tuple(f.reshape(s) for f, s in zip(sums, shapes))
-                + tuple(f.reshape(s) for f, s in zip(errs, shapes)))
+        with scope(tracing.JOIN):
+            return (tuple(f.reshape(s) for f, s in zip(sums, shapes))
+                    + tuple(f.reshape(s) for f, s in zip(errs, shapes)))
 
     shard_fn = jax.shard_map(
         body,
@@ -846,6 +882,11 @@ class CollectivePlan:
     #: buffer concurrently with the in-flight exchange, then patched by
     #: the staged step.  Bit-exact vs the sequential executor.
     overlap: bool = False
+    #: ``ppermute``s one call issues (one per leaf per round, two on the
+    #: quantized wire: int8 blocks and their scales) and the bytes one
+    #: rank sends in a call; static, counted at plan time.
+    permutes: int = 0
+    wire_bytes: int = 0
     #: Auditable per-phase schedule statics (the exact cached slot
     #: tables the executor closed over); () on the p == 1 fast path.
     #: Checked by repro.analysis.planaudit without executing a round.
@@ -856,10 +897,13 @@ class CollectivePlan:
         """Execute the collective.  ``quantized_allreduce`` plans return
         a ``(sums, errors)`` pair of payload-shaped trees; every other
         kind returns one payload-shaped tree."""
-        validate_payload(self.spec, payload)
-        if self._execute is None:  # p == 1 fast path: nothing moves
-            return payload
-        return self._execute(payload)
+        with span(tracing.CALL):
+            with span(tracing.VALIDATE):
+                validate_payload(self.spec, payload)
+            if self._execute is None:  # p == 1 fast path: nothing moves
+                return payload
+            with span(tracing.EXECUTE):
+                return self._execute(payload)
 
     def describe(self) -> str:
         """One-line human summary of the plan."""
@@ -870,6 +914,7 @@ class CollectivePlan:
             extra += " overlap"
         return (f"{self.kind} p={self.p} root={self.root} "
                 f"n={self.n_blocks} rounds={self.rounds} "
+                f"permutes={self.permutes} wire_bytes={self.wire_bytes} "
                 f"backend={self.backend}{extra} spec={self.spec.describe()}")
 
 
@@ -892,6 +937,47 @@ def _plan_statics(kind: str, bundle: ScheduleBundle, n: int,
     # allreduce / quantized_allreduce: reversed reduce then broadcast
     return (reduce_phase_static(bundle, n, axis=axis, overlap=overlap),
             broadcast_phase_static(bundle, n, axis=axis, overlap=overlap))
+
+
+def _message_bytes(step, elems: int, dtype, n: int, rows: int = 1,
+                   qblock: Optional[int] = None) -> int:
+    """Bytes of one round's message for one leaf: ``rows`` slots of a
+    flat vector of ``elems`` elements split into ``n`` blocks, in the
+    round step's slot layout (as :func:`_split_blocks` lays them out)."""
+    slot = step.slot_shape(-(-elems // n), dtype, qblock)
+    return rows * math.prod(slot) * np.dtype(dtype).itemsize
+
+
+def _wire_bytes(kind: str, spec: PayloadSpec, p: int, n: int, rounds: int,
+                step, qblock: Optional[int], sizes_canon) -> int:
+    """Bytes one rank sends in one call of a flat collective: each
+    round's messages, summed over the leaves, times the rounds."""
+    if kind == "quantized_allreduce":
+        rounds //= 2  # a round below is one reduce and one broadcast round
+    per_round = 0
+    for i, (shape, dtype) in enumerate(spec.leaves):
+        if kind == "allgatherv":
+            per_round += sum(_message_bytes(step, max(1, s), dtype, n)
+                             for s in sizes_canon[i])
+        elif kind == "allgather":
+            per_round += _message_bytes(
+                step, shape[0] // p * _leaf_elems(shape[1:]), dtype, n, p)
+        elif kind == "reduce_scatter":
+            per_round += _message_bytes(step, max(1, shape[1] // p),
+                                        _acc_dtype(dtype), n, p)
+        elif kind == "quantized_allreduce":
+            # one reduce round (int8 blocks + per-qblock f32 scales) and
+            # one broadcast round (the same, as two leaves)
+            slot = math.prod(step.slot_shape(
+                -(-_leaf_elems(shape[1:]) // n), np.float32, qblock))
+            scales = slot // qblock
+            per_round += (slot + 4 * scales
+                          + _message_bytes(step, n * slot, np.int8, n)
+                          + _message_bytes(step, n * scales, np.float32, n))
+        else:
+            per_round += _message_bytes(step, _leaf_elems(shape[1:]), dtype,
+                                        n)
+    return rounds * per_round
 
 
 # --------------------------------------------------------- n-block choice
@@ -1215,6 +1301,10 @@ class CirculantComm:
             kind=kind, spec=spec, p=p, root=root, op=op, n_blocks=n,
             rounds=rounds, backend=self.backend, axis_name=self.axis_name,
             qblock=qblock, overlap=overlap,
+            permutes=rounds * spec.num_leaves * (
+                2 if kind == "quantized_allreduce" else 1),
+            wire_bytes=_wire_bytes(kind, spec, p, n, rounds, step, qblock,
+                                   sizes_canon),
             statics=_plan_statics(kind, bundle, n, axis, overlap=overlap),
             _execute=jax.jit(ex))
 

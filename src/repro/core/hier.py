@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from . import tracing
 from .costmodel import DEFAULT_MODEL, CommModel, optimal_hier_blocks
 from .engine import cached_plan, get_bundle
 from .roundstep import (
@@ -76,6 +77,7 @@ from .comm import (
     _allgather_phase,
     _bcast_phase,
     _leaf_elems,
+    _message_bytes,
     _reduce_phase,
     _require,
     _rot_perm,
@@ -84,6 +86,7 @@ from .comm import (
     payload_spec,
     validate_payload,
 )
+from .tracing import scope, span
 
 __all__ = [
     "HIER_KINDS",
@@ -198,12 +201,15 @@ def _lower_hier(mesh: Mesh, inter_axis: str, intra_axis: str, kind: str,
     def body(*shards):
         node = jax.lax.axis_index(inter_axis)
         core = jax.lax.axis_index(intra_axis)
+        is_root = (node == rootN) & (core == rootC)
         shapes = [xs.shape for xs in shards]
-        flats = [xs.reshape(-1) for xs in shards]
+        with scope(tracing.SPLIT):
+            flats = [xs.reshape(-1) for xs in shards]
+            if kind == "broadcast":
+                flats = [jnp.where(is_root, f, jnp.zeros_like(f))
+                         for f in flats]
 
         if kind == "broadcast":
-            is_root = (node == rootN) & (core == rootC)
-            flats = [jnp.where(is_root, f, jnp.zeros_like(f)) for f in flats]
             if inter is not None:   # leaders: broadcast across nodes
                 (recv, send), perms, _ = inter
                 flats = _bcast_phase(flats, nN, recv, send, perms,
@@ -212,8 +218,9 @@ def _lower_hier(mesh: Mesh, inter_axis: str, intra_axis: str, kind: str,
                 (recv, send), perms, _ = intra
                 flats = _bcast_phase(flats, nC, recv, send, perms,
                                      intra_axis, core, step)
-            return tuple(f.reshape(shape) for f, shape in
-                         zip(flats, shapes))
+            with scope(tracing.JOIN):
+                return tuple(f.reshape(shape) for f, shape in
+                             zip(flats, shapes))
 
         if kind == "reduce":
             if rintra is not None:  # each node reduces to its leader
@@ -224,10 +231,10 @@ def _lower_hier(mesh: Mesh, inter_axis: str, intra_axis: str, kind: str,
                 (fwd, acc), perms = rinter
                 flats = _reduce_phase(flats, nN, fwd, acc, perms,
                                       inter_axis, node, idents, op, step)
-            is_root = (node == rootN) & (core == rootC)
-            return tuple(
-                jnp.where(is_root, f, jnp.zeros_like(f)).reshape(shape)
-                for f, shape in zip(flats, shapes))
+            with scope(tracing.JOIN):
+                return tuple(
+                    jnp.where(is_root, f, jnp.zeros_like(f)).reshape(shape)
+                    for f, shape in zip(flats, shapes))
 
         if kind == "allreduce":
             if rintra is not None:
@@ -246,8 +253,9 @@ def _lower_hier(mesh: Mesh, inter_axis: str, intra_axis: str, kind: str,
                 (recv, send), perms, _ = intra
                 flats = _bcast_phase(flats, nC, recv, send, perms,
                                      intra_axis, core, step)
-            return tuple(f.reshape(shape) for f, shape in
-                         zip(flats, shapes))
+            with scope(tracing.JOIN):
+                return tuple(f.reshape(shape) for f, shape in
+                             zip(flats, shapes))
 
         # allgather: intra phase (fused leader-gather + fan-out), then
         # inter exchange of the node blocks -- rank-major output.
@@ -259,9 +267,10 @@ def _lower_hier(mesh: Mesh, inter_axis: str, intra_axis: str, kind: str,
             (recv, _), perms, skips = inter
             flats = _allgather_phase(flats, nN, recv, skips, perms,
                                      inter_axis, node, N, step)
-        return tuple(
-            f.reshape((N * C * shape[0],) + tuple(shape[1:]))
-            for f, shape in zip(flats, shapes))
+        with scope(tracing.JOIN):
+            return tuple(
+                f.reshape((N * C * shape[0],) + tuple(shape[1:]))
+                for f, shape in zip(flats, shapes))
 
     replicated_out = kind == "allgather"
     shard_fn = jax.shard_map(
@@ -336,6 +345,10 @@ class HierPlan:
     backend: str
     inter_axis: str
     intra_axis: str
+    #: ``ppermute``s one call issues (one per leaf per round) and the
+    #: bytes one rank sends in a call; static, counted at plan time.
+    permutes: int = 0
+    wire_bytes: int = 0
     #: Auditable per-phase schedule statics in execution order (see
     #: repro.analysis.planaudit); () on the p == 1 fast path.
     statics: Tuple[PhaseStatic, ...] = field(repr=False, default=())
@@ -346,10 +359,13 @@ class HierPlan:
         return self.nodes * self.cores
 
     def __call__(self, payload: Any) -> Any:
-        validate_payload(self.spec, payload)
-        if self._execute is None:  # p == 1 fast path: nothing moves
-            return payload
-        return self._execute(payload)
+        with span(tracing.CALL):
+            with span(tracing.VALIDATE):
+                validate_payload(self.spec, payload)
+            if self._execute is None:  # p == 1 fast path: nothing moves
+                return payload
+            with span(tracing.EXECUTE):
+                return self._execute(payload)
 
     def describe(self) -> str:
         """One-line human summary of the plan."""
@@ -357,7 +373,8 @@ class HierPlan:
         return (f"hier-{self.kind} mesh={self.nodes}x{self.cores} "
                 f"root={self.root} n=({self.n_inter},{self.n_intra}) "
                 f"rounds={self.rounds} (inter {self.rounds_inter} + intra "
-                f"{self.rounds_intra}) backend={self.backend}{extra} "
+                f"{self.rounds_intra}) permutes={self.permutes} "
+                f"wire_bytes={self.wire_bytes} backend={self.backend}{extra} "
                 f"spec={self.spec.describe()}")
 
 
@@ -511,12 +528,28 @@ class HierComm:
                       inter_axis=self.inter_axis, intra_axis=self.intra_axis)
         if self.p == 1:
             return HierPlan(_execute=None, **common)
+        step = get_round_step(self.backend)
+        wire = 0
+        for shape, dtype in spec.leaves:
+            if kind == "allgather":
+                # intra rounds carry one slot per core row, inter rounds
+                # one per node row of the node blocks
+                e = shape[0] // self.p * _leaf_elems(shape[1:])
+                wire += (rC * _message_bytes(step, e, dtype, nC, cores)
+                         + rN * _message_bytes(step, cores * e, dtype, nN,
+                                               nodes))
+            else:
+                e = _leaf_elems(shape[1:])
+                wire += scale * (rN * _message_bytes(step, e, dtype, nN)
+                                 + rC * _message_bytes(step, e, dtype, nC))
         rootN, rootC = divmod(root, cores)
         bN = get_bundle(nodes, rootN)
         bC = get_bundle(cores, rootC)
         ex = _lower_hier(self.mesh, self.inter_axis, self.intra_axis, kind,
                          bN, bC, nN, nC, rootN, rootC, op, self.backend, spec)
         return HierPlan(_execute=jax.jit(ex),
+                        permutes=common["rounds"] * spec.num_leaves,
+                        wire_bytes=wire,
                         statics=_hier_statics(kind, bN, bC, nN, nC,
                                               self.inter_axis,
                                               self.intra_axis),

@@ -44,6 +44,9 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from . import tracing
+from .tracing import scope
+
 __all__ = [
     "RoundStep",
     "JnpRoundStep",
@@ -323,30 +326,38 @@ class JnpRoundStep(RoundStep):
         return (int(bs),)
 
     def pack(self, buf, idx):
-        return _jnp_call("block_pack_ref", buf, idx)
+        with scope(tracing.RS_PACK):
+            return _jnp_call("block_pack_ref", buf, idx)
 
     def unpack(self, buf, msg, idx):
-        return _jnp_call("block_unpack_ref", buf, msg, idx)
+        with scope(tracing.RS_UNPACK):
+            return _jnp_call("block_unpack_ref", buf, msg, idx)
 
     def shuffle(self, buf, msg, recv_idx, send_idx):
-        return _jnp_call("block_shuffle_ref", buf, msg, recv_idx, send_idx)
+        with scope(tracing.RS_SHUFFLE):
+            return _jnp_call("block_shuffle_ref", buf, msg, recv_idx,
+                             send_idx)
 
     def shuffle_staged(self, buf, msg, pre, recv_idx, send_idx):
-        return _jnp_call("block_shuffle_staged_ref", buf, msg, pre,
-                         recv_idx, send_idx)
+        with scope(tracing.RS_SHUFFLE_STAGED):
+            return _jnp_call("block_shuffle_staged_ref", buf, msg, pre,
+                             recv_idx, send_idx)
 
     def acc_shuffle(self, buf, msg, acc_idx, fwd_idx, *, op: str = "sum"):
-        return _jnp_call("block_acc_shuffle_ref", buf, msg, acc_idx, fwd_idx,
-                         op=op)
+        with scope(tracing.RS_ACC_SHUFFLE):
+            return _jnp_call("block_acc_shuffle_ref", buf, msg, acc_idx,
+                             fwd_idx, op=op)
 
     def acc_shuffle_staged(self, buf, msg, pre, acc_idx, fwd_idx, *,
                            op: str = "sum"):
-        return _jnp_call("block_acc_shuffle_staged_ref", buf, msg, pre,
-                         acc_idx, fwd_idx, op=op)
+        with scope(tracing.RS_ACC_SHUFFLE_STAGED):
+            return _jnp_call("block_acc_shuffle_staged_ref", buf, msg, pre,
+                             acc_idx, fwd_idx, op=op)
 
     def qacc_shuffle(self, buf, err, qmsg, smsg, acc_idx, fwd_idx):
-        return _jnp_call("block_qacc_shuffle_ref", buf, err, qmsg, smsg,
-                         acc_idx, fwd_idx)
+        with scope(tracing.RS_QACC_SHUFFLE):
+            return _jnp_call("block_qacc_shuffle_ref", buf, err, qmsg, smsg,
+                             acc_idx, fwd_idx)
 
 
 _jnp_jits = {}
@@ -399,43 +410,51 @@ class PallasRoundStep(RoundStep):
     def pack(self, buf, idx):
         from repro.kernels.ops import schedule_pack
 
-        return schedule_pack(buf, idx, interpret=self.interpret)
+        with scope(tracing.RS_PACK):
+            return schedule_pack(buf, idx, interpret=self.interpret)
 
     def unpack(self, buf, msg, idx):
         from repro.kernels.ops import schedule_unpack
 
-        return schedule_unpack(buf, msg, idx, interpret=self.interpret)
+        with scope(tracing.RS_UNPACK):
+            return schedule_unpack(buf, msg, idx, interpret=self.interpret)
 
     def shuffle(self, buf, msg, recv_idx, send_idx):
         from repro.kernels.ops import schedule_shuffle
 
-        return schedule_shuffle(buf, msg, recv_idx, send_idx,
-                                interpret=self.interpret)
+        with scope(tracing.RS_SHUFFLE):
+            return schedule_shuffle(buf, msg, recv_idx, send_idx,
+                                    interpret=self.interpret)
 
     def shuffle_staged(self, buf, msg, pre, recv_idx, send_idx):
         from repro.kernels.ops import schedule_shuffle_staged
 
-        return schedule_shuffle_staged(buf, msg, pre, recv_idx, send_idx,
-                                       interpret=self.interpret)
+        with scope(tracing.RS_SHUFFLE_STAGED):
+            return schedule_shuffle_staged(buf, msg, pre, recv_idx, send_idx,
+                                           interpret=self.interpret)
 
     def acc_shuffle(self, buf, msg, acc_idx, fwd_idx, *, op: str = "sum"):
         from repro.kernels.ops import schedule_acc_shuffle
 
-        return schedule_acc_shuffle(buf, msg, acc_idx, fwd_idx, op=op,
-                                    interpret=self.interpret)
+        with scope(tracing.RS_ACC_SHUFFLE):
+            return schedule_acc_shuffle(buf, msg, acc_idx, fwd_idx, op=op,
+                                        interpret=self.interpret)
 
     def acc_shuffle_staged(self, buf, msg, pre, acc_idx, fwd_idx, *,
                            op: str = "sum"):
         from repro.kernels.ops import schedule_acc_shuffle_staged
 
-        return schedule_acc_shuffle_staged(buf, msg, pre, acc_idx, fwd_idx,
-                                           op=op, interpret=self.interpret)
+        with scope(tracing.RS_ACC_SHUFFLE_STAGED):
+            return schedule_acc_shuffle_staged(buf, msg, pre, acc_idx,
+                                               fwd_idx, op=op,
+                                               interpret=self.interpret)
 
     def qacc_shuffle(self, buf, err, qmsg, smsg, acc_idx, fwd_idx):
         from repro.kernels.ops import schedule_qacc_shuffle
 
-        return schedule_qacc_shuffle(buf, err, qmsg, smsg, acc_idx, fwd_idx,
-                                     interpret=self.interpret)
+        with scope(tracing.RS_QACC_SHUFFLE):
+            return schedule_qacc_shuffle(buf, err, qmsg, smsg, acc_idx,
+                                         fwd_idx, interpret=self.interpret)
 
 
 _step_handles = {}

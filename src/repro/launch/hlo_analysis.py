@@ -27,11 +27,16 @@ _DTYPE_BYTES = {
     "c128": 16, "s4": 1, "u4": 1, "f8e4m3fn": 1, "f8e5m2": 1,
 }
 
+# The TPU compiler writes an asynchronous ``collective-permute-start``
+# with a tuple shape (``(f32[..], f32[..], u32[], u32[])``): the shape
+# group takes a parenthesised tuple too, and such an op counts the bytes
+# of the tuple's first element.
 _COLL_RE = re.compile(
-    r"=\s*(\S+?)\s+"
+    r"=\s*(\([^=]*?\)|\S+?)\s+"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(?:-start)?\("
 )
+_FIRST_SHAPE_RE = re.compile(r"[a-z0-9_]+\[[0-9,]*\]")
 _SHAPE_RE = re.compile(r"^\(?([a-z0-9]+)\[([0-9,]*)\]")
 
 
@@ -286,7 +291,11 @@ def collective_stats(hlo_text: str) -> CollectiveStats:
         for l in lines:
             cm = _COLL_RE.search(l)
             if cm:
-                own[name].append((cm.group(2), _shape_bytes(cm.group(1))))
+                shape = cm.group(1)
+                if shape.startswith("("):
+                    first = _FIRST_SHAPE_RE.search(shape)
+                    shape = first.group(0) if first else ""
+                own[name].append((cm.group(2), _shape_bytes(shape)))
             wm = re.search(
                 r"while\(.*?\).*?condition=%?([\w.\-]+),\s*body=%?([\w.\-]+)", l
             )

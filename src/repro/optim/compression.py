@@ -344,16 +344,19 @@ def compressed_grad_sync(grads, err_buckets, axis_name: str, p: int,
     ``(mean_grads, new_err_buckets)`` with mean_grads in the gradient
     dtypes and errors satisfying the completeness invariant.
     """
+    from repro.core import tracing
     from repro.core.comm import circulant_qallreduce_body
 
-    flats = bucketize(grads, spec)
-    targets = [f + e.reshape(-1) for f, e in zip(flats, err_buckets)]
+    with tracing.scope(tracing.BUCKET):
+        flats = bucketize(grads, spec)
+        targets = [f + e.reshape(-1) for f, e in zip(flats, err_buckets)]
     sums, errs = circulant_qallreduce_body(
         targets, axis_name, p, n_blocks=n_blocks, backend=backend,
         qblock=qblock)
-    means = [s / p for s in sums]
-    mean_tree, deltas = unbucketize(means, spec, grads)
-    new_errs = tuple(e + d for e, d in zip(errs, deltas))
+    with tracing.scope(tracing.BUCKET):
+        means = [s / p for s in sums]
+        mean_tree, deltas = unbucketize(means, spec, grads)
+        new_errs = tuple(e + d for e, d in zip(errs, deltas))
     return mean_tree, new_errs
 
 

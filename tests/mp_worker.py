@@ -709,6 +709,78 @@ def check_ring(p, elems=16):
     print(f"ring p={p} ok")
 
 
+def check_tracing(p, calls=3):
+    """Each plan call writes one ``circulant.call`` host span with one
+    ``circulant.validate`` and one ``circulant.execute`` nested in it,
+    flat and hierarchical plans alike; for every kind each plan's static
+    counters match the collective-permutes of its compiled HLO and their
+    bytes, and the HLO carries the round-step and layout scopes."""
+    import glob
+    import tempfile
+
+    from jax.profiler import ProfileData
+
+    from repro.core import tracing
+    from repro.core.comm import CirculantComm
+    from repro.core.hier import HierComm
+    from repro.launch.hlo_analysis import collective_stats
+
+    mesh = make_mesh(p)
+    comm = CirculantComm(mesh, "data")
+    x = sharded(mesh, jnp.arange(p * 300, dtype=jnp.float32).reshape(p, 300))
+    hmesh = Mesh(np.array(jax.devices()[:p]).reshape(2, p // 2),
+                 ("node", "core"))
+    hx = jax.device_put(x, NamedSharding(hmesh, P(("node", "core"))))
+    hcomm = HierComm(hmesh, "node", "core")
+    runs = [(comm.plan("allreduce", x, n_blocks=3), x),
+            (comm.plan("quantized_allreduce", x, n_blocks=2), x),
+            (hcomm.plan("allreduce", hx), hx)]
+    rs = sharded(mesh, jnp.ones((p, 8 * p), jnp.int32))
+    counted = runs + [
+        (comm.plan("broadcast", x, n_blocks=4, root=1), x),
+        (comm.plan("allgather", x, n_blocks=3), x),
+        (comm.plan("allgatherv", x, n_blocks=2,
+                   sizes=[300 - 7 * j for j in range(p)]), x),
+        (comm.plan("reduce_scatter", rs, n_blocks=3), rs),
+        (CirculantComm(mesh, "data", backend="pallas").plan(
+            "allreduce", x, n_blocks=3), x),
+        (hcomm.plan("allgather", hx), hx),
+        (hcomm.plan("broadcast", hx, root=1), hx)]
+    for plan, arg in counted:
+        text = jax.jit(plan).lower(arg).compile().as_text()
+        stats = collective_stats(text)
+        assert stats.ops_by_kind["collective-permute"] == plan.permutes, (
+            plan.describe(), stats.ops_by_kind)
+        assert stats.bytes_by_kind["collective-permute"] == plan.wire_bytes, (
+            plan.describe(), stats.bytes_by_kind)
+        for name in ("roundstep.", tracing.SPLIT, tracing.JOIN):
+            assert name in text, (plan.kind, name)
+    for plan, arg in runs:
+        jax.block_until_ready(plan(arg))           # compile outside the trace
+    with tempfile.TemporaryDirectory() as logdir:
+        jax.profiler.start_trace(logdir)
+        try:
+            for plan, arg in runs:
+                for _ in range(calls):
+                    jax.block_until_ready(plan(arg))
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+        spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                 for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name.startswith("circulant.")]
+    outer = [s for s in spans if s[0] == tracing.CALL]
+    assert len(outer) == calls * len(runs), spans
+    for inner in (tracing.VALIDATE, tracing.EXECUTE):
+        assert sum(s[0] == inner for s in spans) == len(outer), inner
+        for _, a, b in outer:
+            assert sum(s[0] == inner and a <= s[1] and s[2] <= b
+                       for s in spans) == 1, (inner, a, b)
+    print(f"tracing ok: {len(outer)} calls")
+
+
 def main(what, p, backend="jnp", nodes=2):
     if len(jax.devices()) < p:
         # Graceful skip (e.g. a backend that ignores the host-device
@@ -766,6 +838,8 @@ def main(what, p, backend="jnp", nodes=2):
         check_allbroadcast(p)
     if what in ("comm", "all"):
         check_comm(p, backend=backend)
+    if what == "tracing":
+        check_tracing(p)
     print("ALL OK")
 
 

@@ -135,3 +135,74 @@ def test_broadcast_plan_lowers_to_schedule_permutes(topo):
     text = jax.jit(plan).lower(x).compile().as_text()
     starts = text.count("collective-permute-start(")
     assert (starts or text.count("collective-permute(")) == plan.rounds
+
+
+# What stays unscoped in each benchmark cell's entry computation after
+# inheritance (bench/scopes.py), by opcode: in the allreduce the
+# partition index, copies of the input and of scalars into other memory,
+# the zero slot buffers the compiler materializes and the one
+# dynamic-update-slice that fills them (built from the input with no
+# op_name); in the rank stack scalar copies of constants.
+UNSCOPED = {
+    "ddp_allreduce.25m": {
+        "partition-id": 1, "and": 1, "convert": 1, "copy": 4,
+        "copy-start": 5, "copy-done": 5, "fusion": 1, "reshape": 1,
+        "broadcast": 4, "dynamic-update-slice": 1},
+    "int8_gradsync.4m.rankstack": {"copy": 24, "copy-start": 2,
+                                   "copy-done": 2},
+}
+NOT_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+            "while", "conditional", "call", "collective-permute-start",
+            "collective-permute-done"}
+
+
+def _bench_compile(name, topo):
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.aot import compile_cell
+
+    return compile_cell(name, topo)[0].as_text()
+
+
+@pytest.mark.parametrize("name", sorted(UNSCOPED))
+def test_cell_programs_name_their_work(topo, name):
+    """Every instruction of the cell's entry computation that does work
+    carries a program scope or inherits one, but a stated remainder."""
+    from collections import Counter
+
+    from bench.scopes import instruction_scopes, parse
+
+    text = _bench_compile(name, topo)
+    comps, entry = parse(text)
+    scopes = instruction_scopes(text)
+    work = [i for i in comps[entry] if i.opcode not in NOT_WORK]
+    unscoped = Counter(i.opcode for i in work if scopes[i.name] is None)
+    assert dict(unscoped) == UNSCOPED[name]
+    assert any(s.startswith("roundstep.") for s in scopes.values() if s)
+
+
+def test_plan_permutes_and_wire_bytes_match_compiled_hlo(topo):
+    """The plans' static counters equal the collective-permutes of their
+    TPU executables and those permutes' bytes, as counted by
+    repro.launch.hlo_analysis (whose pattern reads the TPU compiler's
+    tuple-shaped asynchronous permutes)."""
+    from repro.core.comm import CirculantComm
+    from repro.launch.hlo_analysis import collective_stats
+
+    p = 4
+    mesh = Mesh(np.array(topo.devices[:p]), ("x",))
+    sh = NamedSharding(mesh, P("x"))
+    comm = CirculantComm(mesh, "x")
+    ddp = _arg((p, 26214400 // 4), jnp.float32, sh)
+    small = _arg((p, 1 << 20), jnp.float32, sh)
+    for plan, x, permutes in (
+            (comm.plan("allreduce", ddp), ddp, 48),
+            (comm.plan("quantized_allreduce", small, n_blocks=8), small, 36)):
+        assert plan.permutes == permutes
+        stats = collective_stats(jax.jit(plan).lower(x).compile().as_text())
+        assert stats.ops_by_kind == {"collective-permute": permutes}
+        assert stats.bytes_by_kind["collective-permute"] == plan.wire_bytes
