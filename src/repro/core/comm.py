@@ -883,10 +883,12 @@ class CollectivePlan:
     #: the staged step.  Bit-exact vs the sequential executor.
     overlap: bool = False
     #: ``ppermute``s one call issues (one per leaf per round, two on the
-    #: quantized wire: int8 blocks and their scales) and the bytes one
-    #: rank sends in a call; static, counted at plan time.
+    #: quantized wire: int8 blocks and their scales), the bytes one rank
+    #: sends in a call, and the payload leaves whose slots the round
+    #: step lays out as tile stacks; static, counted at plan time.
     permutes: int = 0
     wire_bytes: int = 0
+    tiled_leaves: int = 0
     #: Auditable per-phase schedule statics (the exact cached slot
     #: tables the executor closed over); () on the p == 1 fast path.
     #: Checked by repro.analysis.planaudit without executing a round.
@@ -915,6 +917,7 @@ class CollectivePlan:
         return (f"{self.kind} p={self.p} root={self.root} "
                 f"n={self.n_blocks} rounds={self.rounds} "
                 f"permutes={self.permutes} wire_bytes={self.wire_bytes} "
+                f"tiled_leaves={self.tiled_leaves} "
                 f"backend={self.backend}{extra} spec={self.spec.describe()}")
 
 
@@ -939,45 +942,67 @@ def _plan_statics(kind: str, bundle: ScheduleBundle, n: int,
             broadcast_phase_static(bundle, n, axis=axis, overlap=overlap))
 
 
-def _message_bytes(step, elems: int, dtype, n: int, rows: int = 1,
-                   qblock: Optional[int] = None) -> int:
-    """Bytes of one round's message for one leaf: ``rows`` slots of a
-    flat vector of ``elems`` elements split into ``n`` blocks, in the
-    round step's slot layout (as :func:`_split_blocks` lays them out)."""
-    slot = step.slot_shape(-(-elems // n), dtype, qblock)
-    return rows * math.prod(slot) * np.dtype(dtype).itemsize
+def _message_slot(step, elems: int, dtype, n: int,
+                  qblock: Optional[int] = None) -> Tuple[int, ...]:
+    """Slot of one round's message for one leaf: a flat vector of
+    ``elems`` elements split into ``n`` blocks, in the round step's slot
+    layout (as :func:`_split_blocks` lays them out)."""
+    return step.slot_shape(-(-elems // n), dtype, qblock)
 
 
-def _wire_bytes(kind: str, spec: PayloadSpec, p: int, n: int, rounds: int,
-                step, qblock: Optional[int], sizes_canon) -> int:
-    """Bytes one rank sends in one call of a flat collective: each
-    round's messages, summed over the leaves, times the rounds."""
+def _messages_bytes(msgs) -> int:
+    """Bytes of ``(slot, dtype, rows)`` messages: ``rows`` slots each."""
+    return sum(rows * math.prod(slot) * np.dtype(dt).itemsize
+               for slot, dt, rows in msgs)
+
+
+def _leaf_messages(kind: str, shape, dtype, p: int, n: int, step,
+                   qblock: Optional[int], sizes) -> list:
+    """``(slot, dtype, rows)`` of each message one round of a flat
+    collective sends for one payload leaf (``sizes``: the leaf's
+    canonical allgatherv sizes)."""
+    def msg(elems, dt, rows=1):
+        return _message_slot(step, elems, dt, n), dt, rows
+
+    if kind == "allgatherv":
+        return [msg(max(1, s), dtype) for s in sizes]
+    if kind == "allgather":
+        return [msg(shape[0] // p * _leaf_elems(shape[1:]), dtype, p)]
+    if kind == "reduce_scatter":
+        return [msg(max(1, shape[1] // p), _acc_dtype(dtype), p)]
     if kind == "quantized_allreduce":
-        rounds //= 2  # a round below is one reduce and one broadcast round
-    per_round = 0
-    for i, (shape, dtype) in enumerate(spec.leaves):
-        if kind == "allgatherv":
-            per_round += sum(_message_bytes(step, max(1, s), dtype, n)
-                             for s in sizes_canon[i])
-        elif kind == "allgather":
-            per_round += _message_bytes(
-                step, shape[0] // p * _leaf_elems(shape[1:]), dtype, n, p)
-        elif kind == "reduce_scatter":
-            per_round += _message_bytes(step, max(1, shape[1] // p),
-                                        _acc_dtype(dtype), n, p)
-        elif kind == "quantized_allreduce":
-            # one reduce round (int8 blocks + per-qblock f32 scales) and
-            # one broadcast round (the same, as two leaves)
-            slot = math.prod(step.slot_shape(
-                -(-_leaf_elems(shape[1:]) // n), np.float32, qblock))
-            scales = slot // qblock
-            per_round += (slot + 4 * scales
-                          + _message_bytes(step, n * slot, np.int8, n)
-                          + _message_bytes(step, n * scales, np.float32, n))
-        else:
-            per_round += _message_bytes(step, _leaf_elems(shape[1:]), dtype,
-                                        n)
-    return rounds * per_round
+        # one reduce round (int8 blocks + per-qblock f32 scales) and
+        # one broadcast round (the same, as two leaves)
+        slot = _message_slot(step, _leaf_elems(shape[1:]), np.float32, n,
+                             qblock)
+        elems = math.prod(slot)
+        scales = elems // qblock
+        return [(slot, np.int8, 1), ((scales,), np.float32, 1),
+                msg(n * elems, np.int8), msg(n * scales, np.float32)]
+    return [msg(_leaf_elems(shape[1:]), dtype)]
+
+
+def _plan_messages(kind: str, spec: PayloadSpec, p: int, n: int, step,
+                   qblock: Optional[int], sizes_canon) -> list:
+    """Per payload leaf, :func:`_leaf_messages`."""
+    return [_leaf_messages(kind, shape, dtype, p, n, step, qblock,
+                           sizes_canon[i] if sizes_canon else None)
+            for i, (shape, dtype) in enumerate(spec.leaves)]
+
+
+def _wire_bytes(kind: str, leaves, rounds: int) -> int:
+    """Bytes one rank sends in one call of a flat collective: each
+    round's messages (``leaves``, from :func:`_plan_messages`), summed
+    over the leaves, times the rounds."""
+    if kind == "quantized_allreduce":
+        rounds //= 2  # a round above is one reduce and one broadcast round
+    return rounds * sum(_messages_bytes(msgs) for msgs in leaves)
+
+
+def _tiled_leaves(leaves) -> int:
+    """Leaves (``leaves``, from :func:`_plan_messages`) with a message
+    slot laid out as a tile stack rather than flat."""
+    return sum(any(len(slot) > 1 for slot, _, _ in msgs) for msgs in leaves)
 
 
 # --------------------------------------------------------- n-block choice
@@ -1264,6 +1289,8 @@ class CirculantComm:
         for _, dt in spec.leaves:
             step.slot_shape(1, _acc_dtype(dt) if kind == "reduce_scatter"
                             else dt, qblock)
+        messages = _plan_messages(kind, spec, p, n, step, qblock,
+                                  sizes_canon)
         bundle = get_bundle(p, root)
         mesh, axis = self.mesh, self.axis_name
         if kind == "broadcast":
@@ -1303,8 +1330,8 @@ class CirculantComm:
             qblock=qblock, overlap=overlap,
             permutes=rounds * spec.num_leaves * (
                 2 if kind == "quantized_allreduce" else 1),
-            wire_bytes=_wire_bytes(kind, spec, p, n, rounds, step, qblock,
-                                   sizes_canon),
+            wire_bytes=_wire_bytes(kind, messages, rounds),
+            tiled_leaves=_tiled_leaves(messages),
             statics=_plan_statics(kind, bundle, n, axis, overlap=overlap),
             _execute=jax.jit(ex))
 

@@ -50,6 +50,7 @@ backends on CPU CI, including the full 36x32 grid.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Tuple
 
@@ -77,7 +78,7 @@ from .comm import (
     _allgather_phase,
     _bcast_phase,
     _leaf_elems,
-    _message_bytes,
+    _message_slot,
     _reduce_phase,
     _require,
     _rot_perm,
@@ -345,10 +346,12 @@ class HierPlan:
     backend: str
     inter_axis: str
     intra_axis: str
-    #: ``ppermute``s one call issues (one per leaf per round) and the
-    #: bytes one rank sends in a call; static, counted at plan time.
+    #: ``ppermute``s one call issues (one per leaf per round), the bytes
+    #: one rank sends in a call, and the payload leaves whose slots the
+    #: round step lays out as tile stacks; static, counted at plan time.
     permutes: int = 0
     wire_bytes: int = 0
+    tiled_leaves: int = 0
     #: Auditable per-phase schedule statics in execution order (see
     #: repro.analysis.planaudit); () on the p == 1 fast path.
     statics: Tuple[PhaseStatic, ...] = field(repr=False, default=())
@@ -374,7 +377,9 @@ class HierPlan:
                 f"root={self.root} n=({self.n_inter},{self.n_intra}) "
                 f"rounds={self.rounds} (inter {self.rounds_inter} + intra "
                 f"{self.rounds_intra}) permutes={self.permutes} "
-                f"wire_bytes={self.wire_bytes} backend={self.backend}{extra} "
+                f"wire_bytes={self.wire_bytes} "
+                f"tiled_leaves={self.tiled_leaves} "
+                f"backend={self.backend}{extra} "
                 f"spec={self.spec.describe()}")
 
 
@@ -529,19 +534,25 @@ class HierComm:
         if self.p == 1:
             return HierPlan(_execute=None, **common)
         step = get_round_step(self.backend)
-        wire = 0
+        wire = tiled = 0
         for shape, dtype in spec.leaves:
+            # (rounds, elements, blocks, rows) of each level's messages
             if kind == "allgather":
                 # intra rounds carry one slot per core row, inter rounds
                 # one per node row of the node blocks
                 e = shape[0] // self.p * _leaf_elems(shape[1:])
-                wire += (rC * _message_bytes(step, e, dtype, nC, cores)
-                         + rN * _message_bytes(step, cores * e, dtype, nN,
-                                               nodes))
+                levels = ((rC, e, nC, cores), (rN, cores * e, nN, nodes))
             else:
                 e = _leaf_elems(shape[1:])
-                wire += scale * (rN * _message_bytes(step, e, dtype, nN)
-                                 + rC * _message_bytes(step, e, dtype, nC))
+                levels = ((scale * rN, e, nN, 1), (scale * rC, e, nC, 1))
+            leaf_tiled = False
+            for rounds, elems, nb, rows in levels:
+                if rounds:
+                    slot = _message_slot(step, elems, dtype, nb)
+                    wire += (rounds * rows * math.prod(slot)
+                             * np.dtype(dtype).itemsize)
+                    leaf_tiled |= len(slot) > 1
+            tiled += leaf_tiled
         rootN, rootC = divmod(root, cores)
         bN = get_bundle(nodes, rootN)
         bC = get_bundle(cores, rootC)
@@ -550,6 +561,7 @@ class HierComm:
         return HierPlan(_execute=jax.jit(ex),
                         permutes=common["rounds"] * spec.num_leaves,
                         wire_bytes=wire,
+                        tiled_leaves=tiled,
                         statics=_hier_statics(kind, bN, bC, nN, nC,
                                               self.inter_axis,
                                               self.intra_axis),

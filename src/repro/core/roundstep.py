@@ -14,11 +14,24 @@ shares one per-round inner step on its block buffers:
 are ``[R, nslots, *slot]`` arrays (R rows: one per rank in the batched
 simulator data plane, one per root in the all-gather family, a single
 row inside a per-rank ``shard_map`` body), with the slot layout each
-backend asks for through :meth:`RoundStep.slot_shape` (flat ``(bs,)``
-for jnp, a ``(rows, lanes)`` tile stack for Pallas); slot vectors are ``[R]``
-int32 columns of the engine's per-round tables
+backend asks for through :meth:`RoundStep.slot_shape`; slot vectors are
+``[R]`` int32 columns of the engine's per-round tables
 (:meth:`ScheduleBundle.per_round_tables` /
 :meth:`ScheduleBundle.reversed_per_round_tables`).
+
+Slot layout.  Pallas always lays a slot out as a ``(rows, 128)`` tile
+stack (:func:`repro.kernels.layout.slot_shape`), which its kernels need
+to compile.  The jnp backend takes the same tile stack for a plain slot
+whenever it pads the slot by at most ``1/TILE_PAD`` of its elements, and
+keeps the flat ``(bs,)`` layout otherwise: for small slots, whose tile
+padding would inflate the wire, and for quantized (``qblock``) slots.
+The reason is the TPU's memory layout: an array's last two dimensions
+are stored in (8, 128) tiles (16 or 32 rows for narrower dtypes), so in
+a flat ``[R, nslots, bs]`` buffer the slot index is a tile row, and a
+slot write or read touches one sublane of every tile of the buffer.  In
+a tile stack the slot index lies outside the tile, so each round writes
+and reads whole tiles of one slot.  Only the layout differs: the values,
+their accumulation order and the zero padding are the same.
 
 Two backends implement it:
 
@@ -39,10 +52,13 @@ and by the backend-parametrized collective tests.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
 import numpy as np
+
+from repro.kernels import layout
 
 from . import tracing
 from .tracing import scope
@@ -71,6 +87,10 @@ __all__ = [
 ]
 
 BACKENDS = ("jnp", "pallas")
+
+#: A plain jnp slot takes the tile stack when the stack pads it by at
+#: most ``1/TILE_PAD`` of its elements, and stays flat otherwise.
+TILE_PAD = 32
 
 
 # ------------------------------------------------------------ slot plans
@@ -322,7 +342,10 @@ class JnpRoundStep(RoundStep):
 
     def slot_shape(self, bs, dtype, qblock=None):
         if qblock is not None:
-            bs = -(-bs // qblock) * qblock
+            return (int(-(-bs // qblock) * qblock),)
+        tiles = layout.slot_shape(bs, dtype)
+        if TILE_PAD * (math.prod(tiles) - bs) <= bs:
+            return tiles
         return (int(bs),)
 
     def pack(self, buf, idx):

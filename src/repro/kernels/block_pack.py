@@ -23,8 +23,9 @@ dimensions are multiples of the dtype's native tile (8x128 for 32-bit,
 16x128 for 16-bit, 32x128 for 8-bit values) or span the whole array.
 A buffer slot is therefore laid out as a ``[rows, lanes]`` tile stack:
 buffers are ``[R, nslots, rows, lanes]`` and messages ``[R, rows,
-lanes]`` (:func:`slot_shape` gives the layout for a block of ``bs``
-elements; plans hold their buffers in it for every round).  The grid is
+lanes]`` (:func:`slot_shape`, from :mod:`repro.kernels.layout`, gives
+the layout for a block of ``bs`` elements; plans hold their buffers in
+it for every round).  The grid is
 ``(R, tiles)`` -- or ``(R, tiles, 2)`` for the two-step accumulate/drain
 kernels -- where each grid point moves one ``[tile_rows, lanes]`` tile
 of at most :data:`MAX_BLOCK_BYTES`, so every kernel's double-buffered
@@ -68,20 +69,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Slot layout arithmetic, shared with the jnp backend.
+from .layout import LANES, QROWS, slot_shape, sublanes, tileable
 # Single source for combine/identity semantics across kernels, the jnp
 # oracles and the collectives (re-exported here for consumers that only
 # know the kernel module).
 from .quant_ops import dequant_blocks, quant_blocks, quant_error
 from .reduce_ops import op_combine, op_identity
 
-#: Lane width of a slot tile (the TPU vector register width).
-LANES = 128
 #: Upper bound on one operand block; with double buffering and the
 #: fused kernels' five or so operands this keeps a kernel within a few
 #: MiB of v5e's 16 MiB default scoped VMEM.
 MAX_BLOCK_BYTES = 512 * 1024
-#: Quantization blocks per tile row group: the int8 wire tile is 32 rows.
-QROWS = 32
 
 _SQ = pl.Squeezed()
 # Lane-tile index of every block: an explicit int32 so the index maps
@@ -100,32 +99,6 @@ def _resolve(interpret):
 
 
 # ----------------------------------------------------------- slot layout
-
-
-def sublanes(dtype) -> int:
-    """Rows of one native TPU tile: 8 for 32-bit, 16 for 16-bit, 32 for
-    8-bit values (64-bit values have no TPU tile; 8 keeps them working
-    in interpret mode)."""
-    return 8 * max(1, 4 // np.dtype(dtype).itemsize)
-
-
-def tileable(dtype) -> bool:
-    """True when the compiled kernels can hold ``dtype`` (not 64-bit)."""
-    return np.dtype(dtype).itemsize <= 4
-
-
-def slot_shape(bs: int, dtype, qblock: Optional[int] = None) -> Tuple[int, int]:
-    """``(rows, lanes)`` of a buffer slot holding ``bs`` elements.
-
-    Plain slots are ``LANES`` wide with rows padded to the dtype's tile
-    (``sublanes(dtype) * LANES`` elements).  Quantized-wire slots
-    (``qblock`` given) hold one quantization block per row, padded to
-    ``QROWS`` rows so the int8 payload tiles too.
-    """
-    if qblock is not None:
-        return -(-max(1, -(-bs // qblock)) // QROWS) * QROWS, int(qblock)
-    sub = sublanes(dtype)
-    return -(-max(1, -(-bs // LANES)) // sub) * sub, LANES
 
 
 def row_tile(rows: int, lanes: int, dtype, unit: Optional[int] = None) -> int:

@@ -31,6 +31,11 @@ def _take_slot(buffers, idx):
     return jnp.take_along_axis(buffers, col, axis=1)[:, 0]
 
 
+def _rows(mask, msg):
+    """[R] row mask -> broadcastable against [R, *slot] messages."""
+    return mask.reshape((-1,) + (1,) * (msg.ndim - 1))
+
+
 def block_pack_ref(buffers, idx):
     """buffers: [R, nslots, *slot]; idx: [R] int32 -> packed [R, *slot]."""
     return _take_slot(buffers, idx)
@@ -60,7 +65,7 @@ def block_shuffle_staged_ref(buffers, msg, pre, recv_idx, send_idx):
     :func:`block_shuffle_ref`.  Returns (new_buffers, out_msg)."""
     rows = jnp.arange(buffers.shape[0])
     buffers = buffers.at[rows, recv_idx].set(msg, mode="promise_in_bounds")
-    out = jnp.where((recv_idx == send_idx)[:, None], msg, pre)
+    out = jnp.where(_rows(recv_idx == send_idx, msg), msg, pre)
     return buffers, out
 
 
@@ -82,7 +87,7 @@ def block_acc_shuffle_staged_ref(buffers, msg, pre, acc_idx, fwd_idx,
     buffers = buffers.at[rows, acc_idx].set(
         combined, mode="promise_in_bounds"
     )
-    out = jnp.where((acc_idx == fwd_idx)[:, None], combined, pre)
+    out = jnp.where(_rows(acc_idx == fwd_idx, pre), combined, pre)
     ident = op_identity(op, buffers.dtype)
     buffers = buffers.at[rows, fwd_idx].set(
         jnp.full_like(out, ident), mode="promise_in_bounds"

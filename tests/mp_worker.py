@@ -247,10 +247,19 @@ def check_overlap(p, backend="jnp"):
     mesh = make_mesh(p)
     comm = get_comm(mesh, "data", backend=backend)
     rng = np.random.default_rng(43)
+    # "t" holds 3 blocks of 8192 integer-valued floats: the jnp round
+    # step lays them out as tile stacks, and every sum is exact.
+    t = rng.integers(-9, 9, size=(p, 3 * 8192)).astype(np.float32)
     xs = {"w": sharded(mesh, jnp.asarray(
         rng.normal(size=(p, 37)).astype(np.float32))),
         "b": sharded(mesh, jnp.asarray(
-            rng.integers(-9, 9, size=(p, 11)).astype(np.int32)))}
+            rng.integers(-9, 9, size=(p, 11)).astype(np.int32))),
+        "t": sharded(mesh, jnp.asarray(t))}
+    want_t = {"broadcast": np.broadcast_to(t[p - 1], t.shape),
+              "allgather": t,
+              "reduce": np.where(np.arange(p)[:, None] == p - 1,
+                                 t.sum(0), 0),
+              "allreduce": np.broadcast_to(t.sum(0), t.shape)}
     for kind in ("broadcast", "allgather", "reduce", "allreduce"):
         rooted = kind in ("broadcast", "reduce")
         kw = dict(n_blocks=3, root=p - 1 if rooted else 0)
@@ -258,10 +267,13 @@ def check_overlap(p, backend="jnp"):
         ovl = comm.plan(kind, xs, overlap=True, **kw)
         assert ovl is not seq and ovl.overlap and not seq.overlap, \
             f"{kind}: overlap plan not distinct from sequential"
+        assert seq.tiled_leaves == (1 if backend == "jnp" else 3), \
+            seq.describe()
         a, b = seq(xs), ovl(xs)
-        for k in ("w", "b"):
+        for k in ("w", "b", "t"):
             np.testing.assert_array_equal(np.asarray(a[k]),
                                           np.asarray(b[k]))
+        np.testing.assert_array_equal(np.asarray(a["t"]), want_t[kind])
         print(f"overlap {kind} p={p} backend={backend} ok")
     # max-op reduce: the staged drain path must match for non-sum ops.
     fs = {"a": xs["w"]}
@@ -271,10 +283,14 @@ def check_overlap(p, backend="jnp"):
     print(f"overlap reduce(max) p={p} backend={backend} ok")
     # reduce_scatter needs p-divisible shards.
     m = {"m": sharded(mesh, jnp.asarray(
-        rng.normal(size=(p, p * 8)).astype(np.float32)))}
+        rng.normal(size=(p, p * 8)).astype(np.float32))),
+        "t": sharded(mesh, jnp.asarray(t[:, :p * 2048]))}  # tiled shards
     a = comm.reduce_scatter(m, n_blocks=2)
     b = comm.reduce_scatter(m, n_blocks=2, overlap=True)
-    np.testing.assert_array_equal(np.asarray(a["m"]), np.asarray(b["m"]))
+    for k in ("m", "t"):
+        np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+    np.testing.assert_array_equal(
+        np.asarray(a["t"]), t[:, :p * 2048].sum(0).reshape(p, 2048))
     print(f"overlap reduce_scatter p={p} backend={backend} ok")
     # unsupported kinds must be rejected, not silently sequential.
     try:
@@ -736,7 +752,11 @@ def check_tracing(p, calls=3):
             (comm.plan("quantized_allreduce", x, n_blocks=2), x),
             (hcomm.plan("allreduce", hx), hx)]
     rs = sharded(mesh, jnp.ones((p, 8 * p), jnp.int32))
-    counted = runs + [
+    # 2048-element blocks: the jnp round step lays them out as tile stacks
+    big = sharded(mesh, jnp.ones((p, 8192), jnp.float32))
+    tiled = comm.plan("allreduce", big, n_blocks=4)
+    assert tiled.tiled_leaves == 1 and runs[0][0].tiled_leaves == 0
+    counted = runs + [(tiled, big),
         (comm.plan("broadcast", x, n_blocks=4, root=1), x),
         (comm.plan("allgather", x, n_blocks=3), x),
         (comm.plan("allgatherv", x, n_blocks=2,

@@ -13,6 +13,7 @@ schedule-stack fast lane's coverage of the Pallas path):
      on int and float dtypes.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -23,6 +24,7 @@ from repro.core.roundstep import (
     dataplane_reduce,
     get_round_step,
 )
+from repro.kernels import layout
 from repro.core.simulator import (
     simulate_allbroadcast,
     simulate_allreduce,
@@ -104,6 +106,107 @@ def test_acc_shuffle_semantics():
         assert np.array_equal(nb[1, 1], [8 + 10, 9 + 10])
         assert np.array_equal(out[1], [10, 11])
         assert np.array_equal(nb[1, 2], [0, 0])
+
+
+# ------------------------------------------------ jnp slot layout rule
+
+
+@pytest.mark.parametrize("bs,dtype,qblock,shape", [
+    (-(-(26214400 // 4) // 23), jnp.float32, None, (2232, 128)),  # 25 MiB/23
+    ((4096 // 4) // 4, jnp.float32, None, (256,)),                # 4 KiB/4
+    (31000, jnp.float32, None, (248, 128)),    # pads 744 <= 31000/32
+    (30721, jnp.float32, None, (30721,)),      # pads 1023 > 30721/32
+    (32768, jnp.bfloat16, None, (256, 128)),   # 16-row tiles, no pad
+    (2049, jnp.bfloat16, None, (2049,)),       # a 32x128 stack would pad 2047
+    (209920, jnp.int8, None, (1664, 128)),     # 32-row tiles, +1.5%
+    (1 << 20, jnp.float32, 256, (1 << 20,)),   # quantized: always flat
+    (300, jnp.float32, 256, (512,)),           # ... in whole qblocks
+])
+def test_jnp_slot_rule(bs, dtype, qblock, shape):
+    """Large plain slots take the tile stack; small ones and quantized
+    ones stay flat."""
+    assert get_round_step("jnp").slot_shape(bs, dtype, qblock) == shape
+
+
+def test_jnp_slot_rule_64bit_under_x64():
+    """64-bit values have no TPU tile; the stack takes 8 rows, as in the
+    Pallas interpret path."""
+    step = get_round_step("jnp")
+    with jax.enable_x64(True):
+        assert layout.sublanes(jnp.float64) == 8
+        assert step.slot_shape(4096, jnp.float64) == (32, 128)
+        assert step.slot_shape(100, jnp.int64) == (100,)
+
+
+JNP_METHODS = ["pack", "unpack", "shuffle", "shuffle_staged", "acc_shuffle",
+               "acc_shuffle_staged"]
+
+
+def _run_method(step, method, buf, msg, a, b):
+    if method == "pack":
+        return (step.pack(buf, b),)
+    if method == "unpack":
+        return (step.unpack(buf, msg, a),)
+    if method == "shuffle":
+        return step.shuffle(buf, msg, a, b)
+    if method == "shuffle_staged":
+        return step.shuffle_staged(buf, msg, step.pack(buf, b), a, b)
+    if method == "acc_shuffle":
+        return step.acc_shuffle(buf, msg, a, b)
+    return step.acc_shuffle_staged(buf, msg, step.pack(buf, b), a, b)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32,
+                                   jnp.int8])
+@pytest.mark.parametrize("method", JNP_METHODS)
+def test_jnp_methods_bitexact_flat_vs_tiled(method, dtype):
+    """Every jnp method gives the same blocks on a flat and on a tiled
+    buffer holding them; R equals the tile's rows so a row mask that
+    broadcasts against the wrong axis shows."""
+    bs = 1000
+    rows, lanes = layout.slot_shape(bs, dtype)
+    R, ns = rows, 6
+    pad = rows * lanes - bs
+    flat_buf = jnp.asarray(_rand((R, ns, bs), dtype))
+    flat_msg = jnp.asarray(_rand((R, bs), dtype))
+    a = jnp.asarray(RNG.integers(0, ns, size=R), jnp.int32)
+    b = jnp.asarray(RNG.integers(0, ns, size=R), jnp.int32)
+    b = b.at[0].set(a[0])   # the pipeline / same-slot case on row 0
+
+    def tiled(x):
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+        return x.reshape(x.shape[:-1] + (rows, lanes))
+
+    step = get_round_step("jnp")
+    flat = _run_method(step, method, flat_buf, flat_msg, a, b)
+    tile = _run_method(step, method, tiled(flat_buf), tiled(flat_msg), a, b)
+    for f, t in zip(flat, tile):
+        assert t.shape == f.shape[:-1] + (rows, lanes)
+        t = np.asarray(t).reshape(t.shape[:-2] + (-1,))
+        np.testing.assert_array_equal(t[..., :bs], np.asarray(f))
+        # the sum identity is zero, so the padding lanes stay zero
+        assert not np.any(t[..., bs:])
+
+
+def test_tiled_leaves_counts_tile_stacked_leaves():
+    """A ddp-shaped plan (25 MiB f32 at p=4, n=23) lays its one leaf out
+    as a tile stack; a 4 KiB plan keeps it flat; the quantized plan's
+    int8 broadcast leaf tiles; Pallas tiles every leaf."""
+    from repro.core.comm import _plan_messages, _tiled_leaves, payload_spec
+
+    def count(kind, leaves, n, backend="jnp", qblock=None):
+        spec = payload_spec([jax.ShapeDtypeStruct(s, d) for s, d in leaves])
+        return _tiled_leaves(_plan_messages(
+            kind, spec, 4, n, get_round_step(backend), qblock, None))
+
+    ddp = ((4, 26214400 // 4), jnp.float32)
+    small = ((4, 4096 // 4), jnp.float32)
+    assert count("allreduce", [ddp], 23) == 1
+    assert count("allreduce", [small], 4) == 0
+    assert count("allreduce", [ddp, small], 23) == 1
+    assert count("quantized_allreduce", [((4, 1 << 20), jnp.float32)], 5,
+                 qblock=256) == 1
+    assert count("allreduce", [small], 4, backend="pallas") == 1
 
 
 def test_unknown_backend_raises():

@@ -5,7 +5,9 @@ is described rather than attached.  These tests guard what interpret
 mode cannot see: every round-step kernel must lower through Mosaic at
 deployment block sizes (tile-aligned blocks, scoped-VMEM budget), and
 the jnp-backend broadcast plan must lower to the schedule's round count
-of ``collective-permute``s on a 4-chip mesh.
+of ``collective-permute``s on a 4-chip mesh, and the jnp round step's
+slot reads and writes in the allreduce cell must start on tile
+boundaries.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and the suite runs on
@@ -13,6 +15,7 @@ several workers.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -139,17 +142,16 @@ def test_broadcast_plan_lowers_to_schedule_permutes(topo):
 
 # What stays unscoped in each benchmark cell's entry computation after
 # inheritance (bench/scopes.py), by opcode: in the allreduce the
-# partition index, copies of the input and of scalars into other memory,
-# the zero slot buffers the compiler materializes and the one
-# dynamic-update-slice that fills them (built from the input with no
-# op_name); in the rank stack scalar copies of constants.
+# partition index and copies of the input and of scalars into other
+# memory (the split of the tile-stacked slots is one pad, so no zero
+# slot buffer is filled outside a scope); in the rank stack scalar
+# copies of constants.
 UNSCOPED = {
     "ddp_allreduce.25m": {
-        "partition-id": 1, "and": 1, "convert": 1, "copy": 4,
-        "copy-start": 5, "copy-done": 5, "fusion": 1, "reshape": 1,
-        "broadcast": 4, "dynamic-update-slice": 1},
-    "int8_gradsync.4m.rankstack": {"copy": 24, "copy-start": 2,
-                                   "copy-done": 2},
+        "partition-id": 1, "and": 1, "convert": 1, "copy-start": 4,
+        "copy-done": 4},
+    "int8_gradsync.4m.rankstack": {"copy": 18, "copy-start": 1,
+                                   "copy-done": 1},
 }
 NOT_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "while", "conditional", "call", "collective-permute-start",
@@ -206,3 +208,78 @@ def test_plan_permutes_and_wire_bytes_match_compiled_hlo(topo):
         stats = collective_stats(jax.jit(plan).lower(x).compile().as_text())
         assert stats.ops_by_kind == {"collective-permute": permutes}
         assert stats.bytes_by_kind["collective-permute"] == plan.wire_bytes
+
+
+_LINE_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?\s([a-z][a-z0-9\-]*)\(")
+_COMP_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_ALIGNED_RE = re.compile(r'"is_index_aligned":\[([a-z,]*)\]')
+
+
+def _roundstep_slot_accesses(text):
+    """``(instruction, scope, is_index_aligned)`` of every dynamic-slice
+    and dynamic-update-slice under a ``roundstep.*`` scope: its own
+    ``op_name``'s, else that of the fusion whose computation holds it
+    (fused roots included), else the one it inherits."""
+    from bench.scopes import instruction_scopes, scope_of
+
+    scopes = instruction_scopes(text)
+    parsed, fused_scope, comp = [], {}, None
+    for line in text.splitlines():
+        c = _COMP_RE.match(line)
+        if c and "=" not in line.split("{", 1)[0].split("(", 1)[0]:
+            comp = c.group(1)
+            continue
+        m = _LINE_RE.match(line)
+        if not m:
+            continue
+        op = _OP_NAME_RE.search(line)
+        own = scope_of(op.group(1)) if op else None
+        calls = _CALLS_RE.search(line)
+        if m.group(2) == "fusion" and calls:
+            fused_scope[calls.group(1)] = scopes.get(m.group(1)) or own
+        aligned = _ALIGNED_RE.search(line)
+        parsed.append((comp, m.group(1), m.group(2), own,
+                       aligned.group(1) if aligned else None))
+    out = []
+    for comp, name, opcode, own, aligned in parsed:
+        if opcode not in ("dynamic-slice", "dynamic-update-slice"):
+            continue
+        scope = (own if own and own.startswith("roundstep.")
+                 else scopes.get(name) or fused_scope.get(comp) or own)
+        if scope and scope.startswith("roundstep."):
+            out.append((name, scope, aligned))
+    return out
+
+
+def test_roundstep_slot_accesses_are_tile_aligned(topo):
+    """In the allreduce cell every round-step slot read and write starts
+    on a tile boundary in every dimension: the slot index lies outside
+    the (8, 128) tile, so a round touches whole tiles of one slot and
+    not one sublane of every tile of the buffer."""
+    text = _bench_compile("ddp_allreduce.25m", topo)
+    accesses = _roundstep_slot_accesses(text)
+    assert {s for _, s, _ in accesses} >= {
+        "roundstep.acc_shuffle", "roundstep.shuffle", "roundstep.unpack"}
+    unaligned = [(n, s, a) for n, s, a in accesses
+                 if a is None or "false" in a]
+    assert not unaligned, unaligned[:5]
+
+
+def test_plan_counts_tiled_leaves(topo):
+    """The ddp bucket's slots take the tile stack, a 4 KiB payload's
+    stay flat, and describe() says so."""
+    from repro.core.comm import CirculantComm
+
+    p = 4
+    mesh = Mesh(np.array(topo.devices[:p]), ("x",))
+    sh = NamedSharding(mesh, P("x"))
+    comm = CirculantComm(mesh, "x")
+    ddp = comm.plan("allreduce", _arg((p, 26214400 // 4), jnp.float32, sh))
+    small = comm.plan("allreduce", _arg((p, 4096 // 4), jnp.float32, sh),
+                      n_blocks=4)
+    assert (ddp.n_blocks, ddp.tiled_leaves) == (23, 1)
+    assert "tiled_leaves=1" in ddp.describe()
+    assert small.tiled_leaves == 0
