@@ -50,7 +50,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -244,9 +244,11 @@ def _acc_dtype(dt: np.dtype):
 
 
 def _bcast_phase(flats, n, recv_slots, send_slots, perms, axis_name, r, step,
-                 overlap=False):
+                 overlap=False, scales=0):
     """Forward broadcast rounds along ``axis_name``; the root row holds
-    the data, every row ends holding all n blocks.
+    the data, every row ends holding all n blocks.  The last ``scales``
+    leaves are a quantized wire's per-block scales: their permutes run
+    under the ``circulant.scales`` scope.
 
     With ``overlap=True`` the round loop is double-buffered: round
     t+1's send block is packed from the PRE-update buffer -- a value
@@ -267,8 +269,13 @@ def _bcast_phase(flats, n, recv_slots, send_slots, perms, axis_name, r, step,
             bufs.append(buf)
             sizes.append(flat.shape[0])
             msgs.append(step.pack(buf, send_t[0, r][None]))
+        plain = len(msgs) - scales
         for t in range(R):
-            got = [jax.lax.ppermute(m, axis_name, perms[t]) for m in msgs]
+            got = [jax.lax.ppermute(m, axis_name, perms[t])
+                   for m in msgs[:plain]]
+            with scope(tracing.SCALES):
+                got += [jax.lax.ppermute(m, axis_name, perms[t])
+                        for m in msgs[plain:]]
             for i in range(len(bufs)):
                 if t + 1 < R:
                     if overlap:
@@ -418,7 +425,9 @@ def _qreduce_phase(flats, n, fwd_slots, acc_slots, perms, axis_name, r, step,
             metas.append((nb, flat.shape[0]))
         for t in range(R):
             got_q = [jax.lax.ppermute(m, axis_name, perms[t]) for m in qmsgs]
-            got_s = [jax.lax.ppermute(m, axis_name, perms[t]) for m in smsgs]
+            with scope(tracing.SCALES):
+                got_s = [jax.lax.ppermute(m, axis_name, perms[t])
+                         for m in smsgs]
             nxt = F[t + 1, r][None] if t + 1 < R else garbage
             for i in range(len(bufs)):
                 bufs[i], errs[i], qmsgs[i], smsgs[i] = step.qacc_shuffle(
@@ -473,7 +482,7 @@ def _quantized_allreduce_core(flats, n, fwd_slots, acc_slots, recv_slots,
         sizes.append(size)
         nbs.append(nb)
     outs = _bcast_phase(q_flats + s_flats, n, recv_slots, send_slots,
-                        bc_perms, axis_name, r, step)
+                        bc_perms, axis_name, r, step, scales=len(s_flats))
     L = len(q_flats)
     sums, errs = [], []
     with scope(tracing.JOIN):
@@ -543,6 +552,47 @@ def _qsync_static(p: int, sizes: Tuple[int, ...], n_blocks: Optional[int],
         return (n, fwd, acc, recv, send, red_perms, bc_perms)
 
     return cached_plan(key, build)
+
+
+class SyncCounters(NamedTuple):
+    """Static counters of one :func:`circulant_qallreduce_body` call, as
+    :class:`CollectivePlan` counts a plan's: the block count and rounds,
+    the ``ppermute``s issued (two per leaf per round: int8 blocks and
+    their scales), the bytes one rank sends, and the part of those bytes
+    that carries the per-block f32 scales."""
+
+    n_blocks: int
+    rounds: int
+    permutes: int
+    wire_bytes: int
+    scales_wire_bytes: int
+
+
+def circulant_qallreduce_counters(sizes, p: int, *,
+                                  n_blocks: Optional[int] = None,
+                                  root: int = 0, backend: str = "jnp",
+                                  qblock: Optional[int] = None
+                                  ) -> SyncCounters:
+    """:class:`SyncCounters` of :func:`circulant_qallreduce_body` on flat
+    f32 vectors of ``sizes`` elements, with the same options; nothing
+    moves on one rank."""
+    from repro.kernels.quant_ops import QBLOCK
+
+    qblock = QBLOCK if qblock is None else int(qblock)
+    sizes = tuple(int(s) for s in sizes)
+    if p == 1:
+        return SyncCounters(1, 0, 0, 0, 0)
+    n = _qsync_static(p, sizes, n_blocks, int(root), qblock, backend)[0]
+    rounds = get_bundle(p, int(root)).allreduce_rounds(n)
+    step = get_round_step(backend)
+    msgs = [_leaf_messages("quantized_allreduce", (p, size), np.float32, p,
+                           n, step, qblock, None) for size in sizes]
+    return SyncCounters(
+        n_blocks=n, rounds=rounds, permutes=2 * rounds * len(sizes),
+        wire_bytes=_wire_bytes("quantized_allreduce", msgs, rounds),
+        # messages 1 and 3 of a leaf are its scales (reduce, broadcast)
+        scales_wire_bytes=_wire_bytes("quantized_allreduce",
+                                      [m[1::2] for m in msgs], rounds))
 
 
 # ------------------------------------------------------- device lowerings

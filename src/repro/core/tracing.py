@@ -32,6 +32,10 @@ ALLGATHER = "circulant.allgather"
 ALLGATHERV = "circulant.allgatherv"
 SCATTER = "circulant.scatter"
 QREDUCE = "circulant.qreduce"
+# Device scope of the quantized wire's per-block scales permutes, inside
+# the ``circulant.qreduce`` and ``circulant.bcast`` phases (the int8
+# payload's permutes stay in the phase's own scope).
+SCALES = "circulant.scales"
 
 # Device scopes of the slot layout around the round loop.
 SPLIT = "circulant.split"        # payload -> slot buffers

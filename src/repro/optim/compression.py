@@ -74,6 +74,7 @@ __all__ = [
     "unbucketize",
     "init_grad_sync_state",
     "compressed_grad_sync",
+    "grad_sync_counters",
     "streamed_sync_params",
 ]
 
@@ -358,6 +359,20 @@ def compressed_grad_sync(grads, err_buckets, axis_name: str, p: int,
         mean_tree, deltas = unbucketize(means, spec, grads)
         new_errs = tuple(e + d for e, d in zip(errs, deltas))
     return mean_tree, new_errs
+
+
+def grad_sync_counters(spec: BucketSpec, p: int, *, backend: str = "jnp",
+                       n_blocks: Optional[int] = None,
+                       qblock: Optional[int] = None):
+    """Static counters of one :func:`compressed_grad_sync` call over
+    ``spec``'s buckets on ``p`` ranks, with the same options: a
+    :class:`repro.core.comm.SyncCounters` (block count, rounds,
+    permutes, wire bytes a rank sends, and the scales' part of them)."""
+    from repro.core.comm import circulant_qallreduce_counters
+
+    return circulant_qallreduce_counters(
+        spec.bucket_sizes, p, n_blocks=n_blocks, backend=backend,
+        qblock=qblock)
 
 
 # ------------------------------------------------- streamed bucket sync

@@ -775,6 +775,8 @@ def check_tracing(p, calls=3):
             plan.describe(), stats.bytes_by_kind)
         for name in ("roundstep.", tracing.SPLIT, tracing.JOIN):
             assert name in text, (plan.kind, name)
+        assert (tracing.SCALES in text) == (plan.kind == "quantized_allreduce")
+    check_grad_sync_counters(mesh, p)
     for plan, arg in runs:
         jax.block_until_ready(plan(arg))           # compile outside the trace
     with tempfile.TemporaryDirectory() as logdir:
@@ -799,6 +801,54 @@ def check_tracing(p, calls=3):
             assert sum(s[0] == inner and a <= s[1] and s[2] <= b
                        for s in spans) == 1, (inner, a, b)
     print(f"tracing ok: {len(outer)} calls")
+
+
+def check_grad_sync_counters(mesh, p):
+    """The trainer's sync path (``compressed_grad_sync`` inside
+    ``shard_map``, two buckets) builds no plan: its static counters
+    ``grad_sync_counters`` equal the collective-permutes of its compiled
+    HLO and their bytes, and the scales' bytes those of the permutes
+    under ``circulant.scales``."""
+    import re
+
+    from repro.core import tracing
+    from repro.launch.hlo_analysis import collective_stats
+    from repro.optim.compression import (
+        compressed_grad_sync,
+        grad_sync_counters,
+        make_bucket_spec,
+    )
+
+    sizes = {"a": 1000, "b": 700}
+    spec = make_bucket_spec(
+        {k: jax.ShapeDtypeStruct((n,), jnp.float32) for k, n in sizes.items()},
+        4 * 1000)
+    assert spec.num_buckets == 2
+
+    def body(a, b, ea, eb):
+        mean, errs = compressed_grad_sync({"a": a[0], "b": b[0]},
+                                          [ea[0], eb[0]], "data", p, spec)
+        return mean["a"][None], mean["b"][None], errs[0][None], errs[1][None]
+
+    sh = NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct((p, n), jnp.float32, sharding=sh)
+            for n in (1000, 700, 1000, 700)]
+    text = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("data"),) * 4,
+        out_specs=(P("data"),) * 4)).lower(*args).compile().as_text()
+    got = grad_sync_counters(spec, p)
+    stats = collective_stats(text)
+    assert stats.ops_by_kind["collective-permute"] == got.permutes, (
+        got, stats.ops_by_kind)
+    assert stats.bytes_by_kind["collective-permute"] == got.wire_bytes, (
+        got, stats.bytes_by_kind)
+    op = re.compile(r"collective-permute(-start)?\(")
+    scales = collective_stats("\n".join(
+        ln for ln in text.splitlines()
+        if not op.search(ln) or tracing.SCALES in ln))
+    assert scales.ops_by_kind["collective-permute"] == got.permutes // 2
+    assert (scales.bytes_by_kind["collective-permute"]
+            == got.scales_wire_bytes), (got, scales.bytes_by_kind)
 
 
 def main(what, p, backend="jnp", nodes=2):

@@ -5,9 +5,10 @@ is described rather than attached.  These tests guard what interpret
 mode cannot see: every round-step kernel must lower through Mosaic at
 deployment block sizes (tile-aligned blocks, scoped-VMEM budget), and
 the jnp-backend broadcast plan must lower to the schedule's round count
-of ``collective-permute``s on a 4-chip mesh, and the jnp round step's
+of ``collective-permute``s on a 4-chip mesh, the jnp round step's
 slot reads and writes in the allreduce cell must start on tile
-boundaries.
+boundaries, every benchmark cell's program must compile, and the
+gradient sync's permutes must match its static counters.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and the suite runs on
@@ -144,30 +145,44 @@ def test_broadcast_plan_lowers_to_schedule_permutes(topo):
 # inheritance (bench/scopes.py), by opcode: in the allreduce the
 # partition index and copies of the input and of scalars into other
 # memory (the split of the tile-stacked slots is one pad, so no zero
-# slot buffer is filled outside a scope); in the rank stack scalar
-# copies of constants.
+# slot buffer is filled outside a scope); in the rank stacks scalar
+# copies of constants; in the one-rank-per-chip gradient sync the same
+# as the allreduce, and besides three broadcasts of constants and three
+# copies the compiler adds.
 UNSCOPED = {
     "ddp_allreduce.25m": {
         "partition-id": 1, "and": 1, "convert": 1, "copy-start": 4,
         "copy-done": 4},
     "int8_gradsync.4m.rankstack": {"copy": 18, "copy-start": 1,
                                    "copy-done": 1},
+    "int8_gradsync.25m": {
+        "partition-id": 1, "and": 1, "convert": 1, "copy": 3,
+        "broadcast": 3, "copy-start": 5, "copy-done": 5},
+    "int8_gradsync.25m.rankstack": {"copy": 36, "copy-start": 2,
+                                    "copy-done": 2},
 }
 NOT_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "while", "conditional", "call", "collective-permute-start",
             "collective-permute-done"}
 
 
+_COMPILED = {}
+
+
 def _bench_compile(name, topo):
+    """The HLO text of a benchmark cell's timed program, compiled once
+    per module run."""
     import sys
     from pathlib import Path
 
-    root = str(Path(__file__).resolve().parents[1])
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from bench.aot import compile_cell
+    if name not in _COMPILED:
+        root = str(Path(__file__).resolve().parents[1])
+        if root not in sys.path:
+            sys.path.insert(0, root)
+        from bench.aot import compile_cell
 
-    return compile_cell(name, topo)[0].as_text()
+        _COMPILED[name] = compile_cell(name, topo)[0].as_text()
+    return _COMPILED[name]
 
 
 @pytest.mark.parametrize("name", sorted(UNSCOPED))
@@ -208,6 +223,59 @@ def test_plan_permutes_and_wire_bytes_match_compiled_hlo(topo):
         stats = collective_stats(jax.jit(plan).lower(x).compile().as_text())
         assert stats.ops_by_kind == {"collective-permute": permutes}
         assert stats.bytes_by_kind["collective-permute"] == plan.wire_bytes
+
+
+def _permute_scopes(text):
+    """The program scope of each collective-permute-start."""
+    from collections import Counter
+
+    from bench.scopes import instruction_scopes
+
+    return Counter(s for n, s in instruction_scopes(text).items()
+                   if n.startswith("collective-permute-start"))
+
+
+@pytest.mark.parametrize("name,scales", [("ddp_allreduce.25m", 0),
+                                         ("int8_gradsync.25m", 24)])
+def test_only_the_quantized_scales_permutes_are_scales_scoped(topo, name,
+                                                              scales):
+    """The gradient sync sends its per-block scales in 24 of its 48
+    permutes, under ``circulant.scales``, and its int8 blocks in the
+    other 24 under the phases' scopes; the f32 allreduce's 48 stay in
+    its phases' scopes."""
+    from repro.core import tracing
+
+    scopes = _permute_scopes(_bench_compile(name, topo))
+    assert sum(scopes.values()) == 48
+    assert scopes[tracing.SCALES] == scales
+    assert set(scopes) - {tracing.SCALES} <= {
+        tracing.REDUCE, tracing.QREDUCE, tracing.BCAST}
+
+
+def test_grad_sync_counters_match_compiled_hlo(topo):
+    """The trainer path's static counters (``grad_sync_counters``) equal
+    the collective-permutes of the one-rank-per-chip gradient sync's
+    TPU executable and their bytes, and the scales' bytes those of the
+    permutes under ``circulant.scales``."""
+    from repro.core import tracing
+    from repro.launch.hlo_analysis import collective_stats
+    from repro.optim.compression import grad_sync_counters, make_bucket_spec
+
+    elems = 26214400 // 4
+    text = _bench_compile("int8_gradsync.25m", topo)
+    spec = make_bucket_spec(jax.ShapeDtypeStruct((elems,), jnp.float32),
+                            26214400)
+    got = grad_sync_counters(spec, 4)
+    assert (got.n_blocks, got.rounds, got.permutes) == (11, 24, 48)
+    stats = collective_stats(text)
+    assert stats.ops_by_kind == {"collective-permute": got.permutes}
+    assert stats.bytes_by_kind["collective-permute"] == got.wire_bytes
+    # the same module with only the scales' permutes left in it
+    scales = collective_stats("\n".join(
+        ln for ln in text.splitlines()
+        if "collective-permute-start(" not in ln or tracing.SCALES in ln))
+    assert scales.ops_by_kind == {"collective-permute": got.permutes // 2}
+    assert scales.bytes_by_kind["collective-permute"] == got.scales_wire_bytes
 
 
 _LINE_RE = re.compile(

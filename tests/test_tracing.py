@@ -70,11 +70,42 @@ def test_one_rank_plan_counts_nothing():
     assert "permutes=0 wire_bytes=0" in plan.describe()
 
 
+def test_one_rank_grad_sync_counts_nothing():
+    from repro.optim.compression import grad_sync_counters, make_bucket_spec
+
+    spec = make_bucket_spec(jnp.ones((4096,)), 4096)
+    got = grad_sync_counters(spec, 1)
+    assert (got.rounds, got.permutes, got.wire_bytes,
+            got.scales_wire_bytes) == (0, 0, 0, 0)
+
+
+def test_grad_sync_counters_follow_the_schedule():
+    """Two permutes a round for each bucket, the int8 blocks and their
+    scales; at the 25 MiB DDP bucket the cost model's 11 blocks give 24
+    rounds, and the scales are 1.5% of the wire."""
+    from repro.optim.compression import grad_sync_counters, make_bucket_spec
+
+    ddp = make_bucket_spec(jax.ShapeDtypeStruct((26214400 // 4,),
+                                                jnp.float32), 26214400)
+    got = grad_sync_counters(ddp, 4)
+    assert (got.n_blocks, got.rounds, got.permutes) == (11, 24, 48)
+    assert got.scales_wire_bytes == 24 * 2328 * 4
+    assert 0.015 < got.scales_wire_bytes / got.wire_bytes < 0.016
+    two = make_bucket_spec({"a": jnp.ones((2048,)), "b": jnp.ones((1024,))},
+                           4 * 2048)
+    assert two.num_buckets == 2
+    got2 = grad_sync_counters(two, 4, n_blocks=2)
+    assert got2.permutes == 2 * 2 * got2.rounds
+
+
 @pytest.mark.multidevice
 def test_plan_calls_write_spans_and_counters_match_hlo():
     """On 4 host devices: one circulant.call per plan call with one
     validate and one execute inside (flat, quantized and hierarchical
     plans), and for every kind, both backends and hierarchical plans,
     permutes / wire_bytes equal the compiled HLO's collective-permutes
-    and their bytes."""
+    and their bytes; only the quantized plan's HLO names
+    ``circulant.scales``; the trainer's sync path's counters
+    (``grad_sync_counters``) equal its HLO's permutes and bytes, and
+    the scales' bytes those of the permutes under that scope."""
     run_worker("tracing", 4)
