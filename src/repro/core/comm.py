@@ -190,12 +190,28 @@ def _split_blocks(flat: jnp.ndarray, n: int, step,
     per block, zero padded at the tail).  With ``qblock`` the blocks
     hold whole quantization blocks, so schedule blocks and quantization
     blocks never straddle each other (one scale vector per schedule
-    block).  Returns ``(buffer, slot)``."""
-    slot = step.slot_shape(-(-flat.shape[0] // n), flat.dtype, qblock)
-    flat = jnp.pad(flat, (0, n * math.prod(slot) - flat.shape[0]))
-    blocks = flat.reshape((n,) + slot)
+    block).  A tile-stacked quantized block holds the quantization
+    blocks a flat one would (``_qblock_rows``), and its slot's rows past
+    them are zero, so the blocks' contents do not depend on the slot's
+    layout.  Returns ``(buffer, slot)``."""
+    size = flat.shape[0]
+    slot = step.slot_shape(-(-size // n), flat.dtype, qblock)
+    if qblock is None or len(slot) == 1:
+        flat = jnp.pad(flat, (0, n * math.prod(slot) - size))
+        blocks = flat.reshape((n,) + slot)
+    else:
+        nq = _qblock_rows(size, n, qblock)
+        blocks = jnp.pad(flat, (0, n * nq * qblock - size))
+        blocks = jnp.pad(blocks.reshape(n, nq, qblock),
+                         ((0, 0), (0, slot[0] - nq), (0, 0)))
     garbage = jnp.zeros((1,) + slot, flat.dtype)
     return jnp.concatenate([blocks, garbage], axis=0), slot
+
+
+def _qblock_rows(size: int, n: int, qblock: int) -> int:
+    """Quantization blocks that hold a leaf's schedule block on the
+    quantized wire: ``ceil(size / n)`` elements in whole qblocks."""
+    return -(-size // (n * qblock))
 
 
 def _leaf_elems(shape: Tuple[int, ...]) -> int:
@@ -460,7 +476,7 @@ def _quantized_allreduce_core(flats, n, fwd_slots, acc_slots, recv_slots,
 
     reduced = _qreduce_phase(flats, n, fwd_slots, acc_slots, red_perms,
                              axis_name, r, step, qblock)
-    q_flats, s_flats, err_flats, sizes, nbs = [], [], [], [], []
+    q_flats, s_flats, err_blocks, sizes, nbs = [], [], [], [], []
     for buf, err, nb, size in reduced:
         with scope(tracing.REQUANT):
             data = buf[0, :n]                          # [n, *slot]
@@ -477,8 +493,8 @@ def _quantized_allreduce_core(flats, n, fwd_slots, acc_slots, recv_slots,
                                      jnp.zeros((n * nb,), jnp.float32)))
             # The final quantization error belongs to the root (the rank
             # that generated it); everyone else contributes zero.
-            e = err[0, :n] + jnp.where(is_root, eps, jnp.zeros_like(eps))
-            err_flats.append(e.reshape(-1))
+            err_blocks.append(
+                err[0, :n] + jnp.where(is_root, eps, jnp.zeros_like(eps)))
         sizes.append(size)
         nbs.append(nb)
     outs = _bcast_phase(q_flats + s_flats, n, recv_slots, send_slots,
@@ -488,17 +504,21 @@ def _quantized_allreduce_core(flats, n, fwd_slots, acc_slots, recv_slots,
     with scope(tracing.JOIN):
         for i in range(L):
             nb, size = nbs[i], sizes[i]
+            nq = _qblock_rows(size, n, qblock)
             red = dequant_blocks(
                 outs[i].reshape(n * nb, qblock),
                 outs[L + i].reshape(n * nb, 1),
-            ).reshape(-1)[:size]
-            # Pad-lane error is identically zero (all ranks pad with
-            # exact zeros), but fold the tail anyway so truncation
-            # provably never drops error mass.
-            e_full = err_flats[i]
-            e = e_full[:size].at[size - 1].add(jnp.sum(e_full[size:]))
-            sums.append(red)
-            errs.append(e)
+            )
+            # The error past ``size`` and in the slots' pad rows is
+            # exactly zero, so truncation drops no error mass: every
+            # rank pads with exact zeros, a zero lane quantizes to 0
+            # with zero error, and quant_error zeroes the non-finite
+            # lanes of a flagged block.  (A fold of the tail into the
+            # last element would, under vmap, make the TPU compiler lay
+            # the requantization's rank axis minor.)
+            for out, x in ((sums, red), (errs, err_blocks[i])):
+                out.append(x.reshape(n, nb, qblock)[:, :nq]
+                           .reshape(-1)[:size])
     return sums, errs
 
 
@@ -558,14 +578,16 @@ class SyncCounters(NamedTuple):
     """Static counters of one :func:`circulant_qallreduce_body` call, as
     :class:`CollectivePlan` counts a plan's: the block count and rounds,
     the ``ppermute``s issued (two per leaf per round: int8 blocks and
-    their scales), the bytes one rank sends, and the part of those bytes
-    that carries the per-block f32 scales."""
+    their scales), the bytes one rank sends, the part of those bytes
+    that carries the per-block f32 scales, and the buckets whose
+    quantized slot the round step lays out as a tile stack."""
 
     n_blocks: int
     rounds: int
     permutes: int
     wire_bytes: int
     scales_wire_bytes: int
+    tiled_qslots: int
 
 
 def circulant_qallreduce_counters(sizes, p: int, *,
@@ -581,7 +603,7 @@ def circulant_qallreduce_counters(sizes, p: int, *,
     qblock = QBLOCK if qblock is None else int(qblock)
     sizes = tuple(int(s) for s in sizes)
     if p == 1:
-        return SyncCounters(1, 0, 0, 0, 0)
+        return SyncCounters(1, 0, 0, 0, 0, 0)
     n = _qsync_static(p, sizes, n_blocks, int(root), qblock, backend)[0]
     rounds = get_bundle(p, int(root)).allreduce_rounds(n)
     step = get_round_step(backend)
@@ -592,7 +614,8 @@ def circulant_qallreduce_counters(sizes, p: int, *,
         wire_bytes=_wire_bytes("quantized_allreduce", msgs, rounds),
         # messages 1 and 3 of a leaf are its scales (reduce, broadcast)
         scales_wire_bytes=_wire_bytes("quantized_allreduce",
-                                      [m[1::2] for m in msgs], rounds))
+                                      [m[1::2] for m in msgs], rounds),
+        tiled_qslots=_tiled_qslots("quantized_allreduce", msgs))
 
 
 # ------------------------------------------------------- device lowerings
@@ -934,11 +957,14 @@ class CollectivePlan:
     overlap: bool = False
     #: ``ppermute``s one call issues (one per leaf per round, two on the
     #: quantized wire: int8 blocks and their scales), the bytes one rank
-    #: sends in a call, and the payload leaves whose slots the round
-    #: step lays out as tile stacks; static, counted at plan time.
+    #: sends in a call, the payload leaves whose slots the round step
+    #: lays out as tile stacks, and of a quantized plan the leaves whose
+    #: quantized reduce slot it lays out so; static, counted at plan
+    #: time.
     permutes: int = 0
     wire_bytes: int = 0
     tiled_leaves: int = 0
+    tiled_qslots: int = 0
     #: Auditable per-phase schedule statics (the exact cached slot
     #: tables the executor closed over); () on the p == 1 fast path.
     #: Checked by repro.analysis.planaudit without executing a round.
@@ -961,7 +987,7 @@ class CollectivePlan:
         """One-line human summary of the plan."""
         extra = f" op={self.op}" if self.op else ""
         if self.qblock is not None:
-            extra += f" qblock={self.qblock}"
+            extra += f" qblock={self.qblock} tiled_qslots={self.tiled_qslots}"
         if self.overlap:
             extra += " overlap"
         return (f"{self.kind} p={self.p} root={self.root} "
@@ -1053,6 +1079,14 @@ def _tiled_leaves(leaves) -> int:
     """Leaves (``leaves``, from :func:`_plan_messages`) with a message
     slot laid out as a tile stack rather than flat."""
     return sum(any(len(slot) > 1 for slot, _, _ in msgs) for msgs in leaves)
+
+
+def _tiled_qslots(kind: str, leaves) -> int:
+    """Leaves of a quantized plan whose quantized reduce slot (the first
+    message of each leaf, the int8 blocks) is a tile stack."""
+    if kind != "quantized_allreduce":
+        return 0
+    return sum(len(msgs[0][0]) > 1 for msgs in leaves)
 
 
 # --------------------------------------------------------- n-block choice
@@ -1382,6 +1416,7 @@ class CirculantComm:
                 2 if kind == "quantized_allreduce" else 1),
             wire_bytes=_wire_bytes(kind, messages, rounds),
             tiled_leaves=_tiled_leaves(messages),
+            tiled_qslots=_tiled_qslots(kind, messages),
             statics=_plan_statics(kind, bundle, n, axis, overlap=overlap),
             _execute=jax.jit(ex))
 

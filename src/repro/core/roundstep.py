@@ -19,19 +19,24 @@ backend asks for through :meth:`RoundStep.slot_shape`; slot vectors are
 (:meth:`ScheduleBundle.per_round_tables` /
 :meth:`ScheduleBundle.reversed_per_round_tables`).
 
-Slot layout.  Pallas always lays a slot out as a ``(rows, 128)`` tile
-stack (:func:`repro.kernels.layout.slot_shape`), which its kernels need
-to compile.  The jnp backend takes the same tile stack for a plain slot
-whenever it pads the slot by at most ``1/TILE_PAD`` of its elements, and
-keeps the flat ``(bs,)`` layout otherwise: for small slots, whose tile
-padding would inflate the wire, and for quantized (``qblock``) slots.
-The reason is the TPU's memory layout: an array's last two dimensions
-are stored in (8, 128) tiles (16 or 32 rows for narrower dtypes), so in
-a flat ``[R, nslots, bs]`` buffer the slot index is a tile row, and a
-slot write or read touches one sublane of every tile of the buffer.  In
-a tile stack the slot index lies outside the tile, so each round writes
-and reads whole tiles of one slot.  Only the layout differs: the values,
-their accumulation order and the zero padding are the same.
+Slot layout.  Pallas always lays a slot out as a tile stack
+(:func:`repro.kernels.layout.slot_shape`): ``(rows, 128)`` for a plain
+slot, ``(rows, qblock)`` with one quantization block a row for a
+quantized (``qblock``) slot; its kernels need that to compile.  The jnp
+backend takes the same tile stack, for both kinds of slot, whenever it
+pads the slot by at most ``1/TILE_PAD`` of its elements, and keeps the
+flat layout (``(bs,)``, or whole qblocks) otherwise: small slots, whose
+tile padding would inflate the wire, stay flat.  The reason is the
+TPU's memory layout: an array's last two dimensions are stored in
+(8, 128) tiles (16 or 32 rows for narrower dtypes), so in a flat
+``[R, nslots, bs]`` buffer the slot index is a tile row, and a slot
+write or read touches one sublane of every tile of the buffer.  In a
+tile stack the slot index lies outside the tile, so each round writes
+and reads whole tiles of one slot, and a quantized slot's
+``[R * rows, qblock]`` view for the block quantizer is a bitcast.  Only
+the layout differs: the values, their accumulation order and the zero
+padding are the same (a pad row of a quantized slot is an all-zero
+block, whose scale floors and which dequantizes to exact zeros).
 
 Two backends implement it:
 
@@ -88,8 +93,8 @@ __all__ = [
 
 BACKENDS = ("jnp", "pallas")
 
-#: A plain jnp slot takes the tile stack when the stack pads it by at
-#: most ``1/TILE_PAD`` of its elements, and stays flat otherwise.
+#: A jnp slot takes the tile stack when the stack pads it by at most
+#: ``1/TILE_PAD`` of its elements, and stays flat otherwise.
 TILE_PAD = 32
 
 
@@ -341,12 +346,13 @@ class JnpRoundStep(RoundStep):
     backend = "jnp"
 
     def slot_shape(self, bs, dtype, qblock=None):
-        if qblock is not None:
-            return (int(-(-bs // qblock) * qblock),)
-        tiles = layout.slot_shape(bs, dtype)
-        if TILE_PAD * (math.prod(tiles) - bs) <= bs:
+        tiles = layout.slot_shape(bs, dtype, qblock)
+        # a row narrower than whole lanes is stored padded to them
+        stored = tiles[0] * -(-tiles[1] // layout.LANES) * layout.LANES
+        if TILE_PAD * (stored - bs) <= bs:
             return tiles
-        return (int(bs),)
+        whole = qblock or 1
+        return (int(-(-bs // whole) * whole),)
 
     def pack(self, buf, idx):
         with scope(tracing.RS_PACK):

@@ -4,7 +4,7 @@ A slot laid out as a ``[rows, lanes]`` tile stack fills whole native TPU
 tiles: 8x128 for 32-bit, 16x128 for 16-bit, 32x128 for 8-bit values.
 The Pallas kernels (:mod:`repro.kernels.block_pack`) need that layout to
 compile at all; the jnp backend (:mod:`repro.core.roundstep`) takes it
-for large plain slots, so a slot write covers whole tiles.  Pure
+for large slots, plain or quantized, so a slot write covers whole tiles.  Pure
 arithmetic: importing this module pulls in neither JAX nor Pallas.
 """
 

@@ -807,8 +807,9 @@ def check_grad_sync_counters(mesh, p):
     """The trainer's sync path (``compressed_grad_sync`` inside
     ``shard_map``, two buckets) builds no plan: its static counters
     ``grad_sync_counters`` equal the collective-permutes of its compiled
-    HLO and their bytes, and the scales' bytes those of the permutes
-    under ``circulant.scales``."""
+    HLO and their bytes, the scales' bytes those of the permutes under
+    ``circulant.scales``, and no bucket's quantized slot tiles, as the
+    HLO's flat reduce buffer shows."""
     import re
 
     from repro.core import tracing
@@ -837,6 +838,10 @@ def check_grad_sync_counters(mesh, p):
         body, mesh=mesh, in_specs=(P("data"),) * 4,
         out_specs=(P("data"),) * 4)).lower(*args).compile().as_text()
     got = grad_sync_counters(spec, p)
+    # the buckets' quantized slots stay flat, in whole qblocks
+    width = -(-(-(-1000 // got.n_blocks)) // 256) * 256
+    assert got.tiled_qslots == 0, got
+    assert f"f32[1,{got.n_blocks + 2},{width}]" in text, (got, width)
     stats = collective_stats(text)
     assert stats.ops_by_kind["collective-permute"] == got.permutes, (
         got, stats.ops_by_kind)

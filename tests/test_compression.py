@@ -92,6 +92,104 @@ def test_host_plan_identity_and_validation():
         host_plan("quantized_allreduce", 4, 2, op="max")
 
 
+# ------------------------------------------ pad tail of the error vector
+
+
+def _stacked_qallreduce(vals, n):
+    """``circulant_qallreduce_body`` with the p ranks stacked under
+    ``vmap`` (the rank stack's layout): ``(sums, errs)`` as [p, size]."""
+    from repro.core.comm import circulant_qallreduce_body
+
+    p = vals.shape[0]
+
+    def body(x):
+        sums, errs = circulant_qallreduce_body([x], "x", p, n_blocks=n)
+        return sums[0], errs[0]
+
+    out, err = jax.jit(jax.vmap(body, axis_name="x"))(jnp.asarray(vals))
+    return np.asarray(out), np.asarray(err)
+
+
+@pytest.mark.parametrize("inf", [False, True])
+@pytest.mark.parametrize("p,n,size,slot", [
+    (4, 2, 1000, (512,)),              # flat: 24 pad lanes in block 1
+    (4, 3, 24271, (32, 256)),          # stack: a partial and a pad qblock
+    (3, 3, 47674, (64, 256)),          # stack with a pad row of its own
+])
+def test_quantized_error_pad_tail_is_zero(p, n, size, slot, inf):
+    """At a size that is no multiple of n * qblock, the error vector's
+    tail past ``size`` is exactly zero after the quantized reduce and
+    the root's requantization, also when an inf flags the block that
+    holds the tail's first lanes; so the error returned for ``size``
+    elements drops nothing, and exact == lossy + psum(err) holds."""
+    from repro.core.roundstep import get_round_step
+
+    assert get_round_step("jnp").slot_shape(-(-size // n), np.float32,
+                                            256) == slot
+    rng = np.random.default_rng(size + p)
+    vals = (rng.normal(size=(p, size)) *
+            10.0 ** rng.integers(-3, 4, size=(p, 1))).astype(np.float32)
+    if inf:
+        vals[1, size - 1] = np.inf
+    out, err = _stacked_qallreduce(vals, n)
+    # The same leaf zero padded to whole qblocks in each of its n blocks:
+    # the same blocks and rounds, so its error past ``size`` is the tail
+    # the first run cut.
+    full = n * -(-size // (n * 256)) * 256
+    out_f, err_f = _stacked_qallreduce(
+        np.pad(vals, [(0, 0), (0, full - size)]), n)
+    np.testing.assert_array_equal(err_f[:, size:], 0.0)
+    np.testing.assert_array_equal(err_f[:, :size], err)
+    np.testing.assert_array_equal(out_f[:, :size], out)
+    assert np.isfinite(err).all()
+    for r in range(1, p):
+        np.testing.assert_array_equal(out[r], out[0])
+    exact = vals.astype(np.float64).sum(0)
+    finite = np.isfinite(out[0])       # a flagged block comes back NaN
+    assert finite.all() != inf and np.isfinite(exact[finite]).all()
+    recon = out[0].astype(np.float64) + err.astype(np.float64).sum(0)
+    resid = np.abs(recon - exact)[finite]
+    tol = (1e-4 * np.maximum(np.abs(exact), np.abs(vals).max(0) * p)
+           + 1e-7)[finite]
+    assert (resid <= tol).all(), resid.max()
+
+
+@pytest.mark.parametrize("p,n,nq", [(4, 3, 63), (3, 5, 95)])
+def test_tiled_quantized_leaf_matches_host_plan(p, n, nq):
+    """A leaf whose quantized slot is a tile stack with a pad row (nq
+    qblocks a block, nq + 1 rows) syncs bit-for-bit as the host data
+    plan does on its [p, n, nq * qblock] blocks: each schedule block
+    holds the qblocks a flat slot would, and the pad rows carry none."""
+    from repro.core.roundstep import get_round_step
+
+    bs = nq * 256
+    assert get_round_step("jnp").slot_shape(bs, np.float32, 256) == (
+        nq + 1, 256)
+    rng = np.random.default_rng(nq)
+    vals = (rng.normal(size=(p, n * bs)) *
+            10.0 ** rng.integers(-3, 4, size=(p, 1))).astype(np.float32)
+    out, err = _stacked_qallreduce(vals, n)
+    h_out, h_err = host_plan("quantized_allreduce", p, n, qblock=256).run(
+        vals.reshape(p, n, bs))
+    np.testing.assert_array_equal(out, h_out.reshape(p, -1))
+    np.testing.assert_array_equal(err, h_err.reshape(p, -1))
+
+
+def test_grad_sync_counters_count_tiled_qslots():
+    """The 25 MiB and 4 MiB buckets' quantized slots take the tile
+    stack; a 300-element bucket's stays flat."""
+    from repro.optim.compression import grad_sync_counters
+
+    def tiled(elems):
+        spec = make_bucket_spec(jax.ShapeDtypeStruct((elems,), jnp.float32),
+                                4 * elems)
+        return grad_sync_counters(spec, 4).tiled_qslots
+
+    assert tiled(26214400 // 4) == 1
+    assert tiled((4 << 20) // 4) == 1
+    assert tiled(300) == 0
+
+
 # ------------------------------------------------------------ quantize
 
 

@@ -145,8 +145,8 @@ def test_hier_plan_cache_identity_and_eviction_free_growth():
     # every key is namespaced by a distinct leading tag
     tags = {k[0] for k in keys_after if isinstance(k, tuple)}
     assert tags <= {"commplan", "hierplan", "hostplan", "hierhostplan",
-                    "comm", "hiercomm", "slots/bcast", "slots/reduce",
-                    "slots/scatter"}, tags
+                    "comm", "hiercomm", "qsync", "slots/bcast",
+                    "slots/reduce", "slots/scatter"}, tags
 
 
 def test_hier_and_flat_host_plans_do_not_collide():

@@ -119,12 +119,16 @@ def test_acc_shuffle_semantics():
     (32768, jnp.bfloat16, None, (256, 128)),   # 16-row tiles, no pad
     (2049, jnp.bfloat16, None, (2049,)),       # a 32x128 stack would pad 2047
     (209920, jnp.int8, None, (1664, 128)),     # 32-row tiles, +1.5%
-    (1 << 20, jnp.float32, 256, (1 << 20,)),   # quantized: always flat
-    (300, jnp.float32, 256, (512,)),           # ... in whole qblocks
+    (1 << 20, jnp.float32, 256, (4096, 256)),  # quantized: one qblock a row
+    (300, jnp.float32, 256, (512,)),           # flat, in whole qblocks
+    (209716, jnp.float32, 256, (832, 256)),    # 4 MiB/5: pads 3276
+    (595782, jnp.float32, 256, (2336, 256)),   # 25 MiB/11: 8 pad rows
+    (7 * 256, jnp.float32, 256, (7 * 256,)),   # 32 rows would pad 25 of 32
+    (1 << 16, jnp.float32, 64, (1 << 16,)),    # 64-lane rows pad to 128
 ])
 def test_jnp_slot_rule(bs, dtype, qblock, shape):
-    """Large plain slots take the tile stack; small ones and quantized
-    ones stay flat."""
+    """Large slots take the tile stack, plain or quantized; small ones
+    stay flat."""
     assert get_round_step("jnp").slot_shape(bs, dtype, qblock) == shape
 
 
@@ -156,13 +160,17 @@ def _run_method(step, method, buf, msg, a, b):
     return step.acc_shuffle_staged(buf, msg, step.pack(buf, b), a, b)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32,
-                                   jnp.int8])
-@pytest.mark.parametrize("method", JNP_METHODS)
+@pytest.mark.parametrize("method,dtype", [
+    (m, d) for m in JNP_METHODS
+    for d in (jnp.float32, jnp.bfloat16, jnp.int32, jnp.int8)]
+    + [("qacc_shuffle", jnp.float32)])
 def test_jnp_methods_bitexact_flat_vs_tiled(method, dtype):
     """Every jnp method gives the same blocks on a flat and on a tiled
     buffer holding them; R equals the tile's rows so a row mask that
-    broadcasts against the wrong axis shows."""
+    broadcasts against the wrong axis shows.  The quantized step runs on
+    a ``(rows, qblock)`` stack against whole flat qblocks."""
+    if method == "qacc_shuffle":
+        return _check_qacc_flat_vs_tiled()
     bs = 1000
     rows, lanes = layout.slot_shape(bs, dtype)
     R, ns = rows, 6
@@ -186,6 +194,51 @@ def test_jnp_methods_bitexact_flat_vs_tiled(method, dtype):
         np.testing.assert_array_equal(t[..., :bs], np.asarray(f))
         # the sum identity is zero, so the padding lanes stay zero
         assert not np.any(t[..., bs:])
+
+
+def _check_qacc_flat_vs_tiled():
+    """qacc_shuffle on whole flat qblocks and on their tile stack: the
+    buffers, the error, the int8 message and the scales bit-identical;
+    the stack's pad rows stay zero and their scales floor."""
+    from repro.kernels.quant_ops import SCALE_FLOOR
+
+    qb, bs = 256, 1000
+    rows, _ = layout.slot_shape(bs, jnp.float32, qb)
+    nb = -(-bs // qb)                          # 4 blocks; the stack has 32
+    R, ns = rows, 6
+
+    def qrand(shape, dtype):
+        x = _rand(shape[:-1] + (bs,), dtype)   # real lanes, zero tail
+        return np.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, nb * qb - bs)])
+
+    flat = [qrand((R, ns, 0), np.float32), qrand((R, ns, 0), np.float32),
+            np.clip(qrand((R, 0), np.int32), -127, 127).astype(np.int8),
+            np.abs(_rand((R, nb), np.float32))]
+    flat[1] *= 1e-3                            # an error state in flight
+    a = jnp.asarray(RNG.integers(0, ns, size=R), jnp.int32)
+    b = jnp.asarray(RNG.integers(0, ns, size=R), jnp.int32)
+    b = b.at[0].set(a[0])   # the same-slot case on row 0
+
+    def tiled(x, width=qb):
+        x = np.pad(x, [(0, 0)] * (x.ndim - 1)
+                   + [(0, rows * width - x.shape[-1])])
+        return x.reshape(x.shape[:-1] + ((rows, width) if width > 1
+                                          else (rows,)))
+
+    step = get_round_step("jnp")
+    got_f = step.qacc_shuffle(*map(jnp.asarray, flat), a, b)
+    got_t = step.qacc_shuffle(*map(jnp.asarray, (
+        tiled(flat[0]), tiled(flat[1]), tiled(flat[2]),
+        tiled(flat[3], 1))), a, b)
+    for f, t in zip(got_f[:3], got_t[:3]):     # buffers, error, int8
+        assert t.shape == f.shape[:-1] + (rows, qb)
+        t = np.asarray(t).reshape(t.shape[:-2] + (-1,))
+        np.testing.assert_array_equal(t[..., :nb * qb], np.asarray(f))
+        assert not np.any(t[..., bs:])         # pad lanes zero
+    s_f, s_t = np.asarray(got_f[3]), np.asarray(got_t[3])
+    assert s_t.shape == (R, rows)
+    np.testing.assert_array_equal(s_t[:, :nb], s_f)
+    np.testing.assert_array_equal(s_t[:, nb:], np.float32(SCALE_FLOOR))
 
 
 def test_tiled_leaves_counts_tile_stacked_leaves():

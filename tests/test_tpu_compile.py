@@ -6,9 +6,11 @@ mode cannot see: every round-step kernel must lower through Mosaic at
 deployment block sizes (tile-aligned blocks, scoped-VMEM budget), and
 the jnp-backend broadcast plan must lower to the schedule's round count
 of ``collective-permute``s on a 4-chip mesh, the jnp round step's
-slot reads and writes in the allreduce cell must start on tile
-boundaries, every benchmark cell's program must compile, and the
-gradient sync's permutes must match its static counters.
+slot reads and writes in the allreduce cell and the quantized ones in
+the gradient sync must start on tile boundaries, no large array of a
+rank stack may lay its rank axis minor, every benchmark cell's program
+must compile, and the gradient sync's permutes must match its static
+counters.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and the suite runs on
@@ -145,21 +147,18 @@ def test_broadcast_plan_lowers_to_schedule_permutes(topo):
 # inheritance (bench/scopes.py), by opcode: in the allreduce the
 # partition index and copies of the input and of scalars into other
 # memory (the split of the tile-stacked slots is one pad, so no zero
-# slot buffer is filled outside a scope); in the rank stacks scalar
-# copies of constants; in the one-rank-per-chip gradient sync the same
-# as the allreduce, and besides three broadcasts of constants and three
-# copies the compiler adds.
+# slot buffer is filled outside a scope); in the rank stacks nothing;
+# in the one-rank-per-chip gradient sync the same as the allreduce,
+# with two copies more into other memory.
 UNSCOPED = {
     "ddp_allreduce.25m": {
         "partition-id": 1, "and": 1, "convert": 1, "copy-start": 4,
         "copy-done": 4},
-    "int8_gradsync.4m.rankstack": {"copy": 18, "copy-start": 1,
-                                   "copy-done": 1},
+    "int8_gradsync.4m.rankstack": {},
     "int8_gradsync.25m": {
-        "partition-id": 1, "and": 1, "convert": 1, "copy": 3,
-        "broadcast": 3, "copy-start": 5, "copy-done": 5},
-    "int8_gradsync.25m.rankstack": {"copy": 36, "copy-start": 2,
-                                    "copy-done": 2},
+        "partition-id": 1, "and": 1, "convert": 1, "copy-start": 6,
+        "copy-done": 6},
+    "int8_gradsync.25m.rankstack": {},
 }
 NOT_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "while", "conditional", "call", "collective-permute-start",
@@ -267,6 +266,9 @@ def test_grad_sync_counters_match_compiled_hlo(topo):
                             26214400)
     got = grad_sync_counters(spec, 4)
     assert (got.n_blocks, got.rounds, got.permutes) == (11, 24, 48)
+    # the reduce buffer of n + 2 quantized slots, each a tile stack
+    assert got.tiled_qslots == 1
+    assert "f32[1,13,2336,256]{" in text and "f32[1,13,595968]" not in text
     stats = collective_stats(text)
     assert stats.ops_by_kind == {"collective-permute": got.permutes}
     assert stats.bytes_by_kind["collective-permute"] == got.wire_bytes
@@ -336,9 +338,47 @@ def test_roundstep_slot_accesses_are_tile_aligned(topo):
     assert not unaligned, unaligned[:5]
 
 
+def test_quantized_slot_accesses_are_tile_aligned(topo):
+    """In the one-rank-per-chip gradient sync every quantized round
+    step's slot read and write starts on a tile boundary in every
+    dimension: a quantized slot is a ``(rows, qblock)`` tile stack, so
+    a reduce round touches whole tiles of one slot of the buffer and of
+    its error twin."""
+    text = _bench_compile("int8_gradsync.25m", topo)
+    accesses = [a for a in _roundstep_slot_accesses(text)
+                if a[1] == "roundstep.qacc_shuffle"]
+    assert len(accesses) >= 24
+    unaligned = [a for a in accesses if a[2] is None or "false" in a[2]]
+    assert not unaligned, unaligned[:5]
+
+
+_RANK_MINOR_RE = re.compile(
+    r"=\s*([a-z]+)(\d*)\[4,([\d,]+)\]\{0[,}]")
+
+
+@pytest.mark.parametrize("name", ["int8_gradsync.4m.rankstack",
+                                  "int8_gradsync.25m.rankstack"])
+def test_rank_stacks_keep_the_rank_axis_major(topo, name):
+    """No array of more than 1 MB in a rank stack's executable lays its
+    rank axis (dim 0, the 4 stacked ranks) minor: that layout pads 4
+    ranks to 128 lanes, 32 times the array's bytes."""
+    text = _bench_compile(name, topo)
+    minor = []
+    for line in text.splitlines():
+        m = _RANK_MINOR_RE.search(line)
+        if not m:
+            continue
+        bits = int(m.group(2) or 8)            # pred is a byte
+        elems = 4 * int(np.prod([int(d) for d in m.group(3).split(",")]))
+        if elems * bits // 8 > 1 << 20:
+            minor.append(line.strip()[:120])
+    assert not minor, minor[:5]
+
+
 def test_plan_counts_tiled_leaves(topo):
     """The ddp bucket's slots take the tile stack, a 4 KiB payload's
-    stay flat, and describe() says so."""
+    stay flat, and describe() says so; likewise a quantized plan's
+    reduce slot."""
     from repro.core.comm import CirculantComm
 
     p = 4
@@ -351,3 +391,12 @@ def test_plan_counts_tiled_leaves(topo):
     assert (ddp.n_blocks, ddp.tiled_leaves) == (23, 1)
     assert "tiled_leaves=1" in ddp.describe()
     assert small.tiled_leaves == 0
+    assert ddp.tiled_qslots == 0 and "tiled_qslots" not in ddp.describe()
+    # a quantized plan's reduce slot: 1 Mi elements in 8 blocks tile,
+    # 300 elements in 2 stay flat
+    qbig = comm.plan("quantized_allreduce",
+                     _arg((p, 1 << 20), jnp.float32, sh), n_blocks=8)
+    qsmall = comm.plan("quantized_allreduce",
+                       _arg((p, 300), jnp.float32, sh), n_blocks=2)
+    assert (qbig.tiled_qslots, qsmall.tiled_qslots) == (1, 0)
+    assert "tiled_qslots=1" in qbig.describe()

@@ -89,7 +89,8 @@ def test_grad_sync_counters_follow_the_schedule():
                                                 jnp.float32), 26214400)
     got = grad_sync_counters(ddp, 4)
     assert (got.n_blocks, got.rounds, got.permutes) == (11, 24, 48)
-    assert got.scales_wire_bytes == 24 * 2328 * 4
+    assert got.scales_wire_bytes == 24 * 2336 * 4     # 8 pad rows a block
+    assert (got.wire_bytes, got.tiled_qslots) == (14576640, 1)
     assert 0.015 < got.scales_wire_bytes / got.wire_bytes < 0.016
     two = make_bucket_spec({"a": jnp.ones((2048,)), "b": jnp.ones((1024,))},
                            4 * 2048)
