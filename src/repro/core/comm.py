@@ -19,8 +19,7 @@ exactly this collective family (Träff, arXiv:2407.18004):
   * ``comm.broadcast(...)`` / ``allgather`` / ``allgatherv`` /
     ``reduce_scatter`` / ``reduce`` / ``allreduce`` / ``allbroadcast``
     are thin plan-cache lookups, so casual call sites get plan reuse
-    for free.  The legacy ``circulant_*`` functions in
-    :mod:`repro.core.collectives` are shims over these.
+    for free.
 
 Payloads are arbitrary **pytrees**: the plan flattens the tree, splits
 every leaf into the same number of blocks n (per-leaf block size
@@ -1150,8 +1149,9 @@ def _resolve_quantized(spec: PayloadSpec, p: int, n_blocks: Optional[int],
                  f"(one slice/rank); got {shape} for p={p}")
         _require(np.dtype(dtype) == np.float32,
                  "quantized_allreduce requires float32 leaves (cast, or "
-                 "use optim.compression.compressed_allreduce_tree for "
-                 f"bf16/f16 gradients); got {np.dtype(dtype).name}")
+                 "use repro.optim.compression.compressed_grad_sync, which "
+                 "widens bf16/f16 leaves per bucket); got "
+                 f"{np.dtype(dtype).name}")
         e = _leaf_elems(shape[1:])
         elems.append(e)
         total += e  # ~1 wire byte per element (int8 + amortized scales)
@@ -1483,8 +1483,7 @@ def get_comm(mesh: Mesh, axis_name: str, *, backend: str = "jnp",
     """The process-cached :class:`CirculantComm` for this context.
 
     Identity is stable while cached (``get_comm(...) is get_comm(...)``
-    for equal arguments), so the legacy ``circulant_*`` shims hit the
-    same plan cache as first-class communicator users.
+    for equal arguments).
     """
     return cached_plan(
         ("comm", mesh, axis_name, backend, model),

@@ -49,10 +49,10 @@ Two backends implement it:
 
 Both backends implement identical update order (unpack-then-pack;
 accumulate-then-capture-then-drain), so they agree **bit-exactly** --
-asserted by the simulator certification harness
-(:func:`dataplane_broadcast` / :func:`dataplane_reduce` /
-:func:`dataplane_allgather`, wired into ``simulate_*(backend=...)``)
-and by the backend-parametrized collective tests.
+asserted by the simulator certification harness (the host data plans
+of :func:`repro.core.comm.host_plan`, wired into
+``simulate_*(backend=...)``) and by the backend-parametrized collective
+tests.
 """
 
 from __future__ import annotations
@@ -82,13 +82,6 @@ __all__ = [
     "allgather_phase_static",
     "reduce_phase_static",
     "scatter_phase_static",
-    "dataplane_broadcast",
-    "dataplane_allgather",
-    "dataplane_reduce",
-    "dataplane_hier_broadcast",
-    "dataplane_hier_reduce",
-    "dataplane_hier_allreduce",
-    "dataplane_hier_allgather",
 ]
 
 BACKENDS = ("jnp", "pallas")
@@ -104,8 +97,7 @@ TILE_PAD = 32
 # cache (keyed on (p, root, n) -- bundles are themselves cached, so the
 # bundle identity is implied by the key).  The returned arrays are
 # immutable and shared: a CollectivePlan holds them for its lifetime,
-# and repeated per-call lowering (the legacy circulant_* path) pays the
-# clamping exactly once per process.
+# and repeated lowering pays the clamping exactly once per process.
 
 
 def clamp_slots(eff: np.ndarray, n: int, garbage: Optional[int] = None) -> np.ndarray:
@@ -510,116 +502,3 @@ def get_round_step(backend: str = "jnp",
                 else PallasRoundStep(interpret))
         _step_handles[key] = step
     return step
-
-
-# --------------------------------------------- host data-plane executors
-#
-# Single-process executions of the full collectives with the R rows of
-# the batched kernels standing in for the p ranks and the network
-# exchange realized as a row rotation (ppermute's rotation r -> (r+s)%p
-# is exactly jnp.roll along the rank axis).  The simulator runs these
-# next to its message-passing reference and asserts bit-exact agreement
-# -- the certification path for the Pallas backend on CPU CI.
-#
-# The executors live on the cached host plans of :mod:`repro.core.comm`
-# (slot tables + step handle precomputed once per (kind, p, n, root,
-# op, backend)); these wrappers keep the original one-shot entry points.
-
-
-def dataplane_broadcast(p: int, n: int, root: int, values: np.ndarray,
-                        backend: str,
-                        interpret: Optional[bool] = None) -> np.ndarray:
-    """Execute the n-block broadcast data plane on host arrays.
-
-    ``values``: [n] (or [n, bs]) block payloads at the root.  Returns
-    the final [p, n, bs] data slots of every rank.
-    """
-    from .comm import host_plan
-
-    return host_plan("broadcast", p, n, root=root, backend=backend,
-                     interpret=interpret).run(values)
-
-
-def dataplane_allgather(p: int, n: int, values: np.ndarray, backend: str,
-                        interpret: Optional[bool] = None) -> np.ndarray:
-    """Execute the all-to-all broadcast data plane on host arrays.
-
-    ``values``: [p, n] (or [p, n, bs]) -- root j's block payloads.  The
-    [p_rank, p_root] buffer grid is flattened rank-major onto the kernel
-    rows, so the exchange is a roll by ``skip * p`` flat rows.  Returns
-    the final [p_rank, p_root, n, bs] data slots.
-    """
-    from .comm import host_plan
-
-    return host_plan("allgather", p, n, backend=backend,
-                     interpret=interpret).run(values)
-
-
-def dataplane_reduce(p: int, n: int, root: int, values: np.ndarray, op: str,
-                     backend: str,
-                     interpret: Optional[bool] = None) -> np.ndarray:
-    """Execute the reversed-schedule reduction data plane on host arrays.
-
-    ``values``: [p, n] (or [p, n, bs]) per-rank block contributions.
-    Returns the final [p, n, bs] data slots (row ``root`` holds the
-    op-reduction; other rows are drained to the identity).
-    """
-    from .comm import host_plan
-
-    return host_plan("reduce", p, n, root=root, op=op, backend=backend,
-                     interpret=interpret).run(values)
-
-
-# The hierarchical (two-level) variants compose the flat host plans per
-# level (repro.core.hier.hier_host_plan); these wrappers keep the
-# one-shot entry-point shape of their flat siblings above.
-
-
-def dataplane_hier_broadcast(nodes: int, cores: int, n_inter: int,
-                             n_intra: int, root: int, values: np.ndarray,
-                             backend: str,
-                             interpret: Optional[bool] = None) -> np.ndarray:
-    """Two-level broadcast data plane: flat [m] payload at the flat
-    node-major ``root`` -> final [nodes, cores, m] state of every rank."""
-    from .hier import hier_host_plan
-
-    return hier_host_plan("broadcast", nodes, cores, n_inter, n_intra,
-                          root=root, backend=backend,
-                          interpret=interpret).run(values)
-
-
-def dataplane_hier_reduce(nodes: int, cores: int, n_inter: int, n_intra: int,
-                          root: int, values: np.ndarray, op: str,
-                          backend: str,
-                          interpret: Optional[bool] = None) -> np.ndarray:
-    """Two-level reduction data plane: [nodes, cores, m] contributions
-    -> the flat [m] op-reduction held by the root."""
-    from .hier import hier_host_plan
-
-    return hier_host_plan("reduce", nodes, cores, n_inter, n_intra,
-                          root=root, op=op, backend=backend,
-                          interpret=interpret).run(values)
-
-
-def dataplane_hier_allreduce(nodes: int, cores: int, n_inter: int,
-                             n_intra: int, root: int, values: np.ndarray,
-                             op: str, backend: str,
-                             interpret: Optional[bool] = None) -> np.ndarray:
-    """Two-level all-reduction data plane: [nodes, cores, m] in ->
-    [nodes, cores, m] out, every rank holding the composed reduction."""
-    from .hier import hier_host_plan
-
-    return hier_host_plan("allreduce", nodes, cores, n_inter, n_intra,
-                          root=root, op=op, backend=backend,
-                          interpret=interpret).run(values)
-
-
-def dataplane_hier_allgather(nodes: int, cores: int, n_inter: int,
-                             n_intra: int, values: np.ndarray, backend: str,
-                             interpret: Optional[bool] = None) -> np.ndarray:
-    """Two-level allgather data plane: [nodes, cores, e] contributions
-    -> the replicated [nodes*cores, e] rank-major gathered result."""
-    from .hier import hier_host_plan
-
-    return hier_host_plan("allgather", nodes, cores, n_inter, n_intra,
-                          backend=backend, interpret=interpret).run(values)

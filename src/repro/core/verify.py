@@ -226,7 +226,7 @@ def verify_p(p: int) -> None:
     verify_bundle(get_bundle(p))
 
 
-if __name__ == "__main__":  # pragma: no cover - exercised via benchmarks/run.py
+if __name__ == "__main__":  # pragma: no cover - the CLI, python -m repro.core.verify
     import sys
 
     _ps = [int(a) for a in sys.argv[1:]] or (

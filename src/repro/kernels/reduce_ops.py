@@ -5,7 +5,7 @@ collectives' identity slot must agree bit-for-bit (the reduce family
 re-ships drained slots in capped rounds, so the identity must be
 absorbing under the combine).  This module is the single source: all of
 :mod:`repro.kernels.block_pack`, :mod:`repro.kernels.ref` and
-:mod:`repro.core.collectives` resolve ops here, and every entry point
+:mod:`repro.core.comm` resolve ops here, and every entry point
 validates the op name instead of silently defaulting.
 """
 

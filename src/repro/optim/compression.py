@@ -1,14 +1,11 @@
 """Gradient compression with error feedback (distributed-optimization trick).
 
-Two int8-on-the-wire transports implement the lossy mean-allreduce:
-
-  * ``transport="circulant"`` (default) -- the quantized circulant
-    allreduce of :mod:`repro.core.comm` (``2(n-1)+2*ceil(log2 p)``
-    rounds, the paper's round-optimal schedule with the wire carrying
-    int8 blocks + per-block f32 scales and every requantization error
-    captured in the fused round step);
-  * ``transport="ring"`` -- the legacy ring reduce-scatter/all-gather
-    (``2(p-1)`` hops), kept as the baseline.
+The lossy mean-allreduce is the quantized circulant allreduce of
+:mod:`repro.core.comm` (``2(n-1)+2*ceil(log2 p)`` rounds, the paper's
+round-optimal schedule with the wire carrying int8 blocks + per-block
+f32 scales and every requantization error captured in the fused round
+step), run once per call over the gradient buckets of
+:func:`compressed_grad_sync`.
 
 Error-feedback convention (Karimireddy et al. 2019), used everywhere in
 this module: **error leaves are f32 and live in SUM units** -- each rank
@@ -35,9 +32,9 @@ zero (never poisoned).
 
 Wire volume for m f32 elements: ~2m int8 bytes (+ scales) versus 8m f32
 bytes for an uncompressed allreduce -- a 4x reduction the roofline's
-collective term sees directly; the circulant transport additionally
-replaces the ring's 2(p-1) latency terms with 2(n-1)+2*ceil(log2 p)
-(see docs/gradsync.md for the full table).
+collective term sees directly; the circulant schedule pays
+2(n-1)+2*ceil(log2 p) latency terms where a ring pays 2(p-1) (see
+docs/gradsync.md for the full table).
 """
 
 from __future__ import annotations
@@ -54,7 +51,6 @@ from repro.kernels.quant_ops import (
     block_nonfinite,
     dequant_blocks,
     quant_blocks,
-    quant_error,
 )
 
 #: Quantization block length (elements sharing one f32 scale).
@@ -65,9 +61,6 @@ __all__ = [
     "quantize_int8",
     "dequantize_int8",
     "block_nonfinite",
-    "init_error_state",
-    "compressed_psum_ring",
-    "compressed_allreduce_tree",
     "BucketSpec",
     "make_bucket_spec",
     "bucketize",
@@ -97,71 +90,6 @@ def dequantize_int8(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     return dequant_blocks(q, scale).reshape(-1)
 
 
-def init_error_state(params):
-    """Zero-initialized error-feedback state: f32 leaves regardless of
-    the gradient dtype (bf16/f16 error state would quantize the
-    feedback itself away)."""
-    return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-
-
-def _rot(p: int, s: int):
-    return [(r, (r + s) % p) for r in range(p)]
-
-
-def compressed_psum_ring(flat: jnp.ndarray, axis_name: str, p: int):
-    """int8 ring all-reduce (mean) of a flat f32 vector inside shard_map.
-
-    flat length must be divisible by p * BLOCK (caller pads).  Returns
-    ``(mean, err)``: the mean-reduced vector and this rank's locally
-    generated quantization error in SUM units (every per-hop
-    requantization error plus the final quantize of the segment this
-    rank owns), satisfying the completeness invariant of the module
-    docstring.
-    """
-    if p == 1:
-        return flat, jnp.zeros_like(flat)
-    segs = flat.reshape(p, -1)            # [p, m/p]
-    r = jax.lax.axis_index(axis_name)
-    err = jnp.zeros_like(segs)
-
-    # ---- reduce-scatter: after p-1 hops rank r holds the full sum of
-    # segment r.  Each hop ships the partially-reduced segment as int8
-    # (+ f32 block scales); partials accumulate locally in f32.  The
-    # requantization error of every hop is captured into the row of the
-    # segment being shipped (hop h ships segment (r+1+h) % p, so each
-    # row is written exactly once).
-    send_seg = jnp.take(segs, (r + 1) % p, axis=0)
-    for h in range(p - 1):
-        q, s = quantize_int8(send_seg)
-        eh = quant_error(send_seg.reshape(-1, BLOCK), q, s).reshape(-1)
-        err = jax.lax.dynamic_update_slice(
-            err, eh[None], ((r + 1 + h) % p, 0))
-        q = jax.lax.ppermute(q, axis_name, _rot(p, p - 1))  # r -> r-1
-        s = jax.lax.ppermute(s, axis_name, _rot(p, p - 1))
-        got = dequantize_int8(q, s)
-        nxt = (r + 2 + h) % p
-        send_seg = jnp.take(segs, nxt, axis=0) + got
-
-    # ---- all-gather the reduced segment SUMS (int8 on the wire); the
-    # final-quantize error stays in sum units in this rank's own row.
-    q, s = quantize_int8(send_seg)
-    err = jax.lax.dynamic_update_slice(
-        err, quant_error(send_seg.reshape(-1, BLOCK), q, s).reshape(-1)[None],
-        (r, 0))
-    out = jnp.zeros_like(segs)
-    out = jax.lax.dynamic_update_slice(out, dequantize_int8(q, s)[None],
-                                       (r, 0))
-    cur_q, cur_s = q, s
-    for h in range(1, p):
-        cur_q = jax.lax.ppermute(cur_q, axis_name, _rot(p, 1))
-        cur_s = jax.lax.ppermute(cur_s, axis_name, _rot(p, 1))
-        src = (r - h) % p
-        out = jax.lax.dynamic_update_slice(
-            out, dequantize_int8(cur_q, cur_s)[None], (src, 0)
-        )
-    return out.reshape(-1) / p, err.reshape(-1)
-
-
 def _cast_with_delta(red: jnp.ndarray, dtype) -> Tuple[jnp.ndarray,
                                                        jnp.ndarray]:
     """Downcast the f32 mean to the gradient dtype, returning the cast
@@ -175,61 +103,6 @@ def _cast_with_delta(red: jnp.ndarray, dtype) -> Tuple[jnp.ndarray,
         return cast, jnp.zeros_like(red)
     delta = red - cast.astype(jnp.float32)
     return cast, jnp.where(jnp.isfinite(delta), delta, 0.0)
-
-
-def compressed_allreduce_tree(grads, errors, axis_name: str, p: int, *,
-                              transport: str = "circulant",
-                              backend: str = "jnp",
-                              n_blocks: Optional[int] = None,
-                              qblock: Optional[int] = None):
-    """Lossy mean-allreduce of a gradient pytree with error feedback.
-
-    Must be called inside shard_map over ``axis_name``.  ``errors`` is
-    the previous step's error state (f32 leaves, SUM units; start from
-    :func:`init_error_state`).  Gradient leaves may be bf16/f16/f32:
-    sub-f32 leaves are widened to f32 for the transport and the mean is
-    cast back, with the downcast loss folded into the returned error
-    state (the error state itself always stays f32).  Ragged leaf sizes
-    are padded internally; the padded tail's error is folded back into
-    the last real element, so truncation never drops error mass.
-    Returns ``(mean_grads, new_errors)``.
-    """
-    if transport not in ("circulant", "ring"):
-        raise ValueError(f"unknown transport {transport!r} "
-                         "(use 'circulant' or 'ring')")
-    flat_g, treedef = jax.tree.flatten(grads)
-    flat_e = treedef.flatten_up_to(errors)
-    targets = [g.astype(jnp.float32).reshape(-1) + e.reshape(-1)
-               for g, e in zip(flat_g, flat_e)]
-
-    if transport == "circulant":
-        from repro.core.comm import circulant_qallreduce_body
-
-        sums, errs = circulant_qallreduce_body(
-            targets, axis_name, p, n_blocks=n_blocks, backend=backend,
-            qblock=qblock)
-        means = [s / p for s in sums]
-    else:
-        qb = BLOCK if qblock is None else int(qblock)
-        means, errs = [], []
-        for tgt in targets:
-            size = tgt.shape[0]
-            pad = (-size) % (p * qb)
-            red, e = compressed_psum_ring(jnp.pad(tgt, (0, pad)),
-                                          axis_name, p)
-            # fold the padded tail's error back into the last real
-            # element (provably zero for exact-zero padding, but the
-            # truncation must never be able to drop error mass).
-            e = e[:size].at[size - 1].add(jnp.sum(e[size:]))
-            means.append(red[:size])
-            errs.append(e)
-
-    outs, new_errs = [], []
-    for g, m, e in zip(flat_g, means, errs):
-        cast, delta = _cast_with_delta(m, g.dtype)
-        outs.append(cast.reshape(g.shape))
-        new_errs.append((e + delta).reshape(g.shape))
-    return treedef.unflatten(outs), treedef.unflatten(new_errs)
 
 
 # ----------------------------------------------------- gradient buckets

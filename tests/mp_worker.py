@@ -1,7 +1,7 @@
 """Multi-device collective worker: run under XLA host-device flags.
 
-Invoked as a subprocess by test_collectives.py (and by the collective
-benchmarks) so that the main process keeps its single-device view:
+Invoked as a subprocess by test_collectives.py (and the other
+multidevice tests) so that the main process keeps its single-device view:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python tests/mp_worker.py <what> <p>
@@ -26,15 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.collectives import (
-    circulant_allbroadcast,
-    circulant_allgather,
-    circulant_allgatherv,
-    circulant_allreduce,
-    circulant_broadcast,
-    circulant_reduce,
-    ring_allgather,
-)
+from repro.core.comm import get_comm
 
 
 def make_mesh(p):
@@ -52,8 +44,8 @@ def check_broadcast(p, n_blocks, root, elems=97, dtype=jnp.float32,
     data = rng.normal(size=(p, elems)).astype(dtype)
     x = sharded(mesh, jnp.asarray(data))
     out = jax.jit(
-        lambda a: circulant_broadcast(mesh, "data", a, n_blocks=n_blocks,
-                                      root=root, backend=backend)
+        lambda a: get_comm(mesh, "data", backend=backend).broadcast(
+            a, n_blocks=n_blocks, root=root)
     )(x)
     out = np.asarray(out)
     for r in range(p):
@@ -67,8 +59,8 @@ def check_allgather(p, n_blocks, elems=64, dtype=jnp.float32, backend="jnp"):
     data = rng.normal(size=(p * elems,)).astype(dtype)
     x = sharded(mesh, jnp.asarray(data))
     out = jax.jit(
-        lambda a: circulant_allgather(mesh, "data", a, n_blocks=n_blocks,
-                                      backend=backend)
+        lambda a: get_comm(mesh, "data", backend=backend).allgather(
+            a, n_blocks=n_blocks)
     )(x)
     np.testing.assert_allclose(np.asarray(out), data, rtol=0, atol=0)
     print(f"allgather p={p} n={n_blocks} backend={backend} ok")
@@ -83,8 +75,8 @@ def check_allgatherv(p, n_blocks, sizes, dtype=jnp.int32, backend="jnp"):
         rows[j, : sizes[j]] = rng.integers(0, 1000, size=sizes[j])
     x = sharded(mesh, jnp.asarray(rows))
     out = jax.jit(
-        lambda a: circulant_allgatherv(mesh, "data", a, sizes,
-                                       n_blocks=n_blocks, backend=backend)
+        lambda a: get_comm(mesh, "data", backend=backend).allgatherv(
+            a, sizes, n_blocks=n_blocks)
     )(x)
     out = np.asarray(out)
     for j in range(p):
@@ -93,16 +85,15 @@ def check_allgatherv(p, n_blocks, sizes, dtype=jnp.int32, backend="jnp"):
 
 
 def check_compressed_allreduce(p, elems=2048, backend="jnp"):
-    """Both lossy transports (legacy ring, quantized circulant): mean
-    contract, COMPLETE error feedback vs the exact f32 psum on
-    adversarial high-dynamic-range gradients, ragged leaf sizes,
-    bf16 leaves, and nonfinite propagation."""
-    from jax.sharding import PartitionSpec as P
+    """The trainer's int8 sync (``compressed_grad_sync`` over a bucket
+    spec of the leaves): mean contract, COMPLETE error feedback vs the
+    exact f32 sum on adversarial high-dynamic-range gradients, a ragged
+    bucket, a bf16 leaf, and nonfinite propagation."""
     from jax import shard_map
     from repro.optim.compression import (
         BLOCK,
-        compressed_allreduce_tree,
-        init_error_state,
+        compressed_grad_sync,
+        make_bucket_spec,
     )
 
     mesh = make_mesh(p)
@@ -119,73 +110,75 @@ def check_compressed_allreduce(p, elems=2048, backend="jnp"):
     # accounting), bf16 third leaf (f32 error state + downcast delta).
     rag = rng.normal(size=(p, 3 * BLOCK + 17)).astype(np.float32) * 100.0
     bfl = rng.normal(size=(p, 37)).astype(np.float32)
+    # one bucket per leaf (a 4-byte budget), so each leaf's quantization
+    # blocks are its own: "r" alone is a ragged bucket.
+    like = {"w": jax.ShapeDtypeStruct((elems,), jnp.float32),
+            "r": jax.ShapeDtypeStruct(rag.shape[1:], jnp.float32),
+            "t": jax.ShapeDtypeStruct(bfl.shape[1:], jnp.bfloat16)}
+    spec = make_bucket_spec(like, 4)
+    assert spec.num_buckets == 3, spec
 
-    for transport in ("ring", "circulant"):
+    def run(w, r, t):
         def body(xs, ys, zs):
             g = {"w": xs[0], "r": ys[0], "t": zs[0].astype(jnp.bfloat16)}
-            e = init_error_state(g)
-            red, new_e = compressed_allreduce_tree(
-                g, e, "data", p, transport=transport, backend=backend)
-            tot = jax.tree.map(lambda v: jax.lax.psum(v, "data"), new_e)
-            red = jax.tree.map(lambda v: v.astype(jnp.float32), red)
-            return (jax.tree.map(lambda v: v[None], red),
-                    jax.tree.map(lambda v: v[None], tot))
+            errs = [jnp.zeros((s,), jnp.float32) for s in spec.bucket_sizes]
+            red, new_e = compressed_grad_sync(g, errs, "data", p, spec,
+                                              backend=backend)
+            red = {k: v.astype(jnp.float32)[None] for k, v in red.items()}
+            tot = [jax.lax.psum(e, "data")[None] for e in new_e]
+            return red, tot, [e[None] for e in new_e]
 
-        red, tot = jax.jit(shard_map(
+        return jax.jit(shard_map(
             body, mesh=mesh, in_specs=(P("data"),) * 3,
-            out_specs=({k: P("data") for k in "wrt"},) * 2,
+            out_specs=({k: P("data") for k in like},
+                       [P("data")] * spec.num_buckets,
+                       [P("data")] * spec.num_buckets),
             check_vma=False,
-        ))(sharded(mesh, jnp.asarray(data)),
-           sharded(mesh, jnp.asarray(rag)),
-           sharded(mesh, jnp.asarray(bfl)))
-        srcs = {"w": data, "r": rag,
-                "t": np.asarray(jnp.asarray(bfl).astype(jnp.bfloat16),
-                                np.float32)}
-        for k, src in srcs.items():
-            exact_sum = src.astype(np.float64).sum(0)
-            got = np.asarray(red[k], np.float64)
-            te = np.asarray(tot[k], np.float64)
-            # mean contract (loose sanity: one-shot lossy error is set by
-            # the quantization-block amax, ~amax*p/127 per element; the
-            # tight per-element claim is the completeness check below)
-            lim = np.float64(5.0) * p * np.abs(src).max() / 127.0 + 1e-6
-            assert (np.abs(got - exact_sum[None] / p) < lim).all()
-            # completeness: exact_sum == p*mean + psum(err), to f32
-            # accumulation tolerance -- this is what the old ring failed
-            # by a factor of p plus every dropped per-hop error.
-            for r in range(p):
-                resid = np.abs(got[r] * p + te[r] - exact_sum)
-                tol = 1e-4 * np.maximum(np.abs(exact_sum),
-                                        np.abs(src).max(0) * p) + 1e-6
-                assert (resid <= tol).all(), (
-                    f"{transport}/{k} r={r}: error feedback incomplete, "
-                    f"max resid {resid.max():.3e}")
-        print(f"compressed_allreduce p={p} transport={transport} "
-              f"backend={backend} ok")
+        ))(*(sharded(mesh, jnp.asarray(a)) for a in (w, r, t)))
+
+    red, tot, _ = run(data, rag, bfl)
+    srcs = {"w": data, "r": rag,
+            "t": np.asarray(jnp.asarray(bfl).astype(jnp.bfloat16),
+                            np.float32)}
+    # dict leaves flatten in key order, as the spec numbers them
+    for k, b in zip(sorted(srcs), spec.assignment):
+        src = srcs[k]
+        exact_sum = src.astype(np.float64).sum(0)
+        got = np.asarray(red[k], np.float64)
+        te = np.asarray(tot[b], np.float64)
+        # mean contract (loose sanity: one-shot lossy error is set by
+        # the quantization-block amax, ~amax*p/127 per element; the
+        # tight per-element claim is the completeness check below)
+        lim = np.float64(5.0) * p * np.abs(src).max() / 127.0 + 1e-6
+        assert (np.abs(got - exact_sum[None] / p) < lim).all()
+        # completeness: exact_sum == p*mean + psum(err), to f32
+        # accumulation tolerance -- what an error state that drops a
+        # per-hop error, or keeps mean units, fails by a factor of p.
+        for r in range(p):
+            resid = np.abs(got[r] * p + te[r] - exact_sum)
+            tol = 1e-4 * np.maximum(np.abs(exact_sum),
+                                    np.abs(src).max(0) * p) + 1e-6
+            assert (resid <= tol).all(), (
+                f"{k} r={r}: error feedback incomplete, "
+                f"max resid {resid.max():.3e}")
+    print(f"compressed_allreduce p={p} backend={backend} ok")
 
     # nonfinite: a NaN lane poisons exactly its own quantization block
-    # in the result (deterministic all-NaN), never the error state.
+    # of its bucket in the result (deterministic all-NaN), never the
+    # error state.
     bad = data.copy()
     bad[0, BLOCK + 3] = np.nan
-
-    def nf_body(xs):
-        g = {"w": xs[0]}
-        e = init_error_state(g)
-        red, new_e = compressed_allreduce_tree(g, e, "data", p,
-                                               backend=backend)
-        return red["w"][None], new_e["w"][None]
-
-    red, err = jax.jit(shard_map(
-        nf_body, mesh=mesh, in_specs=P("data"),
-        out_specs=(P("data"), P("data")), check_vma=False,
-    ))(sharded(mesh, jnp.asarray(bad)))
-    red, err = np.asarray(red), np.asarray(err)
+    red, _, err = run(bad, rag, bfl)
+    w = np.asarray(red["w"])
     for r in range(p):
-        assert np.isnan(red[r, BLOCK:2 * BLOCK]).all(), \
+        assert np.isnan(w[r, BLOCK:2 * BLOCK]).all(), \
             "NaN block not propagated"
-        assert np.isfinite(red[r, 2 * BLOCK:]).all()
-        assert np.isfinite(red[r, :BLOCK]).all()
-    assert np.isfinite(err).all(), "error state poisoned by NaN input"
+        assert np.isfinite(w[r, 2 * BLOCK:]).all()
+        assert np.isfinite(w[r, :BLOCK]).all()
+        for k in "rt":
+            assert np.isfinite(np.asarray(red[k])[r]).all(), k
+    assert all(np.isfinite(np.asarray(e)).all() for e in err), \
+        "error state poisoned by NaN input"
     print(f"compressed_allreduce p={p} nonfinite backend={backend} ok")
 
 
@@ -242,8 +235,6 @@ def check_overlap(p, backend="jnp"):
     """Overlapped (double-buffered) executor vs sequential on a live
     mesh: distinct cached plans, bit-equal outputs for every kind that
     gains the mode (mixed-dtype pytrees, nonzero roots, max reduces)."""
-    from repro.core.comm import get_comm
-
     mesh = make_mesh(p)
     comm = get_comm(mesh, "data", backend=backend)
     rng = np.random.default_rng(43)
@@ -353,8 +344,6 @@ def check_gradsync_stream(p, backend="jnp", steps=12):
 
 
 def check_reduce_scatter(p):
-    from repro.core.collectives import circulant_reduce_scatter
-
     mesh = make_mesh(p)
     rng = np.random.default_rng(13)
     for n in (1, 2, 3, 6):
@@ -362,7 +351,7 @@ def check_reduce_scatter(p):
         data = rng.normal(size=(p, L)).astype(np.float32)
         x = sharded(mesh, jnp.asarray(data))
         out = jax.jit(
-            lambda a: circulant_reduce_scatter(mesh, "data", a, n_blocks=n)
+            lambda a: get_comm(mesh, "data").reduce_scatter(a, n_blocks=n)
         )(x)
         out = np.asarray(out)
         expect = data.sum(axis=0).reshape(p, -1)
@@ -399,8 +388,8 @@ def check_reduce(p, backend="jnp"):
             data = rng.integers(-1000, 1000, size=(p, 41)).astype(np.int32)
             x = sharded(mesh, jnp.asarray(data))
             out = np.asarray(jax.jit(
-                lambda a: circulant_reduce(mesh, "data", a, n_blocks=n,
-                                           root=root, backend=backend)
+                lambda a: get_comm(mesh, "data", backend=backend).reduce(
+                    a, n_blocks=n, root=root)
             )(x))
             np.testing.assert_array_equal(out[root], data.sum(axis=0))
             for r in range(p):
@@ -409,9 +398,8 @@ def check_reduce(p, backend="jnp"):
             fdata = rng.normal(size=(p, 41)).astype(np.float32)
             xf = sharded(mesh, jnp.asarray(fdata))
             outf = np.asarray(jax.jit(
-                lambda a: circulant_reduce(
-                    mesh, "data", a, n_blocks=n, root=root, op="max",
-                    backend=backend)
+                lambda a: get_comm(mesh, "data", backend=backend).reduce(
+                    a, n_blocks=n, root=root, op="max")
             )(xf))
             np.testing.assert_array_equal(outf[root], fdata.max(axis=0))
             print(f"reduce p={p} n={n} root={root} backend={backend} ok")
@@ -425,8 +413,8 @@ def check_allreduce(p, backend="jnp"):
         data = rng.integers(-1000, 1000, size=(p, 53)).astype(np.int32)
         x = sharded(mesh, jnp.asarray(data))
         out = np.asarray(jax.jit(
-            lambda a: circulant_allreduce(mesh, "data", a, n_blocks=n,
-                                          backend=backend)
+            lambda a: get_comm(mesh, "data", backend=backend).allreduce(
+                a, n_blocks=n)
         )(x))
         expect = data.sum(axis=0)
         for r in range(p):
@@ -434,8 +422,8 @@ def check_allreduce(p, backend="jnp"):
         fdata = rng.normal(size=(p, 53)).astype(np.float32)
         xf = sharded(mesh, jnp.asarray(fdata))
         outf = np.asarray(jax.jit(
-            lambda a: circulant_allreduce(mesh, "data", a, n_blocks=n,
-                                          op="max", backend=backend)
+            lambda a: get_comm(mesh, "data", backend=backend).allreduce(
+                a, n_blocks=n, op="max")
         )(xf))
         expectf = fdata.max(axis=0)
         for r in range(p):
@@ -450,7 +438,7 @@ def check_allbroadcast(p, elems=48):
         data = rng.normal(size=(p * elems,)).astype(np.float32)
         x = sharded(mesh, jnp.asarray(data))
         out = np.asarray(jax.jit(
-            lambda a: circulant_allbroadcast(mesh, "data", a, n_blocks=n)
+            lambda a: get_comm(mesh, "data").allbroadcast(a, n_blocks=n)
         )(x))
         np.testing.assert_allclose(out, data, rtol=0, atol=0)
         print(f"allbroadcast p={p} n={n} ok")
@@ -461,7 +449,7 @@ def check_comm(p, backend="jnp"):
     mixed dtypes, ragged leaves (sizes not divisible by n_blocks), both
     data-plane backends -- certified bit-exact against per-leaf NumPy
     references, with plan-cache identity asserted along the way."""
-    from repro.core.comm import get_comm, payload_spec
+    from repro.core.comm import payload_spec
 
     mesh = make_mesh(p)
     comm = get_comm(mesh, "data", backend=backend)
@@ -568,14 +556,6 @@ def check_comm(p, backend="jnp"):
                                   vin["v"][:, :7])
     print(f"comm allgatherv pytree p={p} backend={backend} ok")
 
-    # ---- shim equivalence: circulant_* resolves to the same plan cache.
-    arr = sharded(mesh, jnp.asarray(state["w"]))
-    a = circulant_broadcast(mesh, "data", arr, n_blocks=4, root=root,
-                            backend=backend)
-    b = comm.broadcast(arr, n_blocks=4, root=root)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    print(f"comm shim equivalence p={p} backend={backend} ok")
-
 
 def check_hier(nodes, cores, backend="jnp"):
     """Two-level hierarchical collectives on a (nodes x cores) mesh:
@@ -664,8 +644,8 @@ def check_hier(nodes, cores, backend="jnp"):
     h1 = get_hier_comm(mesh1, "node", "core", backend=backend)
     arr = jax.device_put(jnp.asarray(state["w"]), spec1)
     a = np.asarray(h1.broadcast(arr, n_intra=3, root=1))
-    b = np.asarray(circulant_broadcast(mesh1, "core", arr, n_blocks=3,
-                                       root=1, backend=backend))
+    b = np.asarray(get_comm(mesh1, "core", backend=backend).broadcast(
+        arr, n_blocks=3, root=1))
     np.testing.assert_array_equal(a, b)
     print(f"hier degenerate 1x{p} == flat backend={backend} ok")
 
@@ -676,7 +656,6 @@ def check_analysis(p, nodes, backend="jnp"):
     their statics (the host-plane CLI covers host plans; this covers
     the jitted flavour's closed-over tables)."""
     from repro.analysis import audit_plan
-    from repro.core.comm import get_comm
     from repro.core.hier import get_hier_comm
 
     mesh = make_mesh(p)
@@ -714,15 +693,6 @@ def check_analysis(p, nodes, backend="jnp"):
         rep = audit_plan(hplan)
         assert rep.ok, f"device hier {kind} failed audit:\n{rep.summary()}"
         print(f"analysis device hier {kind} {nodes}x{cores} ok")
-
-
-def check_ring(p, elems=16):
-    mesh = make_mesh(p)
-    data = np.arange(p * elems, dtype=np.float32)
-    x = sharded(mesh, jnp.asarray(data))
-    out = jax.jit(lambda a: ring_allgather(mesh, "data", a))(x)
-    np.testing.assert_allclose(np.asarray(out), data)
-    print(f"ring p={p} ok")
 
 
 def check_tracing(p, calls=3):
@@ -891,8 +861,6 @@ def main(what, p, backend="jnp", nodes=2):
         check_allgatherv(p, 3, [600] + [1] * (p - 1), backend=backend)
         check_allgatherv(p, 2, list(rng.integers(1, 50, size=p)),
                          backend=backend)
-    if what in ("ring", "all"):
-        check_ring(p)
     if what in ("compressed", "all"):
         check_compressed_allreduce(p, backend=backend)
     if what == "gradsync":

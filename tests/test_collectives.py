@@ -30,10 +30,6 @@ def test_circulant_allgatherv_multidevice(p):
     run_worker("allgatherv", p)
 
 
-def test_ring_allgather_multidevice():
-    run_worker("ring", 8)
-
-
 @pytest.mark.parametrize("p", [3, 8])
 def test_restore_broadcast_multidevice(p):
     run_worker("restore", p)

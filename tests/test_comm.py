@@ -55,18 +55,6 @@ def test_commmodel_frozen_and_hashable():
     assert comm.model is DEFAULT_MODEL
 
 
-def test_legacy_aliases_warn_and_resolve():
-    from repro.core.collectives import CirculantTables, build_tables
-    from repro.core.engine import get_bundle
-
-    with pytest.warns(DeprecationWarning, match="get_bundle"):
-        b = CirculantTables(8)
-    assert b is get_bundle(8)
-    with pytest.warns(DeprecationWarning, match="get_bundle"):
-        b = build_tables(12)
-    assert b is get_bundle(12)
-
-
 def test_payload_spec_hashable_and_stable():
     import jax
 
@@ -399,19 +387,6 @@ def test_optimal_blocks_never_outnumber_payload():
         ni, nc = optimal_hier_blocks(6, 4, 0.5, 0.5, kind=kind)
         assert (ni, nc) == (1, 1)
     assert all(map(math.isfinite, optimal_hier_blocks(2, 2, 1.0, 1.0)))
-
-
-def test_deprecated_aliases_still_in_collectives_all():
-    """The shim surface stays importable: everything the seed exported
-    from collectives still resolves."""
-    from repro.core import collectives
-
-    for name in ("circulant_broadcast", "circulant_allgather",
-                 "circulant_allgatherv", "circulant_allbroadcast",
-                 "circulant_reduce", "circulant_allreduce",
-                 "ring_allgather", "CirculantTables", "build_tables"):
-        assert hasattr(collectives, name), name
-        assert name in collectives.__all__
 
 
 # --------------------------------------------------- multidevice grid

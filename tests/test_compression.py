@@ -26,7 +26,7 @@ from repro.optim.compression import (
     block_nonfinite,
     bucketize,
     dequantize_int8,
-    init_error_state,
+    init_grad_sync_state,
     make_bucket_spec,
     quantize_int8,
     unbucketize,
@@ -233,8 +233,10 @@ def test_quantize_zero_and_tiny_blocks():
 def test_error_state_is_f32_for_low_precision_params():
     params = {"a": jnp.zeros((3, 4), jnp.bfloat16),
               "b": jnp.zeros((7,), jnp.float16)}
-    err = init_error_state(params)
-    assert all(e.dtype == jnp.float32 for e in jax.tree.leaves(err))
+    spec = make_bucket_spec(params)
+    err = init_grad_sync_state(spec)
+    assert all(e.dtype == jnp.float32 for e in err)
+    assert all(f.dtype == jnp.float32 for f in bucketize(params, spec))
 
 
 # ------------------------------------------------------------- buckets

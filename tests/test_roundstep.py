@@ -18,12 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.roundstep import (
-    dataplane_allgather,
-    dataplane_broadcast,
-    dataplane_reduce,
-    get_round_step,
-)
+from repro.core.comm import host_plan
+from repro.core.roundstep import get_round_step
 from repro.kernels import layout
 from repro.core.simulator import (
     simulate_allbroadcast,
@@ -337,13 +333,17 @@ def test_dataplanes_bitexact_across_backends(p):
     rng = np.random.default_rng(p)
     n = 4
     bvals = rng.normal(size=(n,))
-    assert np.array_equal(dataplane_broadcast(p, n, 0, bvals, "jnp"),
-                          dataplane_broadcast(p, n, 0, bvals, "pallas"))
+    assert np.array_equal(
+        host_plan("broadcast", p, n, root=0, backend="jnp").run(bvals),
+        host_plan("broadcast", p, n, root=0, backend="pallas").run(bvals))
     gvals = rng.normal(size=(p, n))
-    assert np.array_equal(dataplane_allgather(p, n, gvals, "jnp"),
-                          dataplane_allgather(p, n, gvals, "pallas"))
+    assert np.array_equal(
+        host_plan("allgather", p, n, backend="jnp").run(gvals),
+        host_plan("allgather", p, n, backend="pallas").run(gvals))
     for op in ("sum", "max"):
         assert np.array_equal(
-            dataplane_reduce(p, n, p - 1, gvals, op, "jnp"),
-            dataplane_reduce(p, n, p - 1, gvals, op, "pallas"),
+            host_plan("reduce", p, n, root=p - 1, op=op,
+                      backend="jnp").run(gvals),
+            host_plan("reduce", p, n, root=p - 1, op=op,
+                      backend="pallas").run(gvals),
         )
